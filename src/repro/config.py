@@ -101,7 +101,6 @@ class ModelConfig:
     # the per-layer TP all-reduce; the only big collective left is the
     # MoE dispatch all-to-all (DeepSeek-V3-style).
     ep_major: bool = False
-    use_pallas: bool = False      # Pallas kernels (TPU); jnp path otherwise
     q_chunk: int = 1024           # q-chunking for memory-bound attention fwd
 
     @property

@@ -165,6 +165,14 @@ class SelectionSchedule:
         return tuple(stages)
 
 
+def platform_kernel_impl() -> str:
+    """The decode kernels of the default backend: the compiled Pallas
+    kernels on a TPU, the jnp kernels (``kernels/ref.py``) anywhere else.
+    One decode path per platform — a TPU never runs the jnp reference
+    unless the caller names ``kernel_impl="ref"``."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
 def select_impl(kernel_impl: str) -> str:
     """Map the attention-kernel impl to the gate-select impl: the Pallas
     paths run selection in-kernel too; everything else (ref, sharded) uses
@@ -572,10 +580,15 @@ class DecodeOptions:
     threaded engine -> ModelApi -> model -> kernels.
 
     policy:          block-selection strategy (see module docstring)
-    kernel_impl:     attention/selection execution path — "ref" (jnp),
-                     "pallas" (TPU), "pallas_interpret" (CPU kernel check),
-                     "sharded" (sequence-parallel shard_map; GatePolicy or
-                     DensePolicy only, needs a mesh-aware ``shard``)
+    kernel_impl:     attention/selection execution path. None (default)
+                     follows the platform (``platform_kernel_impl``: the
+                     compiled Pallas kernels on a TPU, jnp elsewhere);
+                     named paths: "ref" (jnp), "pallas" (TPU only — any
+                     other backend raises at engine construction),
+                     "pallas_interpret" (CPU kernel check), "sharded"
+                     (shard_map over a mesh; GatePolicy or DensePolicy
+                     only, needs a mesh-aware ``shard``; its per-shard
+                     kernels follow the platform)
     sampling:        SamplingParams (default greedy — bitwise argmax)
     budget_override: token budget replacing ``cfg.gate.token_budget`` for
                      this options object (None = config budget); engines
@@ -612,7 +625,7 @@ class DecodeOptions:
                      cache-sized array (ISSUE 9).
     """
     policy: SelectionPolicy = GatePolicy()
-    kernel_impl: str = "ref"
+    kernel_impl: Optional[str] = None
     sampling: SamplingParams = GREEDY
     budget_override: Optional[int] = None
     measure_sparsity: bool = True
@@ -625,7 +638,8 @@ class DecodeOptions:
         if self.quantize not in (None, "int8"):
             raise ValueError(
                 f"quantize must be None or 'int8': {self.quantize!r}")
-        if self.kernel_impl not in KERNEL_IMPLS:
+        if self.kernel_impl is not None and \
+                self.kernel_impl not in KERNEL_IMPLS:
             raise ValueError(f"kernel_impl {self.kernel_impl!r} not in "
                              f"{KERNEL_IMPLS}")
         if self.split_k < 1:
@@ -671,6 +685,23 @@ class DecodeOptions:
                 "DENSE-staged layers read every visible block, so every "
                 "evicted page would fault every step (evict/restore "
                 "thrash)")
+
+    @property
+    def impl(self) -> str:
+        """``kernel_impl`` with None resolved to the platform's kernels."""
+        return self.kernel_impl or platform_kernel_impl()
+
+    def check_platform(self) -> None:
+        """Refuse the compiled TPU kernels on any other backend — never
+        a quiet fallback to interpret mode or to the jnp path."""
+        backend = jax.default_backend()
+        if self.kernel_impl == "pallas" and backend != "tpu":
+            raise ValueError(
+                f"kernel_impl='pallas' runs the compiled TPU kernels, but "
+                f"the default backend is {backend!r}; name "
+                f"'pallas_interpret' (interpret-mode kernel check) or "
+                f"'ref' (jnp), or leave kernel_impl=None for the "
+                f"platform's own path")
 
     def max_selected(self, cfg: ModelConfig) -> Optional[int]:
         """Selected-list width override in BLOCKS (None = config budget).

@@ -39,10 +39,12 @@ one-time layout conversion):
 
 Fused dequant (ISSUE 9): optional ``k_scales``/``v_scales`` — per-block
 f32 dequant factors ([B, Hkv, nb] contiguous, [P, Hkv(, 1)] paged pool
-rows) — ride the SAME scalar-prefetch path as the block indices: the
-kernel body recomputes each streamed block's (physical) id from
-idx_ref/pt_ref and multiplies the block by its scalar scale right after
-the VMEM load's fp32 upcast, inside the online-softmax block loop. The
+rows). The scales of the SELECTED blocks are gathered outside the kernel
+into a [B, Hkv, nsel] array laid out like the block indices (a
+selection-sized gather, so the scalar-memory operand does not grow with
+the pool), which rides the same scalar-prefetch path as the indices; the
+kernel multiplies each streamed block by its scalar scale right after the
+VMEM load's fp32 upcast, inside the online-softmax block loop. The
 int8->fp conversion therefore only ever exists as one [bs, Dh] VMEM tile
 per grid step — no fp copy of the cache is materialized, and HBM traffic
 shrinks with the storage (~4x for int8 vs f32). ``None`` scales leave the
@@ -62,6 +64,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+F32 = jax.lax.Precision.HIGHEST   # full-f32 MXU dots (ref.F32: same math)
 
 
 def _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs,
@@ -95,6 +98,7 @@ def _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs,
             if k_scales is not None:
                 k = k * k_scales[i]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    precision=F32,
                                     preferred_element_type=jnp.float32) * scale
             pos = idxs[i] * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
@@ -118,7 +122,7 @@ def _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs,
             if v_scales is not None:
                 v = v * v_scales[i]
             acc = acc + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p, v, (((1,), (0,)), ((), ())), precision=F32,
                 preferred_element_type=jnp.float32)
         acc_ref[...] = acc
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -140,10 +144,10 @@ def _flash_group(idxs, b, j, n_groups, len_ref, q_ref, k_refs, v_refs,
 
 
 def _kernel_body(idx_ref, len_ref, refs, *, block_size: int, n_groups: int,
-                 blocks_per_step: int, scale: float, scale_lookup=None):
+                 blocks_per_step: int, scale: float, scale_refs=None):
     """Unpack the (q, k*C, v*C, o, scratch) ref layout and run one group.
-    ``scale_lookup(b, h, idxs) -> (k_scales, v_scales)`` resolves the
-    streamed blocks' dequant factors from SMEM (quantized pools only)."""
+    ``scale_refs`` = (ks_ref, vs_ref): the streamed blocks' dequant
+    factors, laid out like ``idx_ref`` (quantized caches only)."""
     C = blocks_per_step
     q_ref = refs[0]
     k_refs = refs[1:1 + C]
@@ -155,8 +159,10 @@ def _kernel_body(idx_ref, len_ref, refs, *, block_size: int, n_groups: int,
     j = pl.program_id(2)
     idxs = [idx_ref[b, h, j * C + i] for i in range(C)]
     k_scales = v_scales = None
-    if scale_lookup is not None:
-        k_scales, v_scales = scale_lookup(b, h, idxs)
+    if scale_refs is not None:
+        ks_ref, vs_ref = scale_refs
+        k_scales = [ks_ref[b, h, j * C + i] for i in range(C)]
+        v_scales = [vs_ref[b, h, j * C + i] for i in range(C)]
     _flash_group(idxs, b, j, n_groups, len_ref, q_ref, k_refs, v_refs,
                  o_ref, m_ref, l_ref, acc_ref, block_size=block_size,
                  scale=scale, k_scales=k_scales, v_scales=v_scales)
@@ -169,13 +175,10 @@ def _kernel(idx_ref, len_ref,              # scalar prefetch
 
 def _kernel_quant(idx_ref, len_ref, ks_ref, vs_ref,  # scalar prefetch
                   *refs, **kw):
-    # contiguous fused-dequant body: ks/vs [B, Hkv, nb] ride scalar
-    # prefetch (SMEM); each streamed block's scale is a scalar read
-    def lookup(b, h, idxs):
-        safe = [jnp.maximum(ix, 0) for ix in idxs]
-        return ([ks_ref[b, h, s] for s in safe],
-                [vs_ref[b, h, s] for s in safe])
-    _kernel_body(idx_ref, len_ref, refs, scale_lookup=lookup, **kw)
+    # fused-dequant body: ks/vs [B, Hkv, nsel] hold the scale of each
+    # SELECTED block (gathered outside the kernel, ``_selected_scales``),
+    # so each streamed block's scale is a scalar read at its list slot
+    _kernel_body(idx_ref, len_ref, refs, scale_refs=(ks_ref, vs_ref), **kw)
 
 
 def _kernel_paged(idx_ref, pt_ref, len_ref,  # scalar prefetch (+page table)
@@ -189,14 +192,28 @@ def _kernel_paged(idx_ref, pt_ref, len_ref,  # scalar prefetch (+page table)
 
 def _kernel_paged_quant(idx_ref, pt_ref, len_ref, ks_ref, vs_ref, *refs,
                         **kw):
-    # paged fused-dequant body: the kernel recomputes each streamed tile's
-    # PHYSICAL page id (same translation the index_map did) and reads that
-    # page's scale row [P, Hkv] from SMEM
-    def lookup(b, h, idxs):
-        phys = [jnp.maximum(pt_ref[b, jnp.maximum(ix, 0)], 0) for ix in idxs]
-        return ([ks_ref[p, h] for p in phys],
-                [vs_ref[p, h] for p in phys])
-    _kernel_body(idx_ref, len_ref, refs, scale_lookup=lookup, **kw)
+    _kernel_body(idx_ref, len_ref, refs, scale_refs=(ks_ref, vs_ref), **kw)
+
+
+def _selected_scales(rows: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+    """Per-head pool scale rows ``[P, Hkv(, 1)]`` -> the scale of each
+    listed page ``[B, Hkv, n]`` for physical ids ``[B, Hkv, n]``. A
+    selection-sized gather: the scalar-prefetch operand then scales with
+    the selected list, not with the pool — a pool-sized [P, Hkv] SMEM
+    operand overflows the TPU's scalar memory at serving pool sizes."""
+    hkv = ids.shape[1]
+    rows = rows.reshape(-1, hkv).astype(jnp.float32)
+    return rows[jnp.maximum(ids, 0), jnp.arange(hkv)[None, :, None]]
+
+
+def _physical_ids(block_indices: jnp.ndarray,
+                  page_table: jnp.ndarray) -> jnp.ndarray:
+    """Logical selected ids [B, Hkv, n] -> physical page ids (the same
+    clamped translation the K/V index_maps do)."""
+    b = block_indices.shape[0]
+    phys = page_table[jnp.arange(b)[:, None, None],
+                      jnp.maximum(block_indices, 0)]
+    return jnp.maximum(phys, 0)
 
 
 def _pad_group(g: int, dtype) -> int:
@@ -267,8 +284,11 @@ def block_sparse_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     )
     prefetch = [idx.astype(jnp.int32), kv_len.astype(jnp.int32)]
     if quant:
-        prefetch += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        bidx = jnp.arange(bsz)[:, None, None]
+        hidx = jnp.arange(hkv)[None, :, None]
+        safe = jnp.maximum(idx, 0)
+        prefetch += [k_scales.astype(jnp.float32)[bidx, hidx, safe],
+                     v_scales.astype(jnp.float32)[bidx, hidx, safe]]
     out = pl.pallas_call(
         functools.partial(_kernel_quant if quant else _kernel,
                           block_size=block_size, n_groups=n_groups,
@@ -301,9 +321,9 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     pages never leave HBM — paging adds zero extra KV I/O.
 
     ``k_scales``/``v_scales`` [P, Hkv(, 1)] f32: per-page per-head dequant
-    rows for int8 pools (serve.paging scale pools). They ride scalar
-    prefetch too; the kernel body redoes the logical->physical translation
-    to pick each streamed page's scale (None = fp path verbatim).
+    rows for int8 pools (serve.paging scale pools). The selected pages'
+    scales are gathered through the same logical->physical translation
+    and ride scalar prefetch beside the indices (None = fp path verbatim).
     """
     bsz, hkv, g, dh = q.shape
     ps = k_pages.shape[2]
@@ -345,8 +365,9 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     prefetch = [idx.astype(jnp.int32), page_table.astype(jnp.int32),
                 kv_len.astype(jnp.int32)]
     if quant:
-        prefetch += [k_scales.reshape(-1, hkv).astype(jnp.float32),
-                     v_scales.reshape(-1, hkv).astype(jnp.float32)]
+        phys = _physical_ids(idx, page_table)
+        prefetch += [_selected_scales(k_scales, phys),
+                     _selected_scales(v_scales, phys)]
     out = pl.pallas_call(
         functools.partial(_kernel_paged_quant if quant else _kernel_paged,
                           block_size=block_size,
@@ -361,7 +382,7 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 def _kernel_paged_splitk(idx_ref, pt_ref, len_ref,   # scalar prefetch
                          *refs, block_size: int, n_groups: int,
                          blocks_per_step: int, scale: float, per_pad: int,
-                         scale_lookup=None):
+                         scale_refs=None):
     """Split-K body: each (b, h, s) lane accumulates its OWN split's
     online-softmax state and emits the raw partial (acc, m, l) instead of
     normalizing — the cross-split combine happens outside the kernel."""
@@ -375,10 +396,13 @@ def _kernel_paged_splitk(idx_ref, pt_ref, len_ref,   # scalar prefetch
     h = pl.program_id(1)
     s = pl.program_id(2)
     j = pl.program_id(3)
-    idxs = [idx_ref[b, h, s * per_pad + j * C + i] for i in range(C)]
+    slots = [s * per_pad + j * C + i for i in range(C)]
+    idxs = [idx_ref[b, h, t] for t in slots]
     k_scales = v_scales = None
-    if scale_lookup is not None:
-        k_scales, v_scales = scale_lookup(b, h, idxs)
+    if scale_refs is not None:
+        ks_ref, vs_ref = scale_refs
+        k_scales = [ks_ref[b, h, t] for t in slots]
+        v_scales = [vs_ref[b, h, t] for t in slots]
     _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs, m_ref, l_ref,
                  acc_ref, block_size=block_size, scale=scale,
                  k_scales=k_scales, v_scales=v_scales)
@@ -392,14 +416,8 @@ def _kernel_paged_splitk(idx_ref, pt_ref, len_ref,   # scalar prefetch
 
 def _kernel_paged_splitk_quant(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
                                *refs, **kw):
-    # split-K fused-dequant body: same physical-page scale lookup as
-    # _kernel_paged_quant, per split segment
-    def lookup(b, h, idxs):
-        phys = [jnp.maximum(pt_ref[b, jnp.maximum(ix, 0)], 0) for ix in idxs]
-        return ([ks_ref[p, h] for p in phys],
-                [vs_ref[p, h] for p in phys])
     _kernel_paged_splitk(idx_ref, pt_ref, len_ref, *refs,
-                         scale_lookup=lookup, **kw)
+                         scale_refs=(ks_ref, vs_ref), **kw)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "num_splits",
@@ -478,8 +496,9 @@ def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
     prefetch = [idx.astype(jnp.int32), page_table.astype(jnp.int32),
                 kv_len.astype(jnp.int32)]
     if quant:
-        prefetch += [k_scales.reshape(-1, hkv).astype(jnp.float32),
-                     v_scales.reshape(-1, hkv).astype(jnp.float32)]
+        phys = _physical_ids(idx, page_table)
+        prefetch += [_selected_scales(k_scales, phys),
+                     _selected_scales(v_scales, phys)]
     acc, m, l = pl.pallas_call(
         functools.partial(
             _kernel_paged_splitk_quant if quant else _kernel_paged_splitk,

@@ -1,8 +1,9 @@
 """Jit'd dispatching wrappers over the Pallas kernels and their jnp oracles.
 
-Models call these; the ``use_pallas`` flag (ModelConfig) or explicit
-``impl=`` picks the path. On CPU (tests, dry-run) the jnp path or
-``interpret=True`` is used; on TPU the Mosaic kernels.
+Models call these with ``impl=`` from ``DecodeOptions.impl``: the
+platform's kernels by default (the compiled Mosaic kernels on a TPU, the
+jnp path elsewhere), or a path the caller names — "ref", "pallas" or
+"pallas_interpret" (the kernels in interpret mode, a CPU check).
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
 """Pure-jnp reference oracles for the Pallas kernels.
 
 These are the correctness ground truth (kernels assert_allclose against
-them) AND the CPU/dry-run execution path (`use_pallas=False`).
+them) AND the execution path of every non-TPU backend
+(``core.policy.platform_kernel_impl``).
 
 Contracts (HEAD-MAJOR decode layouts — the decode-path invariant: no
 cache-sized transpose or copy; every decode-time access below is a
@@ -37,6 +38,12 @@ import jax.numpy as jnp
 
 from repro.models.common import NEG_INF
 
+# f32 decode math at full f32 precision: on a TPU a DEFAULT-precision f32
+# matmul rounds its operands to bf16 (one MXU pass), which would truncate
+# the softmax weights; the Pallas kernels use the same precision, so the
+# two paths differ only in f32 accumulation order (no-op on the CPU)
+F32 = jax.lax.Precision.HIGHEST
+
 
 def _deq(g: jnp.ndarray, scales: Optional[jnp.ndarray], idx: jnp.ndarray,
          block_size: int) -> jnp.ndarray:
@@ -70,14 +77,15 @@ def sparse_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
     vg = _deq(vg, v_scales, idx, block_size)
 
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32)) * scale
+                    kg.astype(jnp.float32), precision=F32) * scale
     valid = (block_indices[..., None] >= 0) & (pos < kv_len[:, None, None, None])
     valid = valid.reshape(b, hkv, 1, nsel * block_size)
     sc = jnp.where(valid, sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     # guard rows with zero valid keys (shouldn't happen: last block forced)
     p = jnp.where(jnp.any(valid, axis=-1, keepdims=True), p, 0.0)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, vg.astype(jnp.float32))
+    o = jnp.einsum("bhgk,bhkd->bhgd", p, vg.astype(jnp.float32),
+                   precision=F32)
     return o.astype(q.dtype)
 
 
@@ -124,13 +132,14 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     # token positions are LOGICAL (masking against kv_len)
     pos = idx[..., None] * ps + jnp.arange(ps)                   # [B,Hkv,nsel,ps]
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32)) * scale
+                    kg.astype(jnp.float32), precision=F32) * scale
     valid = (block_indices[..., None] >= 0) & (pos < kv_len[:, None, None, None])
     valid = valid.reshape(b, hkv, 1, nsel * ps)
     sc = jnp.where(valid, sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     p = jnp.where(jnp.any(valid, axis=-1, keepdims=True), p, 0.0)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, vg.astype(jnp.float32))
+    o = jnp.einsum("bhgk,bhkd->bhgd", p, vg.astype(jnp.float32),
+                   precision=F32)
     return o.astype(q.dtype)
 
 
@@ -195,13 +204,14 @@ def paged_sparse_decode_splitk_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
         & (pos < kv_len[:, None, None, None, None])
     valid = valid.reshape(b, hkv, num_splits, 1, per * ps)
     sc = jnp.einsum("bhgd,bhskd->bhsgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32)) * scale
+                    kg.astype(jnp.float32), precision=F32) * scale
     sc = jnp.where(valid, sc, NEG_INF)
 
     m_s = jnp.max(sc, axis=-1, keepdims=True)            # [B,Hkv,NS,G,1]
     p = jnp.where(sc > NEG_INF / 2, jnp.exp(sc - m_s), 0.0)
     l_s = jnp.sum(p, axis=-1, keepdims=True)
-    acc_s = jnp.einsum("bhsgk,bhskd->bhsgd", p, vg.astype(jnp.float32))
+    acc_s = jnp.einsum("bhsgk,bhskd->bhsgd", p, vg.astype(jnp.float32),
+                       precision=F32)
 
     m = jnp.max(m_s, axis=2, keepdims=True)              # over splits
     rescale = jnp.where(l_s > 0, jnp.exp(m_s - m), 0.0)
@@ -217,11 +227,12 @@ def dense_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     b, hkv, g, dh = q.shape
     s = k_cache.shape[2]
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    k_cache.astype(jnp.float32)) / math.sqrt(dh)
+                    k_cache.astype(jnp.float32), precision=F32) / math.sqrt(dh)
     valid = (jnp.arange(s)[None, :] < kv_len[:, None])[:, None, None, :]
     sc = jnp.where(valid, sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32),
+                   precision=F32)
     return o.astype(q.dtype)
 
 

@@ -137,7 +137,7 @@ def cell_fn_and_specs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
         # telemetry off: the dry-run probes cost the decode DATA PATH,
         # matching the bench_decode hot-path discipline
         opts = default_options(cfg).replace(
-            kernel_impl=os.environ.get("REPRO_SERVE_IMPL", "ref"),
+            kernel_impl=os.environ.get("REPRO_SERVE_IMPL"),
             measure_sparsity=False)
 
         def serve_step(params, state, token):

@@ -26,7 +26,8 @@ from repro.core import attngate as ag
 from repro.core import kcache as kc
 from repro.core import sparsity as sp
 from repro.core.policy import (STAGE_DENSE, STAGE_SELECT, DecodeOptions,
-                               SelectionInputs, select_impl)
+                               SelectionInputs, platform_kernel_impl,
+                               select_impl)
 from repro.kernels import ops
 from repro.models import moe as moe_mod
 from repro.models.common import (apply_rope, decode_attention, linear, mlp,
@@ -218,7 +219,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 cfg=cfg.gate, rope_theta=cfg.rope_theta,
                 max_selected=options.max_selected(cfg),
                 budget_blocks=budget_blocks, split_k=options.split_k,
-                inner_impl="pallas" if cfg.use_pallas else "ref",
+                inner_impl=platform_kernel_impl(),
                 k_scale=k_scale, v_scale=v_scale, **plan_kw)
         new_len = cur_len + active.astype(jnp.int32)
         aux = (_selection_aux(idx, kc.visible_blocks(
@@ -288,7 +289,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         def _fresh(cur):
             del cur
             return policy.select(
-                inp, cfg, impl=select_impl(options.kernel_impl),
+                inp, cfg, impl=select_impl(options.impl),
                 max_selected=options.max_selected(cfg),
                 unify_heads=options.schedule.unify_heads).astype(jnp.int32)
 
@@ -304,7 +305,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         def _run_sparse(_):
             o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx,
                                         pt_kv, new_len, block_size=ps,
-                                        impl=options.kernel_impl,
+                                        impl=options.impl,
                                         k_scales=k_scale, v_scales=v_scale)
             return o.reshape(b, 1, hkv * g, dh)
 
@@ -338,7 +339,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                               k_pages=k_pages, page_table=page_table,
                               kmin_pages=kmin_pages, kmax_pages=kmax_pages,
                               k_scale_pages=k_scale)
-        idx = policy.select(inp, cfg, impl=select_impl(options.kernel_impl),
+        idx = policy.select(inp, cfg, impl=select_impl(options.impl),
                             max_selected=options.max_selected(cfg),
                             unify_heads=options.schedule.unify_heads)
         if budget_blocks is not None:
@@ -348,7 +349,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
         o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx, pt_kv,
                                     new_len, block_size=ps,
-                                    impl=options.kernel_impl,
+                                    impl=options.impl,
                                     k_scales=k_scale, v_scales=v_scale)
         o = o.reshape(b, 1, hkv * g, dh)
         aux = (_selection_aux(idx, kc.visible_blocks(

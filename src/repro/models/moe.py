@@ -157,16 +157,10 @@ def moe_mlp_sharded(p: Params, x: jnp.ndarray, mcfg: MoEConfig,
                     activation: str, mesh, ep_major: bool = False
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
 
-        def smap(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm2
-
-        def smap(f, in_specs, out_specs):
-            return _sm2(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    def smap(f, in_specs, out_specs):
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs)
 
     t, d = x.shape
     e, k, f = mcfg.n_experts, mcfg.top_k, mcfg.expert_d_ff

@@ -492,7 +492,7 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         def _fresh(cur):
             del cur
             return policy.select(
-                inp, cfg, impl=select_impl(options.kernel_impl),
+                inp, cfg, impl=select_impl(options.impl),
                 max_selected=options.max_selected(cfg),
                 unify_heads=options.schedule.unify_heads).astype(jnp.int32)
 
@@ -501,7 +501,7 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
 
         def _run_sparse(_):
             o = ops.sparse_decode(qgrp, k_cache, v_cache, idx, new_len,
-                                  block_size=bs, impl=options.kernel_impl)
+                                  block_size=bs, impl=options.impl)
             return o.reshape(b, 1, hkv * g, dh)
 
         def _run_dense(_):
@@ -544,12 +544,12 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                               gate_params=p.get("gate"), kg=kg_cache,
                               k_cache=k_cache, meta_kmin=meta_kmin,
                               meta_kmax=meta_kmax)
-        idx = policy.select(inp, cfg, impl=select_impl(options.kernel_impl),
+        idx = policy.select(inp, cfg, impl=select_impl(options.impl),
                             max_selected=options.max_selected(cfg),
                             unify_heads=options.schedule.unify_heads)
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
         o = ops.sparse_decode(qgrp, k_cache, v_cache, idx, new_len,
-                              block_size=bs, impl=options.kernel_impl)
+                              block_size=bs, impl=options.impl)
         o = o.reshape(b, 1, hkv * g, dh)
         aux = (_selection_aux(idx, kc.visible_blocks(
                    jnp.maximum(new_len, 1), bs), k_cache.shape[2] // bs)

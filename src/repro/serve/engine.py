@@ -77,6 +77,7 @@ class DecodeEngine:
                 f"loop directly instead of DecodeEngine")
         self.max_len = max_len
         self.options = options if options is not None else default_options(cfg)
+        self.options.check_platform()
         self.shard = shard          # mesh-aware: enables kernel_impl="sharded"
         # the decode state is donated: KV/Kg cache updates alias in place
         self._step = jax.jit(functools.partial(
@@ -828,6 +829,9 @@ class DecodeEngine:
             "peak_pages_used": (sched.allocator.num_pages - 1
                                 - sched.allocator.min_free),
             "num_pages": num_pages, "page_size": ps,
+            # devices the K/V page pool spans (the kv-head shards of the
+            # paged x sharded path; 1 unsharded)
+            "pool_devices": len(pages.k_pages.sharding.device_set),
             # bucketed-prefill jit cache (bounded: one program per
             # power-of-two page count ever seen by this engine)
             "prefill_jit_programs": len(self._prefill_jit),

@@ -36,19 +36,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import GateConfig
 from repro.core import sparsity as sp
+from repro.core.policy import select_impl
 from repro.distributed.sharding import MODEL
 from repro.models.common import NEG_INF, apply_rope
-
-try:  # JAX >= 0.6
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def _flat_axis_index(axes: Tuple[str, ...], sizes: Tuple[int, ...]):
@@ -228,8 +218,8 @@ def sharded_sparse_decode(
         n_sel = jax.lax.psum(n_sel, seq) if nsh > 1 else n_sel
         return o.astype(qr.dtype), k_loc, v_loc, kg_loc, n_sel
 
-    fn = shard_map(
-        local, mesh,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(spec_qg, spec_q, P(bspec, None, None), P(bspec, None, None),
                   spec_kv, spec_kv, spec_kv, spec_len, spec_w),
         out_specs=(spec_q, spec_kv, spec_kv, spec_kv, P(bspec, None)))
@@ -283,8 +273,9 @@ def sharded_paged_decode(
 
     Within a shard the selected list is reduced by the split-K kernel when
     ``split_k > 1`` (``ops.paged_sparse_decode_splitk``) — the in-shard
-    analog of the paper's num_split — with ``inner_impl`` picking jnp ref
-    (CPU) or the Pallas kernel (TPU).
+    analog of the paper's num_split — with ``inner_impl`` picking the
+    selection and attention kernels each shard runs (the platform's:
+    ``core.policy.platform_kernel_impl``).
 
     Returns (o [S,Hkv,G,Dh], k_pages, v_pages, kg_pages, k_scale, v_scale,
     idx [S,Hkv,k]) with pools updated in place (same shardings); ``idx``
@@ -375,7 +366,7 @@ def sharded_paged_decode(
         new_len = cl + act.astype(jnp.int32)
         n_valid = kc.visible_blocks(jnp.maximum(new_len, 1), cfg.block_size)
         idx = ops.gate_select_paged(qg, kgp, pt, n_valid, cfg, max_selected,
-                                    impl="ref")
+                                    impl=select_impl(inner_impl))
         if extra:
             reuse, do_sel = extra
             idx = jnp.where(do_sel, idx, reuse)
@@ -406,7 +397,12 @@ def sharded_paged_decode(
         args = args + (reuse_idx, jnp.asarray(do_select, bool))
     out_specs = (spec_h4, spec_h4, spec_h4, spec_h3) \
         + ((spec_h3, spec_h3) if quant else ()) + (spec_h3,)
-    fn = shard_map(local, mesh, in_specs=in_specs, out_specs=out_specs)
+    # check_vma=False: the per-shard Pallas kernels declare plain output
+    # shapes, which carry no varying-manual-axes type; every output here
+    # is head-sharded (out_specs name 'model'), so nothing is replicated
+    # that the type check would have to prove
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(*args)
     if quant:
         o, k_pages, v_pages, kg_pages, k_scale, v_scale, idx = out

@@ -161,8 +161,9 @@ def capture_sharded():
     from repro.data.pipeline import DataState, make_batch
     from repro.models import transformer as tf
     from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = sharded_cfg()
     params = tf.init_lm(jax.random.PRNGKey(PARAM_SEED), cfg)
     batch = {"tokens": make_batch(cfg, SHARDED_B, SHARDED_PRE,

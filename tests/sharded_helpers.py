@@ -6,6 +6,8 @@ stays single-device (jax locks the device count at first init).
 """
 import sys
 
+from repro.launch.mesh import make_mesh
+
 
 def sharded_decode_parity():
     import dataclasses, functools
@@ -18,7 +20,7 @@ def sharded_decode_parity():
     from repro.models import transformer as tf
     from repro.distributed import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = reduced(configs.get("qwen3_0_6b"))
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, token_budget=64,
@@ -63,7 +65,7 @@ def sharded_decode_threshold_parity():
     from repro.models import transformer as tf
     from repro.distributed import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = reduced(configs.get("qwen3_0_6b"))
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, method="threshold",
@@ -106,7 +108,7 @@ def sharded_policy_golden():
 
     gold = np.load(os.path.join(os.path.dirname(__file__),
                                 "golden_policy.npz"))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = G.sharded_cfg()
     params = tf.init_lm(jax.random.PRNGKey(G.PARAM_SEED), cfg)
     batch = {"tokens": make_batch(cfg, G.SHARDED_B, G.SHARDED_PRE,
@@ -146,7 +148,7 @@ def paged_sharded_parity():
     from repro.models.registry import get_api
     from repro.serve.engine import DecodeEngine
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
     cfg = reduced(configs.get("qwen3_0_6b")).replace(dtype="float32")
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, token_budget=32))
@@ -211,7 +213,7 @@ def paged_sharded_quant_parity():
     from repro.models.registry import get_api
     from repro.serve.engine import DecodeEngine
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
     cfg = reduced(configs.get("qwen3_0_6b")).replace(dtype="float32")
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, token_budget=32))
@@ -268,7 +270,7 @@ def paged_sharded_schedule_parity():
     from repro.models.registry import get_api
     from repro.serve.engine import DecodeEngine
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
     cfg = reduced(configs.get("qwen3_0_6b")).replace(dtype="float32")
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, token_budget=32))
@@ -325,7 +327,7 @@ def paged_sharded_eviction_parity():
     from repro.serve.engine import DecodeEngine
     from repro.serve.eviction import EvictionConfig
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
     cfg = reduced(configs.get("qwen3_0_6b")).replace(dtype="float32")
     cfg = cfg.replace(gate=dataclasses.replace(
         cfg.gate, block_size=8, d_gate=16, token_budget=16))
@@ -379,7 +381,7 @@ def paged_sharded_hybrid_parity():
     from repro.models.registry import get_api
     from repro.serve.engine import DecodeEngine
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
     # num_layers=3 with period 2 -> 1 unit + 1 trailing mamba layer
     cfg = reduced(configs.get("zamba2_1_2b"),
                   num_layers=3).replace(dtype="float32")
@@ -433,7 +435,7 @@ def moe_sharded_parity():
     from repro.models import moe as moe_mod
     from repro.distributed import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     D, E, K, F = 32, 8, 2, 64
     mcfg = MoEConfig(n_experts=E, top_k=K, n_shared_experts=1,
                      expert_d_ff=F, capacity_factor=8.0)
@@ -461,7 +463,7 @@ def moe_sharded_grads():
     from repro.models import moe as moe_mod
     from repro.distributed import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     D, E, K, F = 32, 8, 2, 64
     mcfg = MoEConfig(n_experts=E, top_k=K, expert_d_ff=F, capacity_factor=8.0)
     p = moe_mod.init_moe(jax.random.PRNGKey(0), D, mcfg, "swiglu", "float32")

@@ -78,7 +78,8 @@ def test_moe_sharded_grads():
 # ---------------------------------------------------------------------------
 
 def _mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    return make_local_mesh()
 
 
 def test_sanitize_spec_drops_nondivisible():

@@ -537,6 +537,24 @@ def test_decode_options_hashable_and_validated():
     assert default_options(cfg) == DecodeOptions()
 
 
+def test_kernel_impl_follows_platform():
+    """The default decode path is the platform's own (jnp on this CPU
+    backend); the compiled TPU kernels are refused off a TPU instead of
+    quietly falling back to interpret mode or the jnp path."""
+    assert jax.default_backend() != "tpu"
+    assert DecodeOptions().kernel_impl is None
+    assert DecodeOptions().impl == pol.platform_kernel_impl() == "ref"
+    assert DecodeOptions(kernel_impl="pallas_interpret").impl == \
+        "pallas_interpret"
+    cfg = G.tiny_cfg()
+    _, params, _ = _params_and_prompt(cfg)
+    with pytest.raises(ValueError, match="compiled TPU kernels"):
+        DecodeEngine(cfg, params, max_len=G.MAX_LEN,
+                     options=DecodeOptions(kernel_impl="pallas"))
+    DecodeEngine(cfg, params, max_len=G.MAX_LEN,
+                 options=DecodeOptions(kernel_impl="pallas_interpret"))
+
+
 def test_engine_budget_override_static():
     """budget_override in the OPTIONS (static, recompiles) narrows the
     compiled selection width end to end."""

@@ -219,7 +219,11 @@ def test_serve_quant_int8_close_to_fp(options):
     the per-page abs-max quantization budget (~1.5% of the logit scale on
     this config; bound set at 0.05 with headroom). Covers the gate policy
     (Kg finalize from dequantized keys) and Quest (min/max metadata from
-    dequantized keys + dequantized trailing-block recompute)."""
+    dequantized keys + dequantized trailing-block recompute).
+
+    The logits are compared over each request's common greedy prefix:
+    once a near-tie argmax flips, the two engines decode different
+    sequences and their later logits measure that, not quantization."""
     cfg = _tiny_cfg()
     api = get_api(cfg)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
@@ -231,8 +235,12 @@ def test_serve_quant_int8_close_to_fp(options):
     assert res_q["stats"]["retired"] == len(reqs)
     for r in reqs:
         rid = r["rid"]
+        assert res_q[rid][0] == res_fp[rid][0], f"rid {rid}: first token"
         a, b = res_fp["logits"][rid], res_q["logits"][rid]
         n = min(len(a), len(b))
+        diverged = np.nonzero(np.asarray(res_fp[rid][:n])
+                              != np.asarray(res_q[rid][:n]))[0]
+        n = int(diverged[0]) if diverged.size else n
         d = float(np.max(np.abs(a[:n] - b[:n])))
         assert d <= 0.05, f"rid {rid}: int8 logit drift {d}"
 
