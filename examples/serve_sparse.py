@@ -5,8 +5,7 @@ models) end to end.
     PYTHONPATH=src python examples/serve_sparse.py [--arch qwen3_0_6b]
         [--budget 128] [--method budget|threshold] [--batch 4] [--new 64]
         [--policy gate|quest|oracle|sliding_window] [--temperature 0]
-        [--top-p 1.0] [--paged] [--full-width]
-        [--kernel-impl ref|pallas|pallas_interpret]
+        [--top-p 1.0] [--paged]
 
 Default: one uniform batch through ``DecodeEngine.generate``. With
 ``--paged``, ragged requests (mixed prompt lengths and decode budgets) go
@@ -19,13 +18,8 @@ budget, applied as a runtime mask). Decode behavior is one
 ``--temperature``/``--top-p`` switch greedy to stochastic sampling.
 Either way the trailing partial block is force-selected
 (K-compression-cache semantics) and the engine reports MEASURED achieved
-sparsity + derived I/O economics.
-
-By default the model is the arch's reduced CPU-sized twin; ``--full-width``
-serves the published config (gate block and d_gate included — run it on a
-TPU). The decode kernels follow the platform unless ``--kernel-impl``
-names a path; compiles persist in JAX's compilation cache
-(``repro.launch.compile_cache``).
+sparsity + derived I/O economics. Compiles persist in JAX's compilation
+cache (``repro.launch.compile_cache``).
 """
 import argparse
 import dataclasses
@@ -87,26 +81,16 @@ def main():
                          "block-sparse kernels — ~4x smaller pool and "
                          "swap traffic at decode-realistic accuracy "
                          "(see docs/ARCHITECTURE.md section 8)")
-    ap.add_argument("--full-width", action="store_true",
-                    help="serve the published config instead of its "
-                         "reduced twin (TPU-sized)")
-    ap.add_argument("--kernel-impl", default=None,
-                    choices=["ref", "pallas", "pallas_interpret"],
-                    help="decode kernels (default: the platform's — "
-                         "compiled Pallas on a TPU, jnp elsewhere)")
     args = ap.parse_args()
     enable_compile_cache()
 
-    cfg = configs.get(args.arch)
+    cfg = reduced(configs.get(args.arch))
     if not (cfg.gate.enabled and cfg.has_attention and cfg.is_decoder):
         raise SystemExit(f"{args.arch}: no decode gate (family {cfg.family}) "
                          "— pick a gated arch for this example")
-    gate_kw = dict(method=args.method, token_budget=args.budget,
-                   threshold=args.threshold)
-    if not args.full_width:
-        cfg = reduced(cfg)
-        gate_kw.update(block_size=16, d_gate=16)
-    cfg = cfg.replace(gate=dataclasses.replace(cfg.gate, **gate_kw))
+    cfg = cfg.replace(gate=dataclasses.replace(
+        cfg.gate, block_size=16, d_gate=16, method=args.method,
+        token_budget=args.budget, threshold=args.threshold))
 
     params = get_api(cfg).init_params(jax.random.PRNGKey(0), cfg)
     max_len = args.prefill + args.new + 16
@@ -114,7 +98,6 @@ def main():
         raise SystemExit("--quantize needs --paged (pools are paged-only)")
     opts = DecodeOptions(
         policy=get_policy(args.policy),
-        kernel_impl=args.kernel_impl,
         quantize=args.quantize,
         sampling=SamplingParams(temperature=args.temperature,
                                 top_p=args.top_p))

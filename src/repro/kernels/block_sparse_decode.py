@@ -62,9 +62,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import F32, qk_precision   # the ref's dot precisions
+
 NEG_INF = -1e30
 LANES = 128
-F32 = jax.lax.Precision.HIGHEST   # full-f32 MXU dots (ref.F32: same math)
+
+
+def out_vma(*xs) -> frozenset:
+    """The mesh axes a kernel's output varies over: those of its operands.
+    Inside a ``shard_map`` (with its replication check on) a Pallas output
+    must declare them; elsewhere the set is empty and unused."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
 def _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs,
@@ -92,13 +100,15 @@ def _flash_accum(idxs, b, j, len_ref, q_ref, k_refs, v_refs,
     @pl.when(gmax >= 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                    # [G_pad, Dh]
+        qk_prec = qk_precision(q_ref.dtype, k_refs[0].dtype,
+                               k_scales is not None)
         scores = []
         for i in range(C):
             k = k_refs[i][0, 0].astype(jnp.float32)            # [bs, Dh]
             if k_scales is not None:
                 k = k * k_scales[i]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    precision=F32,
+                                    precision=qk_prec,
                                     preferred_element_type=jnp.float32) * scale
             pos = idxs[i] * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
@@ -294,7 +304,8 @@ def block_sparse_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
                           block_size=block_size, n_groups=n_groups,
                           blocks_per_step=c, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, hkv, g_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, hkv, g_pad, dh), q.dtype,
+                                       vma=out_vma(q, k_cache, v_cache)),
         interpret=interpret,
     )(*prefetch, qp, *([k_cache] * c), *([v_cache] * c))
     return out[:, :, :g]
@@ -373,7 +384,8 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                           block_size=block_size,
                           n_groups=n_groups, blocks_per_step=c, scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, hkv, g_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, hkv, g_pad, dh), q.dtype,
+                                       vma=out_vma(q, k_pages, v_pages)),
         interpret=interpret,
     )(*prefetch, qp, *([k_pages] * c), *([v_pages] * c))
     return out[:, :, :g]
@@ -506,12 +518,10 @@ def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
             n_groups=n_groups, blocks_per_step=c, scale=scale,
             per_pad=per_pad),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((bsz, hkv, ns, g_pad, dh),
-                                        jnp.float32),
-                   jax.ShapeDtypeStruct((bsz, hkv, ns, g_pad, LANES),
-                                        jnp.float32),
-                   jax.ShapeDtypeStruct((bsz, hkv, ns, g_pad, LANES),
-                                        jnp.float32)),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct((bsz, hkv, ns, g_pad, w), jnp.float32,
+                                 vma=out_vma(q, k_pages, v_pages))
+            for w in (dh, LANES, LANES)),
         interpret=interpret,
     )(*prefetch, qp, *([k_pages] * c), *([v_pages] * c))
 

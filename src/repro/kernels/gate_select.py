@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.config import GateConfig
 from repro.core import sparsity as sp
+from repro.kernels.block_sparse_decode import out_vma
 from repro.models.common import NEG_INF
 
 LANES = 128
@@ -178,7 +179,8 @@ def fused_gate_select(qg: jnp.ndarray, kg: jnp.ndarray, n_valid: jnp.ndarray,
             force_first=bool(cfg.always_first_block),
             force_last=bool(cfg.always_last_block), scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, k_sel), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, k_sel), jnp.int32,
+                                       vma=out_vma(qg, kg)),
         interpret=interpret,
     )(n_valid.astype(jnp.int32), qg, kg)
 
@@ -290,7 +292,8 @@ def fused_gate_select_paged(qg: jnp.ndarray, kg_pages: jnp.ndarray,
             force_first=bool(cfg.always_first_block),
             force_last=bool(cfg.always_last_block), scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hkv, k_sel), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((s, hkv, k_sel), jnp.int32,
+                                       vma=out_vma(qg, kg_pages)),
         interpret=interpret,
     )(page_table.astype(jnp.int32), n_valid.astype(jnp.int32), qg,
       *([kg_pages] * c))

@@ -38,11 +38,22 @@ import jax.numpy as jnp
 
 from repro.models.common import NEG_INF
 
-# f32 decode math at full f32 precision: on a TPU a DEFAULT-precision f32
-# matmul rounds its operands to bf16 (one MXU pass), which would truncate
-# the softmax weights; the Pallas kernels use the same precision, so the
-# two paths differ only in f32 accumulation order (no-op on the CPU)
+# Decode dot precision. On a TPU a DEFAULT-precision f32 matmul rounds its
+# operands to bf16 (one MXU pass). That is exact for q.k when q and k are
+# both bf16 values (the fp serving path), so that dot keeps the one pass;
+# dequantized int8 keys, f32 caches and the f32 softmax weights of p.v
+# need full f32 (HIGHEST). The Pallas kernels pick the same precisions, so
+# the two paths differ only in f32 accumulation order. Precision is a
+# no-op on the CPU.
 F32 = jax.lax.Precision.HIGHEST
+
+
+def qk_precision(q_dtype, k_dtype, dequantized: bool) -> jax.lax.Precision:
+    """Precision of the q.k dot: one bf16 pass where it is exact (bf16 q
+    and k, no dequant scale), full f32 otherwise."""
+    exact = (not dequantized and jnp.dtype(q_dtype) == jnp.bfloat16
+             and jnp.dtype(k_dtype) == jnp.bfloat16)
+    return jax.lax.Precision.DEFAULT if exact else F32
 
 
 def _deq(g: jnp.ndarray, scales: Optional[jnp.ndarray], idx: jnp.ndarray,
@@ -77,7 +88,9 @@ def sparse_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
     vg = _deq(vg, v_scales, idx, block_size)
 
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32), precision=F32) * scale
+                    kg.astype(jnp.float32),
+                    precision=qk_precision(q.dtype, k_cache.dtype,
+                                           k_scales is not None)) * scale
     valid = (block_indices[..., None] >= 0) & (pos < kv_len[:, None, None, None])
     valid = valid.reshape(b, hkv, 1, nsel * block_size)
     sc = jnp.where(valid, sc, NEG_INF)
@@ -132,7 +145,9 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     # token positions are LOGICAL (masking against kv_len)
     pos = idx[..., None] * ps + jnp.arange(ps)                   # [B,Hkv,nsel,ps]
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32), precision=F32) * scale
+                    kg.astype(jnp.float32),
+                    precision=qk_precision(q.dtype, k_pages.dtype,
+                                           k_scales is not None)) * scale
     valid = (block_indices[..., None] >= 0) & (pos < kv_len[:, None, None, None])
     valid = valid.reshape(b, hkv, 1, nsel * ps)
     sc = jnp.where(valid, sc, NEG_INF)
@@ -204,7 +219,9 @@ def paged_sparse_decode_splitk_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
         & (pos < kv_len[:, None, None, None, None])
     valid = valid.reshape(b, hkv, num_splits, 1, per * ps)
     sc = jnp.einsum("bhgd,bhskd->bhsgk", q.astype(jnp.float32),
-                    kg.astype(jnp.float32), precision=F32) * scale
+                    kg.astype(jnp.float32),
+                    precision=qk_precision(q.dtype, k_pages.dtype,
+                                           k_scales is not None)) * scale
     sc = jnp.where(valid, sc, NEG_INF)
 
     m_s = jnp.max(sc, axis=-1, keepdims=True)            # [B,Hkv,NS,G,1]
@@ -227,7 +244,9 @@ def dense_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     b, hkv, g, dh = q.shape
     s = k_cache.shape[2]
     sc = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32),
-                    k_cache.astype(jnp.float32), precision=F32) / math.sqrt(dh)
+                    k_cache.astype(jnp.float32),
+                    precision=qk_precision(q.dtype, k_cache.dtype,
+                                           False)) / math.sqrt(dh)
     valid = (jnp.arange(s)[None, :] < kv_len[:, None])[:, None, None, :]
     sc = jnp.where(valid, sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)

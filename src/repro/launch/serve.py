@@ -1,21 +1,16 @@
 """Sparse-decode serving launcher.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3_0_6b [--reduced] \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3_0_6b --reduced \
         [--batch 4] [--prefill 256] [--new 64] [--budget 128]
         [--method budget|threshold] [--dense]
         [--policy gate|quest|oracle|sliding_window]
-        [--paged] [--kernel-impl ref|pallas|pallas_interpret]
 
 Runs prefill + autoregressive decode through the SeerAttention-R engine
 (KV cache + K-compression cache + selection policy + block-sparse
 attention) and reports throughput and MEASURED achieved sparsity.
 --policy swaps the block-selection strategy (core.policy); --dense
-disables selection entirely for an A/B reference. --paged serves the
-batch as requests through the paged engine (``DecodeEngine.serve``)
-instead of the uniform-batch ``generate``. The decode kernels follow the
-platform (compiled Pallas on a TPU, jnp elsewhere) unless --kernel-impl
-names a path. Compiles persist in JAX's compilation cache
-(``repro.launch.compile_cache``).
+disables selection entirely for an A/B reference. Compiles persist in
+JAX's compilation cache (``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -47,9 +42,6 @@ def main():
     ap.add_argument("--policy", default="gate",
                     choices=["gate", "quest", "quest_recompute", "oracle",
                              "sliding_window"])
-    ap.add_argument("--paged", action="store_true")
-    ap.add_argument("--kernel-impl", default=None,
-                    choices=["ref", "pallas", "pallas_interpret"])
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -69,8 +61,7 @@ def main():
     # distilled gate; only GatePolicy needs cfg.gate.enabled
     sparse = (not args.dense) and cfg.has_attention and cfg.is_decoder \
         and (cfg.gate.enabled or not pol.needs_gate)
-    opts = DecodeOptions(policy=pol if sparse else DensePolicy(),
-                         kernel_impl=args.kernel_impl)
+    opts = DecodeOptions(policy=pol if sparse else DensePolicy())
     params = get_api(cfg).init_params(jax.random.PRNGKey(0), cfg)
     max_len = args.prefill + args.new + 16
     batch = {"tokens": make_batch(cfg, args.batch, args.prefill,
@@ -80,23 +71,9 @@ def main():
             (args.batch, cfg.n_image_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
 
     eng = DecodeEngine(cfg, params, max_len=max_len, options=opts)
-    print(f"arch={cfg.arch_id} policy={args.policy if sparse else 'dense'} "
-          f"kernels={opts.impl} devices={jax.device_count()} "
-          f"({jax.devices()[0].device_kind})")
-    if args.paged:
-        reqs = [{"rid": i, "tokens": batch["tokens"][i],
-                 "max_new_tokens": args.new} for i in range(args.batch)]
-        st = eng.serve(reqs, n_slots=args.batch)["stats"]
-        print(f"serve: {st['generated_tokens']} tokens in "
-              f"{st['wall_s']:.2f} s ({st['tok_per_s']:.1f} tok/s), "
-              f"retired {st['retired_clean']}/{args.batch} clean, "
-              f"errors {st['errors']}")
-        if sparse:
-            print("measured sparsity by request: "
-                  + ", ".join(f"{rid}: {rho:.3f}" for rid, rho in
-                              sorted(st["sparsity_by_rid"].items())))
-        return
     res = eng.generate(batch, args.new)
+    print(f"arch={cfg.arch_id} policy={args.policy if sparse else 'dense'} "
+          f"devices={jax.device_count()}")
     print(f"prefill: {res['prefill_s'] * 1e3:.1f} ms | decode: "
           f"{res['decode_s'] * 1e3:.1f} ms | {res['tok_per_s']:.1f} tok/s")
     if sparse:

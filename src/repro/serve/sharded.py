@@ -397,12 +397,10 @@ def sharded_paged_decode(
         args = args + (reuse_idx, jnp.asarray(do_select, bool))
     out_specs = (spec_h4, spec_h4, spec_h4, spec_h3) \
         + ((spec_h3, spec_h3) if quant else ()) + (spec_h3,)
-    # check_vma=False: the per-shard Pallas kernels declare plain output
-    # shapes, which carry no varying-manual-axes type; every output here
-    # is head-sharded (out_specs name 'model'), so nothing is replicated
-    # that the type check would have to prove
+    # the replication check stays on: the per-shard Pallas kernels declare
+    # the mesh axes their outputs vary over (kernels' ``out_vma``)
     fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+                       out_specs=out_specs)
     out = fn(*args)
     if quant:
         o, k_pages, v_pages, kg_pages, k_scale, v_scale, idx = out

@@ -24,6 +24,7 @@ import pytest
 
 import repro.configs as configs
 from repro.config import reduced
+from repro.core import metacache as mc
 from repro.core.policy import DecodeOptions, QuestPolicy
 from repro.kernels import ops
 from repro.serve import paging as pg
@@ -212,18 +213,28 @@ def _serve(cfg, params, reqs, options=None, **kw):
 
 @pytest.mark.parametrize("options", [
     DecodeOptions(quantize="int8"),
-    DecodeOptions(quantize="int8", policy=QuestPolicy()),
+    DecodeOptions(quantize="int8", policy=QuestPolicy(), budget_override=64),
 ], ids=["gate", "quest"])
 def test_serve_quant_int8_close_to_fp(options):
     """Decode-realistic parity: int8 pools track the fp engine to within
     the per-page abs-max quantization budget (~1.5% of the logit scale on
     this config; bound set at 0.05 with headroom). Covers the gate policy
     (Kg finalize from dequantized keys) and Quest (min/max metadata from
-    dequantized keys + dequantized trailing-block recompute).
+    dequantized keys + dequantized trailing-block recompute, scored every
+    step).
 
-    The logits are compared over each request's common greedy prefix:
-    once a near-tie argmax flips, the two engines decode different
-    sequences and their later logits measure that, not quantization."""
+    Each request is compared through the first row whose greedy token
+    differs: row i holds the logits that chose token i, from a context
+    (tokens 0..i-1) that both engines share up to that row.
+
+    Quest runs at a budget covering every visible block. At the 32-token
+    budget its int8 selection can differ from fp on a near-tie: request 2's
+    first sparse step (5 visible blocks, 4 kept) had fp Quest bounds 20.2724
+    and 20.2667 for blocks 1 and 3 in layer 0, head 0; the int8 metadata
+    moved block 3's bound by 0.009 and the step read block 3 instead of 1,
+    moving that row's logits by 0.37 (earlier rows <= 0.005). A swapped
+    block is not quantization error; ``test_trailing_meta_int8_within_half_step``
+    bounds the metadata itself."""
     cfg = _tiny_cfg()
     api = get_api(cfg)
     params = api.init_params(jax.random.PRNGKey(0), cfg)
@@ -235,14 +246,35 @@ def test_serve_quant_int8_close_to_fp(options):
     assert res_q["stats"]["retired"] == len(reqs)
     for r in reqs:
         rid = r["rid"]
-        assert res_q[rid][0] == res_fp[rid][0], f"rid {rid}: first token"
         a, b = res_fp["logits"][rid], res_q["logits"][rid]
         n = min(len(a), len(b))
         diverged = np.nonzero(np.asarray(res_fp[rid][:n])
                               != np.asarray(res_q[rid][:n]))[0]
-        n = int(diverged[0]) if diverged.size else n
+        n = int(diverged[0]) + 1 if diverged.size else n
+        assert n >= 3, f"rid {rid}: tokens diverge at row {n - 1}"
         d = float(np.max(np.abs(a[:n] - b[:n])))
         assert d <= 0.05, f"rid {rid}: int8 logit drift {d}"
+
+
+def test_trailing_meta_int8_within_half_step():
+    """Quest's per-step trailing-block metadata on an int8 pool (dequantized
+    page -> key min/max) stays within half a quantization step of the fp
+    page's metadata, per (page, KV head): the selection inputs carry only
+    quantization error."""
+    rng = np.random.default_rng(4)
+    npool, hkv, ps, dh = 5, 2, 8, 16
+    kp = jnp.asarray(rng.normal(size=(npool, hkv, ps, dh)), jnp.float32)
+    kq, ksc = pg.quantize_block(kp, jnp.ones_like(kp, bool))
+    table = jnp.asarray([[1, 3, 0], [2, 4, 0]], jnp.int32)
+    cur_len = jnp.asarray([13, 5], jnp.int32)   # partial pages 3 and 2
+    fmin, fmax, fidx = mc.trailing_meta_paged(kp, table, cur_len, ps)
+    qmin, qmax, qidx = mc.trailing_meta_paged(kq, table, cur_len, ps,
+                                              k_scale=ksc)
+    np.testing.assert_array_equal(np.asarray(fidx), np.asarray(qidx))
+    phys = np.asarray(table)[np.arange(2), np.asarray(fidx)]
+    half = np.asarray(ksc)[phys] / 2 + 1e-6                  # [S, Hkv, 1]
+    assert np.all(np.abs(np.asarray(qmin) - np.asarray(fmin)) <= half)
+    assert np.all(np.abs(np.asarray(qmax) - np.asarray(fmax)) <= half)
 
 
 def test_serve_quant_preempt_swap_resume_bitwise():

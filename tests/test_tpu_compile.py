@@ -7,7 +7,9 @@ DESCRIBED v5e chip at the full qwen3_0_6b decode widths — no chip is
 needed, nothing runs, and each compile takes about two seconds. The
 topology is described inside a module fixture (never at import time), so
 every pytest-xdist worker collects the same tests and only the worker
-that runs this file loads the TPU compiler.
+that runs this file loads the TPU compiler. The paged x sharded layer
+body compiles over all four chips of the described v5e:2x2, with the
+Pallas kernels inside its ``shard_map``.
 """
 import dataclasses
 import os
@@ -15,6 +17,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as PS_
 from jax.sharding import SingleDeviceSharding
 
 import repro.configs as configs
@@ -23,6 +26,8 @@ from repro.kernels.block_sparse_decode import (
     block_sparse_decode_paged_splitk)
 from repro.kernels.gate_select import (fused_gate_select,
                                        fused_gate_select_paged)
+from repro.launch.mesh import make_mesh
+from repro.serve.sharded import sharded_paged_decode
 
 # qwen3_0_6b decode widths: 8 slots, 8 KV heads x GQA group 2, head_dim
 # 128, page == gate block 64, d_gate 128; a 2048-page pool, 512-entry
@@ -33,15 +38,25 @@ P, NPT, K = 2048, 512, GATE.token_budget // GATE.block_size
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no TPU compiler in this environment
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topology):
+    """The (1, 4) ("data", "model") mesh of the paged x sharded path."""
+    return make_mesh((1, 4), ("data", "model"), devices=topology.devices)
 
 
 def _sds(one_chip, shape, dtype):
@@ -116,3 +131,29 @@ def test_fused_gate_select_paged_compiles(one_chip, method, dtype):
              _sds(one_chip, (P, HKV, DG), dtype),
              _sds(one_chip, (B, NPT), jnp.int32),
              _sds(one_chip, (B,), jnp.int32))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_sharded_paged_decode_compiles(four_chips, quant):
+    """One layer's paged x sharded step (append, gate-select, block-sparse
+    attention) with the Pallas kernels inside its ``shard_map``, whose
+    replication check stays on: each kernel output declares the mesh axes
+    it varies over."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(four_chips, PS_()))
+    kv = jnp.int8 if quant else jnp.bfloat16
+    args = (sds((B, HKV, DG), jnp.bfloat16), sds((B, HKV, G, DH), jnp.bfloat16),
+            sds((B, HKV, DH), jnp.bfloat16), sds((B, HKV, DH), jnp.bfloat16),
+            sds((P, HKV, PS, DH), kv), sds((P, HKV, PS, DH), kv),
+            sds((P, HKV, DG), jnp.bfloat16), sds((B, NPT), jnp.int32),
+            sds((B,), jnp.int32), sds((B,), jnp.bool_),
+            sds((HKV, 3 * DH, DG), jnp.bfloat16))
+    scales = (sds((P, HKV, 1), jnp.float32),) * 2 if quant else ()
+
+    def fn(*a):
+        ks, vs = a[11:] or (None, None)
+        return sharded_paged_decode(*a[:11], mesh=four_chips, cfg=GATE,
+                                    rope_theta=1e6, inner_impl="pallas",
+                                    k_scale=ks, v_scale=vs)
+    _compile(fn, *args, *scales)
