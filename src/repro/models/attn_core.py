@@ -240,23 +240,24 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
     # selecting layers (cond on the stage id, below)
     gate_for_append = \
         p.get("gate") if (policy.needs_gate and not staged) else None
-    if k_scale is not None:
-        k_pages, v_pages, kg_pages, k_scale, v_scale = \
-            pg.append_token_paged_quant(
-                k_pages, v_pages, kg_pages, k_scale, v_scale, kr[:, 0],
-                v[:, 0], page_table, cur_len, active, gate_for_append,
-                cfg.gate, rope_theta=cfg.rope_theta)
-    else:
-        k_pages, v_pages, kg_pages = pg.append_token_paged(
-            k_pages, v_pages, kg_pages, kr[:, 0], v[:, 0], page_table,
-            cur_len, active, gate_for_append, cfg.gate,
-            rope_theta=cfg.rope_theta)
-    # ... and the min/max metadata page rows only for the policy that
-    # reads THEM (QuestPolicy): finalize a page's row when it fills
-    if policy.needs_meta and kmin_pages is not None and not staged:
-        kmin_pages, kmax_pages = pg.append_meta_paged(
-            kmin_pages, kmax_pages, k_pages, page_table, cur_len, active,
-            ps, k_scale=k_scale)
+    with jax.named_scope("kv_append"):
+        if k_scale is not None:
+            k_pages, v_pages, kg_pages, k_scale, v_scale = \
+                pg.append_token_paged_quant(
+                    k_pages, v_pages, kg_pages, k_scale, v_scale, kr[:, 0],
+                    v[:, 0], page_table, cur_len, active, gate_for_append,
+                    cfg.gate, rope_theta=cfg.rope_theta)
+        else:
+            k_pages, v_pages, kg_pages = pg.append_token_paged(
+                k_pages, v_pages, kg_pages, kr[:, 0], v[:, 0], page_table,
+                cur_len, active, gate_for_append, cfg.gate,
+                rope_theta=cfg.rope_theta)
+        # ... and the min/max metadata page rows only for the policy that
+        # reads THEM (QuestPolicy): finalize a page's row when it fills
+        if policy.needs_meta and kmin_pages is not None and not staged:
+            kmin_pages, kmax_pages = pg.append_meta_paged(
+                kmin_pages, kmax_pages, k_pages, page_table, cur_len,
+                active, ps, k_scale=k_scale)
     new_len = cur_len + active.astype(jnp.int32)
 
     if staged:
@@ -264,21 +265,23 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         do_select = stage == STAGE_SELECT             # traced bool scalar
         is_dense = stage == STAGE_DENSE
 
-        if policy.needs_gate and "gate" in p and kg_pages is not None:
-            kg_pages = jax.lax.cond(
-                do_select,
-                lambda kgp: pg.finalize_kg_paged(
-                    k_pages, kgp, page_table, cur_len, active, p["gate"],
-                    cfg.gate, rope_theta=cfg.rope_theta, k_scale=k_scale),
-                lambda kgp: kgp, kg_pages)
-        if policy.needs_meta and kmin_pages is not None:
-            def _adv_meta(mn, mx):
-                return pg.append_meta_paged(mn, mx, k_pages, page_table,
-                                            cur_len, active, ps,
-                                            k_scale=k_scale)
-            kmin_pages, kmax_pages = jax.lax.cond(
-                do_select, _adv_meta, lambda mn, mx: (mn, mx),
-                kmin_pages, kmax_pages)
+        with jax.named_scope("kv_append"):
+            if policy.needs_gate and "gate" in p and kg_pages is not None:
+                kg_pages = jax.lax.cond(
+                    do_select,
+                    lambda kgp: pg.finalize_kg_paged(
+                        k_pages, kgp, page_table, cur_len, active,
+                        p["gate"], cfg.gate, rope_theta=cfg.rope_theta,
+                        k_scale=k_scale),
+                    lambda kgp: kgp, kg_pages)
+            if policy.needs_meta and kmin_pages is not None:
+                def _adv_meta(mn, mx):
+                    return pg.append_meta_paged(mn, mx, k_pages, page_table,
+                                                cur_len, active, ps,
+                                                k_scale=k_scale)
+                kmin_pages, kmax_pages = jax.lax.cond(
+                    do_select, _adv_meta, lambda mn, mx: (mn, mx),
+                    kmin_pages, kmax_pages)
 
         inp = SelectionInputs(q_nope=q_nope, qr=qr, pos=pos, new_len=new_len,
                               gate_params=p.get("gate"), kg_pages=kg_pages,
@@ -293,13 +296,14 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 max_selected=options.max_selected(cfg),
                 unify_heads=options.schedule.unify_heads).astype(jnp.int32)
 
-        idx = jax.lax.cond(do_select, _fresh, lambda cur: cur, plan)
-        if budget_blocks is not None:
-            # the carried plan is already capped, so re-masking a reuse
-            # layer's idx is idempotent
-            slot_cap = jnp.arange(idx.shape[-1])[None, None, :] \
-                < budget_blocks[:, None, None]
-            idx = jnp.where(slot_cap, idx, -1)
+        with jax.named_scope("gate_select"):
+            idx = jax.lax.cond(do_select, _fresh, lambda cur: cur, plan)
+            if budget_blocks is not None:
+                # the carried plan is already capped, so re-masking a
+                # reuse layer's idx is idempotent
+                slot_cap = jnp.arange(idx.shape[-1])[None, None, :] \
+                    < budget_blocks[:, None, None]
+                idx = jnp.where(slot_cap, idx, -1)
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
 
         def _run_sparse(_):
@@ -317,7 +321,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 logit_softcap=cfg.attn_logit_softcap).reshape(
                     b, 1, hkv * g, dh)
 
-        o = jax.lax.cond(is_dense, _run_dense, _run_sparse, None)
+        with jax.named_scope("sparse_attn"):
+            o = jax.lax.cond(is_dense, _run_dense, _run_sparse, None)
         if options.measure_sparsity:
             sel = _selection_aux(idx, kc.visible_blocks(
                 jnp.maximum(new_len, 1), ps), npt)
@@ -339,18 +344,20 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                               k_pages=k_pages, page_table=page_table,
                               kmin_pages=kmin_pages, kmax_pages=kmax_pages,
                               k_scale_pages=k_scale)
-        idx = policy.select(inp, cfg, impl=select_impl(options.impl),
-                            max_selected=options.max_selected(cfg),
-                            unify_heads=options.schedule.unify_heads)
-        if budget_blocks is not None:
-            slot_cap = jnp.arange(idx.shape[-1])[None, None, :] \
-                < budget_blocks[:, None, None]
-            idx = jnp.where(slot_cap, idx, -1)
+        with jax.named_scope("gate_select"):
+            idx = policy.select(inp, cfg, impl=select_impl(options.impl),
+                                max_selected=options.max_selected(cfg),
+                                unify_heads=options.schedule.unify_heads)
+            if budget_blocks is not None:
+                slot_cap = jnp.arange(idx.shape[-1])[None, None, :] \
+                    < budget_blocks[:, None, None]
+                idx = jnp.where(slot_cap, idx, -1)
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
-        o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx, pt_kv,
-                                    new_len, block_size=ps,
-                                    impl=options.impl,
-                                    k_scales=k_scale, v_scales=v_scale)
+        with jax.named_scope("sparse_attn"):
+            o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx, pt_kv,
+                                        new_len, block_size=ps,
+                                        impl=options.impl,
+                                        k_scales=k_scale, v_scales=v_scale)
         o = o.reshape(b, 1, hkv * g, dh)
         aux = (_selection_aux(idx, kc.visible_blocks(
                    jnp.maximum(new_len, 1), ps), npt)
@@ -390,13 +397,14 @@ def block_decode_paged(p: Params, x1, cfg: ModelConfig, layer_pages,
     attn_out, new_pages, aux = ret[:3]
     x1 = x1 + attn_out
     h2 = rms_norm(p["ln2"], x1, cfg.norm_eps)
-    if "moe" in p:
-        b = x1.shape[0]
-        y, _ = moe_mod.moe_mlp(p["moe"], h2.reshape(b, -1), cfg.moe,
-                               cfg.activation, None)
-        y = y.reshape(b, 1, -1)
-    else:
-        y = mlp(p["mlp"], h2, cfg.activation)
+    with jax.named_scope("mlp"):
+        if "moe" in p:
+            b = x1.shape[0]
+            y, _ = moe_mod.moe_mlp(p["moe"], h2.reshape(b, -1), cfg.moe,
+                                   cfg.activation, None)
+            y = y.reshape(b, 1, -1)
+        else:
+            y = mlp(p["mlp"], h2, cfg.activation)
     if stage is not None:
         return x1 + y, new_pages, aux, ret[3]
     return x1 + y, new_pages, aux

@@ -780,11 +780,12 @@ def lm_decode_step_paged(params: Params, pages, slot_state,
         x1, (new_pages, auxs) = layer_scan(self_scan, x1,
                                            (params["blocks"], tuple(pages)),
                                            unroll=not cfg.scan_layers)
-    x1 = rms_norm(params["final_norm"], x1, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = x1 @ params["embed"]["w"].T
-    else:
-        logits = linear(params["lm_head"], x1)
+    with jax.named_scope("lm_head"):
+        x1 = rms_norm(params["final_norm"], x1, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = x1 @ params["embed"]["w"].T
+        else:
+            logits = linear(params["lm_head"], x1)
     return (logits[:, 0], PagedPages(*new_pages), slot_state,
             aggregate_decode_aux(auxs))
 

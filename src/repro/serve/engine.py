@@ -37,6 +37,7 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.config import ModelConfig
 from repro.core.policy import DecodeOptions, default_options
@@ -48,6 +49,27 @@ from repro.serve.eviction import EvictionConfig, EvictionManager
 from repro.serve.offload import (HostSwapSpace, SwapConfig, SwapEntry,
                                  SwapError)
 from repro.serve.scheduler import Request, Scheduler, pages_needed
+
+
+def _sync(what: str) -> TraceAnnotation:
+    """Span of one blocking device->host pull: ``serve()`` wraps each round
+    trip in its own, so the trace counts them."""
+    return TraceAnnotation("serve.sync", what=what)
+
+
+def _pull(a, what: str):
+    """A device array as a host copy, in its own ``serve.sync`` span (None
+    passes through)."""
+    if a is None:
+        return None
+    with _sync(what):
+        return np.array(a)
+
+
+def _bucket_pages(prompt_len: int, ps: int) -> int:
+    """Prefill bucket of a prompt: its page count rounded up to a power of
+    two."""
+    return 1 << (-(-prompt_len // ps) - 1).bit_length()
 
 
 class GenerationResult(Dict):
@@ -361,8 +383,9 @@ class DecodeEngine:
             key = jax.random.fold_in(
                 jax.random.fold_in(base_key, ridx_of[req.rid]),
                 len(req.out_tokens))
-            return int(smp.make_sampler(params_s)(jnp.asarray(row_logits),
-                                                  key=key))
+            with _sync("sample"):
+                return int(smp.make_sampler(params_s)(
+                    jnp.asarray(row_logits), key=key))
 
         # how many layer slices the pools carry is a FAMILY property
         # (transformer: self-attn layers; hybrid: attention units; ssm: 0
@@ -395,10 +418,16 @@ class DecodeEngine:
         track = eviction is not None
         step = self._paged_steps.get(track)
         if step is None:   # one jit per flavor per engine: repeat serve()
-            step = self._paged_steps[track] = jax.jit(functools.partial(
-                self.api.decode_step_paged, cfg=cfg,
-                options=eviction_options, shard=self.shard),
-                donate_argnums=(1,))
+            def paged_decode_step(params, pages, slot_state, token,
+                                  page_table, cur_len, active,
+                                  budget_blocks=None):
+                return self.api.decode_step_paged(
+                    params, pages, slot_state, token, page_table, cur_len,
+                    active, cfg=cfg, options=eviction_options,
+                    budget_blocks=budget_blocks, shard=self.shard)
+            # named, so the device trace shows jit_paged_decode_step
+            step = self._paged_steps[track] = jax.jit(
+                paged_decode_step, donate_argnums=(1,))
         evmgr = None
         if eviction is not None:
             evmgr = EvictionManager(
@@ -453,60 +482,61 @@ class DecodeEngine:
             captured from the PRE-step buffer (the step jit never donates
             ``slot_state``), which together with the pending ``token`` is
             exactly the point decode resumes from."""
-            n_content = max(1, -(-req.swap_len // ps))
-            content = req.pages[:n_content]
-            # ghost ids carry no K/V — extract through the trash page and
-            # overwrite those blocks from their host PageEntries below
-            phys_ids = [p if p < num_pages else pg.NULL_PAGE
-                        for p in content]
-            # power-of-two id padding (trash-page ids): bounds the jit
-            # cache of extract/restore to O(log pool) programs; re-admission
-            # pads the same n_content to the same bucket, so shapes match
-            k, v, kg, kmin, kmax, k_sc, v_sc = pg.extract_pages(
-                pages, pg.pad_page_ids(phys_ids))
-            k, v = np.array(k), np.array(v)
-            kg = None if kg is None else np.array(kg)
-            kmin = None if kmin is None else np.array(kmin)
-            kmax = None if kmax is None else np.array(kmax)
-            k_sc = None if k_sc is None else np.array(k_sc)
-            v_sc = None if v_sc is None else np.array(v_sc)
-            reason = None
-            if evmgr is not None:
-                blocks = evmgr.evicted.pop(req.rid, None) or {}
-                for lb, ghost in sorted(blocks.items()):
-                    evmgr.ghost_free.append(ghost)
+            with TraceAnnotation("serve.swap_out", rid=req.rid):
+                n_content = max(1, -(-req.swap_len // ps))
+                content = req.pages[:n_content]
+                # ghost ids carry no K/V — extract through the trash page
+                # and overwrite those blocks from their host PageEntries
+                # below
+                phys_ids = [p if p < num_pages else pg.NULL_PAGE
+                            for p in content]
+                # power-of-two id padding (trash-page ids): bounds the jit
+                # cache of extract/restore to O(log pool) programs;
+                # re-admission pads the same n_content to the same bucket,
+                # so shapes match
+                k, v, kg, kmin, kmax, k_sc, v_sc = pg.extract_pages(
+                    pages, pg.pad_page_ids(phys_ids))
+                k, v, kg, kmin, kmax, k_sc, v_sc = (
+                    _pull(a, f"swap_{what}") for a, what in (
+                        (k, "k"), (v, "v"), (kg, "kg"), (kmin, "kmin"),
+                        (kmax, "kmax"), (k_sc, "k_scale"), (v_sc, "v_scale")))
+                reason = None
+                if evmgr is not None:
+                    blocks = evmgr.evicted.pop(req.rid, None) or {}
+                    for lb, ghost in sorted(blocks.items()):
+                        evmgr.ghost_free.append(ghost)
+                        try:
+                            pe = swap.pop(("page", req.rid, lb))
+                        except SwapError:
+                            reason = "restore_failed"
+                            continue
+                        k[:, lb] = pe.k[:, 0]
+                        v[:, lb] = pe.v[:, 0]
+                        if kg is not None and pe.kg is not None:
+                            kg[:, lb] = pe.kg[:, 0]
+                        if kmin is not None and pe.kmin is not None:
+                            kmin[:, lb] = pe.kmin[:, 0]
+                            kmax[:, lb] = pe.kmax[:, 0]
+                        if k_sc is not None and pe.k_scale is not None:
+                            k_sc[:, lb] = pe.k_scale[:, 0]
+                            v_sc[:, lb] = pe.v_scale[:, 0]
+                st_conv = st_h = None
+                if slot_state is not None:
+                    row = ss.read_slot(slot_state, jnp.asarray(req.slot))
+                    st_conv = _pull(row.conv, "swap_conv")
+                    st_h = _pull(row.h, "swap_h")
+                if reason is None:
                     try:
-                        pe = swap.pop(("page", req.rid, lb))
+                        swap.put(req.rid, SwapEntry(
+                            k=k, v=v, kg=kg,
+                            token=int(token_buf[req.slot]),
+                            cur_len=req.swap_len, kmin=kmin, kmax=kmax,
+                            k_scale=k_sc, v_scale=v_sc,
+                            state_conv=st_conv, state_h=st_h))
                     except SwapError:
-                        reason = "restore_failed"
-                        continue
-                    k[:, lb] = pe.k[:, 0]
-                    v[:, lb] = pe.v[:, 0]
-                    if kg is not None and pe.kg is not None:
-                        kg[:, lb] = pe.kg[:, 0]
-                    if kmin is not None and pe.kmin is not None:
-                        kmin[:, lb] = pe.kmin[:, 0]
-                        kmax[:, lb] = pe.kmax[:, 0]
-                    if k_sc is not None and pe.k_scale is not None:
-                        k_sc[:, lb] = pe.k_scale[:, 0]
-                        v_sc[:, lb] = pe.v_scale[:, 0]
-            st_conv = st_h = None
-            if slot_state is not None:
-                row = ss.read_slot(slot_state, jnp.asarray(req.slot))
-                st_conv = None if row.conv is None else np.asarray(row.conv)
-                st_h = None if row.h is None else np.asarray(row.h)
-            if reason is None:
-                try:
-                    swap.put(req.rid, SwapEntry(
-                        k=k, v=v, kg=kg,
-                        token=int(token_buf[req.slot]),
-                        cur_len=req.swap_len, kmin=kmin, kmax=kmax,
-                        k_scale=k_sc, v_scale=v_sc,
-                        state_conv=st_conv, state_h=st_h))
-                except SwapError:
-                    reason = "swap_put_failed"
-            if reason is not None:
-                pending_failures.append((req, reason))
+                        reason = "swap_put_failed"
+                if reason is not None:
+                    pending_failures.append((req, reason))
 
         # recycled pages may hold a previous tenant's Kg row; the
         # staleness contract needs a ZERO row on every partial trailing
@@ -555,6 +585,24 @@ class DecodeEngine:
             sched.release_filter = release_filter
             evmgr.mark_clean = mark_live
 
+        def restore(req: Request, entry: SwapEntry):
+            """Resume: scatter a swapped-out request's pages (and its
+            recurrent rows) back from host swap space into its new pages
+            and slot. Returns (pages, slot_state)."""
+            def dev(a):
+                return None if a is None else jnp.asarray(a)
+            new_pages = pg.restore_pages(
+                pages, dev(entry.k), dev(entry.v), dev(entry.kg),
+                pg.pad_page_ids(req.pages), dev(entry.kmin), dev(entry.kmax),
+                k_scale=dev(entry.k_scale), v_scale=dev(entry.v_scale))
+            if slot_state is None or (entry.state_conv is None
+                                      and entry.state_h is None):
+                return new_pages, slot_state
+            row = ss.SlotState(conv=dev(entry.state_conv),
+                               h=dev(entry.state_h))
+            return new_pages, ss.write_slot(slot_state, row,
+                                            jnp.asarray(req.slot))
+
         def fail_unfinished(reason: str) -> None:
             for r in reqs:
                 if r.rid not in sched.finished:
@@ -562,228 +610,248 @@ class DecodeEngine:
 
         while sched.has_work() or (arrivals is not None
                                    and not arrivals.exhausted):
-            # the scheduler's virtual clock: lifecycle ``*_step`` stamps
-            # and the arrival schedule both read the decode-loop iteration
-            # counter, never wall time — fixed trace => fixed schedule
-            sched.now = n_steps
-            if arrivals is not None:
-                for rd in arrivals.pull(n_steps):
-                    rid = rd.get("rid", len(reqs))
-                    if rid in ridx_of or rid in ("stats", "logits"):
-                        # malformed trace entry: drop it (never-raises —
-                        # the already-running batch must not pay for it)
-                        rejected_arrivals += 1
-                        continue
-                    req = register(rd)
-                    try:
-                        sched.submit(req)
-                    except ValueError as e:
-                        # an arriving request the pool/table can never hold
-                        # fails ALONE with the reason, mid-run
-                        sched.fail(req, f"submit_rejected: {e}")
-            for req in sched.admissions():
-                if req.swapped:            # resume: restore, don't prefill
-                    try:
-                        entry = swap.pop(req.rid)
-                    except SwapError:
-                        # permanently unreadable swap entry: the request's
-                        # KV is gone — fail IT, keep serving the others
-                        fail_req(req, "restore_failed")
-                        continue
-                    pages = pg.restore_pages(
-                        pages, jnp.asarray(entry.k), jnp.asarray(entry.v),
-                        None if entry.kg is None else jnp.asarray(entry.kg),
-                        pg.pad_page_ids(req.pages),
-                        None if entry.kmin is None
-                        else jnp.asarray(entry.kmin),
-                        None if entry.kmax is None
-                        else jnp.asarray(entry.kmax),
-                        k_scale=None if entry.k_scale is None
-                        else jnp.asarray(entry.k_scale),
-                        v_scale=None if entry.v_scale is None
-                        else jnp.asarray(entry.v_scale))
-                    if slot_state is not None and (
-                            entry.state_conv is not None
-                            or entry.state_h is not None):
-                        row = ss.SlotState(
-                            conv=None if entry.state_conv is None
-                            else jnp.asarray(entry.state_conv),
-                            h=None if entry.state_h is None
-                            else jnp.asarray(entry.state_h))
-                        slot_state = ss.write_slot(slot_state, row,
-                                                   jnp.asarray(req.slot))
-                    token_buf[req.slot] = entry.token
-                    req.swapped = False
-                else:
-                    pages, slot_state, lg = self._paged_prefill(
-                        pages, slot_state, req, ps)
-                    first = sample_slot(req, lg)
-                    req.out_tokens.append(first)
-                    sched.note_token(req, first)   # TTFT stamp + stream
-                    if collect_logits:
-                        req.out_logits.append(lg)
-                    token_buf[req.slot] = first
-                mark_live(req.pages)                 # content written
-                if budget_blocks is not None:
-                    budget_blocks[req.slot] = slot_cap(req.rid)
-                sched.retire_if_done(req)
-            if evmgr is not None:
-                pages = evmgr.enforce_caps(pages)
-            fresh = sched.prepare_step(swap_out)   # lazy growth + preemption
-            flush_failures()
-            dirty.update(sched.drain_released())
-            sweep_dirty([p for p in fresh if p in dirty])
-            if not sched.active.any():
-                if not sched.pending:
-                    if arrivals is not None and not arrivals.exhausted:
-                        # open-loop gap: nothing to decode yet but the
-                        # trace has more arrivals — tick the virtual clock
-                        # forward so they come due (bounded by max_steps)
-                        n_steps += 1
-                        if n_steps > limit:
-                            fail_unfinished("step_limit")
-                            break
-                        continue
-                    break
-                # preemption may have just vacated every slot while freeing
-                # its pages — loop back through admissions once before
-                # declaring a stall
-                idle_spins += 1
-                if idle_spins > 1:
-                    # no-progress watchdog: admission is stuck (e.g. the
-                    # allocator keeps faulting). Fail the request admission
-                    # keeps choosing (highest priority, FIFO within the
-                    # class) — each firing unblocks the queue by one, so
-                    # the loop always terminates — instead of raising away
-                    # everyone's partial results.
-                    fail_req(max(sched.pending, key=lambda r: r.priority),
-                             "admission_stall")
-                    idle_spins = 0
-                continue
-            idle_spins = 0
-            active_now = int(sched.active.sum())
-            active_sum += active_now
-            active_max = max(active_max, active_now)
-            replays = 0
-            while True:
-                # slot_state is NOT donated and NOT adopted until the step
-                # is accepted: a faulted attempt is re-run from the SAME
-                # recurrent state (updates are not idempotent), which keeps
-                # the replay bitwise-equal to a never-faulted step
-                logits, pages, slot_state_out, aux = step(
-                    self.params, pages, slot_state,
-                    jnp.asarray(token_buf),
-                    jnp.asarray(sched.page_table),
-                    jnp.asarray(sched.cur_len),
-                    jnp.asarray(sched.active),
-                    budget_blocks=(jnp.asarray(budget_blocks)
-                                   if budget_blocks is not None else None))
-                if evmgr is None:
-                    break
-                touched = np.asarray(aux["touched_pages"], bool)
-                faulted = (touched & (sched.page_table >= num_pages)
-                           & sched.active[:, None])
-                if not faulted.any():
-                    # victim model feeds on FAULT-FREE steps only (replay
-                    # reads are restore traffic, not attention heat)
-                    evmgr.heat.observe(touched, sched.active)
-                    break
-                # optimistic execution faulted: some row selected a block
-                # whose K/V is evicted (its gate/meta ghost rows scored it
-                # normally). Restore the pages and RE-RUN the step; page
-                # writes are idempotent (the trailing append rewrites the
-                # same values at the same positions before any read), so
-                # the replay is bitwise equal to a never-faulted step.
-                evmgr.n_replays += 1
-                replays += 1
-                if replays > evmgr.config.max_replays:
-                    # evict/restore thrash: fail the faulted requests. The
-                    # surviving rows of this run never read a ghost, so
-                    # their logits are valid as-is.
-                    for slot in np.nonzero(faulted.any(axis=1))[0]:
-                        if sched.slots[slot] is not None:
-                            fail_req(sched.slots[slot], "restore_thrash")
-                    break
-                # pin every page ANY active row touched (plus trailing):
-                # restoring row A must not evict what row B's replay reads,
-                # or the replay loop could ping-pong forever
-                pinned = set()
-                for slot in np.nonzero(sched.active)[0]:
-                    r = sched.slots[slot]
-                    for lb in np.nonzero(touched[slot])[0]:
-                        pinned.add((r.rid, int(lb)))
-                    pinned.add((r.rid, int(sched.cur_len[slot]) // ps))
-                for slot in np.nonzero(faulted.any(axis=1))[0]:
-                    r = sched.slots[slot]
-                    if r is None or not sched.active[slot]:
-                        continue    # preempted while restoring another row
-                    lbs = [int(x) for x in np.nonzero(faulted[slot])[0]]
-                    pages, ok = evmgr.restore(pages, r, lbs, pinned=pinned,
-                                              swap_out=swap_out)
-                    if not ok:
-                        fail_req(r, "restore_failed")
-                flush_failures()
-                dirty.update(sched.drain_released())
+            with StepTraceAnnotation("serve.step", step_num=n_steps):
+                # the scheduler's virtual clock: lifecycle ``*_step`` stamps
+                # and the arrival schedule both read the decode-loop iteration
+                # counter, never wall time — fixed trace => fixed schedule
+                sched.now = n_steps
+                if arrivals is not None:
+                    with TraceAnnotation("serve.arrivals") as span:
+                        handed = arrivals.pull(n_steps)
+                        span.set_metadata(handed=len(handed))
+                        for rd in handed:
+                            rid = rd.get("rid", len(reqs))
+                            if rid in ridx_of or rid in ("stats", "logits"):
+                                # malformed trace entry: drop it (never-
+                                # raises — the already-running batch must
+                                # not pay for it)
+                                rejected_arrivals += 1
+                                continue
+                            req = register(rd)
+                            try:
+                                sched.submit(req)
+                            except ValueError as e:
+                                # an arriving request the pool/table can
+                                # never hold fails ALONE with the reason,
+                                # mid-run
+                                sched.fail(req, f"submit_rejected: {e}")
+                for req in sched.admissions():
+                    with TraceAnnotation(
+                            "serve.admit", rid=req.rid,
+                            prompt_len=req.prompt_len,
+                            **({"resumed": True} if req.swapped else
+                               {"bucket": _bucket_pages(req.prompt_len, ps)})):
+                        if req.swapped:        # resume: restore, don't prefill
+                            try:
+                                entry = swap.pop(req.rid)
+                            except SwapError:
+                                # permanently unreadable swap entry: the
+                                # request's KV is gone — fail IT, keep
+                                # serving the others
+                                fail_req(req, "restore_failed")
+                                continue
+                            with TraceAnnotation("serve.restore", rid=req.rid):
+                                pages, slot_state = restore(req, entry)
+                            token_buf[req.slot] = entry.token
+                            req.swapped = False
+                        else:
+                            pages, slot_state, lg = self._paged_prefill(
+                                pages, slot_state, req, ps)
+                            first = sample_slot(req, lg)
+                            req.out_tokens.append(first)
+                            sched.note_token(req, first)  # TTFT stamp + stream
+                            if collect_logits:
+                                req.out_logits.append(lg)
+                            token_buf[req.slot] = first
+                        mark_live(req.pages)             # content written
+                        if budget_blocks is not None:
+                            budget_blocks[req.slot] = slot_cap(req.rid)
+                        sched.retire_if_done(req)
+                with TraceAnnotation("serve.prepare"):
+                    if evmgr is not None:
+                        pages = evmgr.enforce_caps(pages)
+                    # lazy growth + preemption
+                    fresh = sched.prepare_step(swap_out)
+                    flush_failures()
+                    dirty.update(sched.drain_released())
+                    sweep_dirty([p for p in fresh if p in dirty])
                 if not sched.active.any():
-                    break
-            # the attempt that broke the loop is the accepted one (fault-
-            # free, or its surviving rows' outputs are valid); slots that
-            # failed/retired/preempted get their rows rewritten at the
-            # next admission or restore before anything reads them
-            slot_state = slot_state_out
-            if not sched.active.any():
-                # every row failed or was preempted mid-replay; count the
-                # spin against the step limit so injected-fault storms
-                # still terminate
+                    if not sched.pending:
+                        if arrivals is not None and not arrivals.exhausted:
+                            # open-loop gap: nothing to decode yet but the
+                            # trace has more arrivals — tick the virtual clock
+                            # forward so they come due (bounded by max_steps)
+                            n_steps += 1
+                            if n_steps > limit:
+                                fail_unfinished("step_limit")
+                                break
+                            continue
+                        break
+                    # preemption may have just vacated every slot while freeing
+                    # its pages — loop back through admissions once before
+                    # declaring a stall
+                    idle_spins += 1
+                    if idle_spins > 1:
+                        # no-progress watchdog: admission is stuck (e.g. the
+                        # allocator keeps faulting). Fail the request admission
+                        # keeps choosing (highest priority, FIFO within the
+                        # class) — each firing unblocks the queue by one, so
+                        # the loop always terminates — instead of raising away
+                        # everyone's partial results.
+                        fail_req(max(sched.pending, key=lambda r: r.priority),
+                                 "admission_stall")
+                        idle_spins = 0
+                    continue
+                idle_spins = 0
+                active_now = int(sched.active.sum())
+                active_sum += active_now
+                active_max = max(active_max, active_now)
+                replays = 0
+                while True:
+                    # slot_state is NOT donated and NOT adopted until the step
+                    # is accepted: a faulted attempt is re-run from the SAME
+                    # recurrent state (updates are not idempotent), which keeps
+                    # the replay bitwise-equal to a never-faulted step
+                    with TraceAnnotation("serve.dispatch", active=active_now):
+                        logits, pages, slot_state_out, aux = step(
+                            self.params, pages, slot_state,
+                            jnp.asarray(token_buf),
+                            jnp.asarray(sched.page_table),
+                            jnp.asarray(sched.cur_len),
+                            jnp.asarray(sched.active),
+                            budget_blocks=(jnp.asarray(budget_blocks)
+                                           if budget_blocks is not None
+                                           else None))
+                    if evmgr is None:
+                        break
+                    with _sync("touched_pages"):
+                        touched = np.asarray(aux["touched_pages"], bool)
+                    faulted = (touched & (sched.page_table >= num_pages)
+                               & sched.active[:, None])
+                    if not faulted.any():
+                        # victim model feeds on FAULT-FREE steps only (replay
+                        # reads are restore traffic, not attention heat)
+                        evmgr.heat.observe(touched, sched.active)
+                        break
+                    # optimistic execution faulted: some row selected a block
+                    # whose K/V is evicted (its gate/meta ghost rows scored it
+                    # normally). Restore the pages and RE-RUN the step; page
+                    # writes are idempotent (the trailing append rewrites the
+                    # same values at the same positions before any read), so
+                    # the replay is bitwise equal to a never-faulted step.
+                    evmgr.n_replays += 1
+                    replays += 1
+                    faulted_slots = np.nonzero(faulted.any(axis=1))[0]
+                    if replays > evmgr.config.max_replays:
+                        # evict/restore thrash: fail the faulted requests. The
+                        # surviving rows of this run never read a ghost, so
+                        # their logits are valid as-is.
+                        for slot in faulted_slots:
+                            if sched.slots[slot] is not None:
+                                fail_req(sched.slots[slot], "restore_thrash")
+                        break
+                    with TraceAnnotation(
+                            "serve.replay", replay=replays,
+                            rid=[sched.slots[s].rid for s in faulted_slots
+                                 if sched.slots[s] is not None]):
+                        # pin every page ANY active row touched (plus
+                        # trailing): restoring row A must not evict what
+                        # row B's replay reads, or the replay loop could
+                        # ping-pong forever
+                        pinned = set()
+                        for slot in np.nonzero(sched.active)[0]:
+                            r = sched.slots[slot]
+                            for lb in np.nonzero(touched[slot])[0]:
+                                pinned.add((r.rid, int(lb)))
+                            pinned.add((r.rid,
+                                        int(sched.cur_len[slot]) // ps))
+                        for slot in faulted_slots:
+                            r = sched.slots[slot]
+                            if r is None or not sched.active[slot]:
+                                continue  # preempted restoring another row
+                            lbs = [int(x)
+                                   for x in np.nonzero(faulted[slot])[0]]
+                            pages, ok = evmgr.restore(pages, r, lbs,
+                                                      pinned=pinned,
+                                                      swap_out=swap_out)
+                            if not ok:
+                                fail_req(r, "restore_failed")
+                        flush_failures()
+                        dirty.update(sched.drain_released())
+                    if not sched.active.any():
+                        break
+                # the attempt that broke the loop is the accepted one (fault-
+                # free, or its surviving rows' outputs are valid); slots that
+                # failed/retired/preempted get their rows rewritten at the
+                # next admission or restore before anything reads them
+                slot_state = slot_state_out
+                if not sched.active.any():
+                    # every row failed or was preempted mid-replay; count the
+                    # spin against the step limit so injected-fault storms
+                    # still terminate
+                    n_steps += 1
+                    if n_steps > limit:
+                        fail_unfinished("step_limit")
+                        break
+                    continue
+                self._last_aux = aux
+                # idle/retired slots decode garbage rows (rho=0): remember who
+                # was live so sparsity_stats() averages ACTIVE rows only
+                self._last_active = sched.active.copy()
+                slot_reqs = list(sched.slots)   # before retirement mutates it
+                # per-request failure isolation: a non-finite logits row (a
+                # poisoned request, or an injected "logits" fault) is retired
+                # with an error instead of sampling garbage into the batch
+                with _sync("isfinite"):
+                    finite = np.array(jnp.isfinite(logits).all(axis=-1))
+                if faults is not None and faults.fire("logits"):
+                    act = np.nonzero(sched.active)[0]
+                    if act.size:
+                        finite[act[0]] = False
+                bad = (~finite) & sched.active
+                for slot in np.nonzero(bad)[0]:
+                    fail_req(sched.slots[slot], "non_finite_logits")
+                stoch = any_stochastic(slot_reqs)
+                lg_np = None
+                if collect_logits or stoch:
+                    with _sync("logits"):
+                        lg_np = np.asarray(logits, np.float32)
+                if stoch:
+                    with TraceAnnotation("serve.sample"):
+                        nxt = np.zeros((n_slots,), np.int32)
+                        for slot in np.nonzero(sched.active)[0]:
+                            nxt[slot] = sample_slot(slot_reqs[slot],
+                                                    lg_np[slot])
+                else:
+                    with _sync("argmax"):
+                        nxt = np.asarray(jnp.argmax(logits, axis=-1),
+                                         np.int32)
+                if self.options.measure_sparsity:
+                    with _sync("sparsity_rows"):
+                        rho_rows = np.asarray(aux["sparsity_rows"],
+                                              np.float32)
+                    with _sync("sel_blocks"):
+                        sel_rows = np.asarray(aux["sel_blocks"], np.float32)
+                    for slot in np.nonzero(sched.active)[0]:
+                        rid = slot_reqs[slot].rid
+                        rho_sum[rid] += float(rho_rows[slot])
+                        sel_sum[rid] += float(sel_rows[slot])
+                        rho_n[rid] += 1
+                with TraceAnnotation("serve.complete"):
+                    sched.complete_step(nxt,
+                                        lg_np if collect_logits else None)
+                    # retirements this step
+                    dirty.update(sched.drain_released())
+                    sweep_dirty(set(dirty))
+                    token_buf = np.where(sched.active, nxt, 0).astype(
+                        np.int32)
                 n_steps += 1
                 if n_steps > limit:
+                    # step-limit watchdog: fail whatever is unfinished with
+                    # partial results + telemetry instead of raising away the
+                    # finished requests' outputs
                     fail_unfinished("step_limit")
                     break
-                continue
-            self._last_aux = aux
-            # idle/retired slots decode garbage rows (rho=0): remember who
-            # was live so sparsity_stats() averages ACTIVE rows only
-            self._last_active = sched.active.copy()
-            slot_reqs = list(sched.slots)   # before retirement mutates it
-            # per-request failure isolation: a non-finite logits row (a
-            # poisoned request, or an injected "logits" fault) is retired
-            # with an error instead of sampling garbage into the batch
-            finite = np.array(jnp.isfinite(logits).all(axis=-1))
-            if faults is not None and faults.fire("logits"):
-                act = np.nonzero(sched.active)[0]
-                if act.size:
-                    finite[act[0]] = False
-            bad = (~finite) & sched.active
-            for slot in np.nonzero(bad)[0]:
-                fail_req(sched.slots[slot], "non_finite_logits")
-            stoch = any_stochastic(slot_reqs)
-            lg_np = (np.asarray(logits, np.float32)
-                     if (collect_logits or stoch) else None)
-            if stoch:
-                nxt = np.zeros((n_slots,), np.int32)
-                for slot in np.nonzero(sched.active)[0]:
-                    nxt[slot] = sample_slot(slot_reqs[slot], lg_np[slot])
-            else:
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            if self.options.measure_sparsity:
-                rho_rows = np.asarray(aux["sparsity_rows"], np.float32)
-                sel_rows = np.asarray(aux["sel_blocks"], np.float32)
-                for slot in np.nonzero(sched.active)[0]:
-                    rid = slot_reqs[slot].rid
-                    rho_sum[rid] += float(rho_rows[slot])
-                    sel_sum[rid] += float(sel_rows[slot])
-                    rho_n[rid] += 1
-            sched.complete_step(nxt, lg_np if collect_logits else None)
-            dirty.update(sched.drain_released())   # retirements this step
-            sweep_dirty(set(dirty))
-            token_buf = np.where(sched.active, nxt, 0).astype(np.int32)
-            n_steps += 1
-            if n_steps > limit:
-                # step-limit watchdog: fail whatever is unfinished with
-                # partial results + telemetry instead of raising away the
-                # finished requests' outputs
-                fail_unfinished("step_limit")
-                break
         wall = time.perf_counter() - t0
 
         out = ServeResult()
@@ -885,13 +953,15 @@ class DecodeEngine:
         request's slot in ``slot_state``. Returns (pages, slot_state, fp32
         logits row) — the caller samples."""
         plen = req.prompt_len
-        n_prompt = -(-plen // ps)
-        bucket = 1 << (n_prompt - 1).bit_length()       # pages, power of 2
+        bucket = _bucket_pages(plen, ps)
         fn = self._prefill_jit.get(bucket)
         if fn is None:
-            fn = self._prefill_jit[bucket] = jax.jit(functools.partial(
-                self.api.prefill, cfg=self.cfg, max_len=bucket * ps,
-                options=self.options))
+            def paged_prefill(params, batch):
+                return self.api.prefill(params, batch, cfg=self.cfg,
+                                        max_len=bucket * ps,
+                                        options=self.options)
+            # named, so the device trace shows jit_paged_prefill
+            fn = self._prefill_jit[bucket] = jax.jit(paged_prefill)
         toks = np.zeros((1, bucket * ps), np.int32)
         toks[0, :plen] = req.prompt
         logits, cstate = fn(self.params,
@@ -908,7 +978,8 @@ class DecodeEngine:
         if slot_state is not None and view.slot is not None:
             slot_state = ss.write_slot(slot_state, view.slot,
                                        jnp.asarray(req.slot))
-        return pages, slot_state, np.asarray(logits[0], np.float32)
+        with _sync("prefill_logits"):
+            return pages, slot_state, np.asarray(logits[0], np.float32)
 
     def sparsity_stats(self, state=None) -> Dict[str, Any]:
         """Measured selection economics of the LATEST decode step.
