@@ -150,26 +150,28 @@ def trailing_meta(k_cache: jnp.ndarray, cur_len: jnp.ndarray,
     return tmin, tmax, t_idx
 
 
-def trailing_meta_paged(k_pages: jnp.ndarray, page_table: jnp.ndarray,
+def trailing_meta_paged(k_pages: jnp.ndarray, layer: jnp.ndarray,
+                        page_table: jnp.ndarray,
                         cur_len: jnp.ndarray, page_size: int,
                         k_scale: Optional[jnp.ndarray] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Paged twin of ``trailing_meta``: one physical page per slot.
 
-    k_pages [P, Hkv, ps, Dh]; page_table [S, npt]; cur_len [S]. Reads
-    exactly ONE page per slot (O(page_size)); rows with ``cur_len == 0``
-    read the null page and collapse to zeros. ``k_scale`` [P, Hkv, 1]
-    (int8 pools, ISSUE 9) dequantizes the gathered page first — the
-    metadata describes the values attention will actually read."""
+    k_pages [L, P, Hkv, ps, Dh] (stacked, read at ``layer``); page_table
+    [S, npt]; cur_len [S]. Reads exactly ONE page per slot
+    (O(page_size)); rows with ``cur_len == 0`` read the null page and
+    collapse to zeros. ``k_scale`` [L, P, Hkv, 1] (int8 pools)
+    dequantizes the gathered page first — the metadata describes the
+    values attention will actually read."""
     ps = page_size
     sidx = jnp.arange(cur_len.shape[0])
     t_idx = jnp.maximum(-(-cur_len // ps) - 1, 0)           # [S] logical
     phys = page_table[sidx, t_idx]                          # [S]
     rem = cur_len - t_idx * ps
-    blk = k_pages[phys]                                     # [S, Hkv, ps, Dh]
+    blk = k_pages[layer, phys]                              # [S, Hkv, ps, Dh]
     if k_scale is not None:
         from repro.serve.paging import dequantize_block
-        blk = dequantize_block(blk, k_scale[phys])
+        blk = dequantize_block(blk, k_scale[layer, phys])
     valid = (jnp.arange(ps)[None, :] < rem[:, None])[:, None, :, None]
     tmin, tmax = _block_minmax(blk, valid)
     return tmin, tmax, t_idx

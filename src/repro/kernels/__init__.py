@@ -2,9 +2,10 @@
 
 Layout invariants (binding, PR 2 — see docs/ARCHITECTURE.md): every
 cache/pool operand is HEAD-MAJOR — contiguous caches [B, Hkv, S, Dh],
-page pools [P, Hkv, ps, Dh], Kg [.., Hkv, Dg] — and no kernel (or its
-ref) may transpose or materialise a copy of a cache-sized array on the
-decode path; page/block-sized temporaries are fine. Int8 pools (ISSUE 9)
+layer-stacked page pools [L, P, Hkv, ps, Dh] read at a scalar-prefetched
+layer index, Kg [.., Hkv, Dg] — and no kernel (or its ref) may transpose
+or materialise a copy of a cache-sized array on the decode path;
+page/block-sized temporaries are fine. Int8 pools
 add per-(page, head) f32 scale rows threaded as scalar-prefetch operands
 with the dequant fused inside the block loop — the fp path with
 ``k_scales=None`` is byte-for-byte the original program.

@@ -32,13 +32,16 @@ transpose or copy between token-in and logits-out; prefill does the
 one-time layout conversion):
   q             [B, Hkv, G_pad, Dh]
   k_cache/v_...  [B, Hkv, S, Dh]     (S = nb * bs; contiguous block reads)
-  k_pages/v_...  [P, Hkv, ps, Dh]    (paged pools, ps == block_size)
+  k_pages/v_...  [L, P, Hkv, ps, Dh] (layer-stacked paged pools, ps ==
+                                     block_size, read at ``layer``)
+  layer         [] int32            (paged: scalar-prefetched; the K/V
+                                     BlockSpecs squeeze the layer dim)
   block_indices [B, Hkv, nsel] int32 (-1 padding)
   kv_len        [B] int32
   out           [B, Hkv, G_pad, Dh]
 
 Fused dequant (ISSUE 9): optional ``k_scales``/``v_scales`` — per-block
-f32 dequant factors ([B, Hkv, nb] contiguous, [P, Hkv(, 1)] paged pool
+f32 dequant factors ([B, Hkv, nb] contiguous, [L, P, Hkv, 1] paged pool
 rows). The scales of the SELECTED blocks are gathered outside the kernel
 into a [B, Hkv, nsel] array laid out like the block indices (a
 selection-sized gather, so the scalar-memory operand does not grow with
@@ -191,29 +194,36 @@ def _kernel_quant(idx_ref, len_ref, ks_ref, vs_ref,  # scalar prefetch
     _kernel_body(idx_ref, len_ref, refs, scale_refs=(ks_ref, vs_ref), **kw)
 
 
-def _kernel_paged(idx_ref, pt_ref, len_ref,  # scalar prefetch (+page table)
+def _kernel_paged(layer_ref, idx_ref, pt_ref, len_ref,  # scalar prefetch
                   *refs, **kw):
-    # identical math to _kernel — the logical->physical translation lives
-    # entirely in the BlockSpec index_map (pt_ref is consumed there); the
-    # in-kernel masking stays in LOGICAL positions so kv_len semantics match
-    # the contiguous kernel exactly.
+    # identical math to _kernel — the layer and logical->physical
+    # translation live entirely in the BlockSpec index_map (layer_ref and
+    # pt_ref are consumed there); the in-kernel masking stays in LOGICAL
+    # positions so kv_len semantics match the contiguous kernel exactly.
     _kernel_body(idx_ref, len_ref, refs, **kw)
 
 
-def _kernel_paged_quant(idx_ref, pt_ref, len_ref, ks_ref, vs_ref, *refs,
-                        **kw):
+def _kernel_paged_quant(layer_ref, idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
+                        *refs, **kw):
     _kernel_body(idx_ref, len_ref, refs, scale_refs=(ks_ref, vs_ref), **kw)
 
 
-def _selected_scales(rows: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
-    """Per-head pool scale rows ``[P, Hkv(, 1)]`` -> the scale of each
-    listed page ``[B, Hkv, n]`` for physical ids ``[B, Hkv, n]``. A
-    selection-sized gather: the scalar-prefetch operand then scales with
-    the selected list, not with the pool — a pool-sized [P, Hkv] SMEM
-    operand overflows the TPU's scalar memory at serving pool sizes."""
+def _selected_scales(rows: jnp.ndarray, layer: jnp.ndarray,
+                     ids: jnp.ndarray) -> jnp.ndarray:
+    """Stacked per-head pool scale rows ``[L, P, Hkv, 1]`` at ``layer`` ->
+    the scale of each listed page ``[B, Hkv, n]`` for physical ids
+    ``[B, Hkv, n]``. A selection-sized gather: the scalar-prefetch operand
+    then scales with the selected list, not with the pool — a pool-sized
+    [P, Hkv] SMEM operand overflows the TPU's scalar memory at serving
+    pool sizes."""
     hkv = ids.shape[1]
-    rows = rows.reshape(-1, hkv).astype(jnp.float32)
-    return rows[jnp.maximum(ids, 0), jnp.arange(hkv)[None, :, None]]
+    return rows[layer, jnp.maximum(ids, 0), jnp.arange(hkv)[None, :, None],
+                0].astype(jnp.float32)
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the [1] int32 scalar-prefetch operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
 
 def _physical_ids(block_indices: jnp.ndarray,
@@ -314,30 +324,35 @@ def block_sparse_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("block_size", "blocks_per_step",
                                              "interpret"))
 def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
-                              v_pages: jnp.ndarray,
+                              v_pages: jnp.ndarray, layer: jnp.ndarray,
                               block_indices: jnp.ndarray,
                               page_table: jnp.ndarray, kv_len: jnp.ndarray,
                               *, block_size: int, blocks_per_step: int = 4,
                               interpret: bool = False,
                               k_scales: jnp.ndarray = None,
                               v_scales: jnp.ndarray = None) -> jnp.ndarray:
-    """Paged variant: q [B,Hkv,G,Dh]; k_pages/v_pages [P, Hkv, ps, Dh]
-    HEAD-MAJOR global pools (ps == block_size); block_indices [B,Hkv,nsel]
-    LOGICAL block ids (-1 padding); page_table [B, npt] logical->physical.
+    """Paged variant: q [B,Hkv,G,Dh]; k_pages/v_pages [L, P, Hkv, ps, Dh]
+    layer-STACKED HEAD-MAJOR global pools (ps == block_size), read at the
+    int32 ``layer``; block_indices [B,Hkv,nsel] LOGICAL block ids (-1
+    padding); page_table [B, npt] logical->physical.
 
-    The page table rides the same scalar-prefetch path as the selected
-    indices, so the logical->physical indirection happens inside the
-    ``BlockSpec.index_map``: grid step (b, h, j) streams physical pages
-    ``page_table[b, block_indices[b,h,j*C+i]]`` HBM->VMEM. Non-selected
-    pages never leave HBM — paging adds zero extra KV I/O.
+    The layer index and the page table ride the same scalar-prefetch path
+    as the selected indices, so the layer and logical->physical
+    indirection happen inside the ``BlockSpec.index_map``: grid step
+    (b, h, j) streams pages ``[layer, page_table[b,
+    block_indices[b,h,j*C+i]], h]`` HBM->VMEM straight out of the stacked
+    pool (its layer dim squeezed). Non-selected pages never leave HBM and
+    no layer-sized slice of the pool is ever made — paging adds zero
+    extra KV I/O.
 
-    ``k_scales``/``v_scales`` [P, Hkv(, 1)] f32: per-page per-head dequant
-    rows for int8 pools (serve.paging scale pools). The selected pages'
-    scales are gathered through the same logical->physical translation
-    and ride scalar prefetch beside the indices (None = fp path verbatim).
+    ``k_scales``/``v_scales`` [L, P, Hkv, 1] f32: per-page per-head
+    dequant rows for int8 pools (serve.paging scale pools). The selected
+    pages' scales are gathered at ``layer`` through the same
+    logical->physical translation and ride scalar prefetch beside the
+    indices (None = fp path verbatim).
     """
     bsz, hkv, g, dh = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     assert ps == block_size, (ps, block_size)
     nsel = block_indices.shape[-1]
     c, n_groups, idx = _pad_indices(block_indices, nsel, blocks_per_step)
@@ -350,22 +365,24 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         return (b, h, 0, 0)
 
     def kv_map(i):
-        def f(b, h, j, idx_ref, pt_ref, *rest):
+        def f(b, h, j, layer_ref, idx_ref, pt_ref, *rest):
             log = jnp.maximum(idx_ref[b, h, j * c + i], 0)
             phys = pt_ref[b, log]
-            return (jnp.maximum(phys, 0), h, 0, 0)
+            return (layer_ref[0], jnp.maximum(phys, 0), h, 0, 0)
         return f
 
     def o_map(b, h, j, *prefetch):
         return (b, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if quant else 3,
+        num_scalar_prefetch=6 if quant else 4,
         grid=(bsz, hkv, n_groups),
         in_specs=(
             [pl.BlockSpec((1, 1, g_pad, dh), q_map)]
-            + [pl.BlockSpec((1, 1, ps, dh), kv_map(i)) for i in range(c)]
-            + [pl.BlockSpec((1, 1, ps, dh), kv_map(i)) for i in range(c)]),
+            + [pl.BlockSpec((None, 1, 1, ps, dh), kv_map(i))
+               for i in range(c)]
+            + [pl.BlockSpec((None, 1, 1, ps, dh), kv_map(i))
+               for i in range(c)]),
         out_specs=pl.BlockSpec((1, 1, g_pad, dh), o_map),
         scratch_shapes=[
             pltpu.VMEM((g_pad, LANES), jnp.float32),   # m
@@ -373,12 +390,12 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
             pltpu.VMEM((g_pad, dh), jnp.float32),      # acc
         ],
     )
-    prefetch = [idx.astype(jnp.int32), page_table.astype(jnp.int32),
-                kv_len.astype(jnp.int32)]
+    prefetch = [_layer_operand(layer), idx.astype(jnp.int32),
+                page_table.astype(jnp.int32), kv_len.astype(jnp.int32)]
     if quant:
         phys = _physical_ids(idx, page_table)
-        prefetch += [_selected_scales(k_scales, phys),
-                     _selected_scales(v_scales, phys)]
+        prefetch += [_selected_scales(k_scales, layer, phys),
+                     _selected_scales(v_scales, layer, phys)]
     out = pl.pallas_call(
         functools.partial(_kernel_paged_quant if quant else _kernel_paged,
                           block_size=block_size,
@@ -391,7 +408,7 @@ def block_sparse_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     return out[:, :, :g]
 
 
-def _kernel_paged_splitk(idx_ref, pt_ref, len_ref,   # scalar prefetch
+def _kernel_paged_splitk(layer_ref, idx_ref, pt_ref, len_ref,  # prefetch
                          *refs, block_size: int, n_groups: int,
                          blocks_per_step: int, scale: float, per_pad: int,
                          scale_refs=None):
@@ -426,9 +443,9 @@ def _kernel_paged_splitk(idx_ref, pt_ref, len_ref,   # scalar prefetch
         lo_ref[0, 0, 0] = l_ref[...]
 
 
-def _kernel_paged_splitk_quant(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
-                               *refs, **kw):
-    _kernel_paged_splitk(idx_ref, pt_ref, len_ref, *refs,
+def _kernel_paged_splitk_quant(layer_ref, idx_ref, pt_ref, len_ref, ks_ref,
+                               vs_ref, *refs, **kw):
+    _kernel_paged_splitk(layer_ref, idx_ref, pt_ref, len_ref, *refs,
                          scale_refs=(ks_ref, vs_ref), **kw)
 
 
@@ -436,6 +453,7 @@ def _kernel_paged_splitk_quant(idx_ref, pt_ref, len_ref, ks_ref, vs_ref,
                                              "blocks_per_step", "interpret"))
 def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
                                      v_pages: jnp.ndarray,
+                                     layer: jnp.ndarray,
                                      block_indices: jnp.ndarray,
                                      page_table: jnp.ndarray,
                                      kv_len: jnp.ndarray, *, block_size: int,
@@ -455,10 +473,11 @@ def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
     rescale in jnp (exactly ``ref.paged_sparse_decode_splitk_ref``). Use
     when a single sequence's selected list is long enough to starve the
     grid — e.g. the paged x sharded serving path, where each head shard
-    owns the full selected list of its local heads.
+    owns the full selected list of its local heads. Pools, ``layer`` and
+    scales as ``block_sparse_decode_paged``.
     """
     bsz, hkv, g, dh = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     assert ps == block_size, (ps, block_size)
     ns = max(1, num_splits)
     nsel = block_indices.shape[-1]
@@ -480,22 +499,24 @@ def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
         return (b, h, 0, 0)
 
     def kv_map(i):
-        def f(b, h, s, j, idx_ref, pt_ref, *rest):
+        def f(b, h, s, j, layer_ref, idx_ref, pt_ref, *rest):
             log = jnp.maximum(idx_ref[b, h, s * per_pad + j * c + i], 0)
             phys = pt_ref[b, log]
-            return (jnp.maximum(phys, 0), h, 0, 0)
+            return (layer_ref[0], jnp.maximum(phys, 0), h, 0, 0)
         return f
 
     def part_map(b, h, s, j, *prefetch):
         return (b, h, s, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if quant else 3,
+        num_scalar_prefetch=6 if quant else 4,
         grid=(bsz, hkv, ns, n_groups),
         in_specs=(
             [pl.BlockSpec((1, 1, g_pad, dh), q_map)]
-            + [pl.BlockSpec((1, 1, ps, dh), kv_map(i)) for i in range(c)]
-            + [pl.BlockSpec((1, 1, ps, dh), kv_map(i)) for i in range(c)]),
+            + [pl.BlockSpec((None, 1, 1, ps, dh), kv_map(i))
+               for i in range(c)]
+            + [pl.BlockSpec((None, 1, 1, ps, dh), kv_map(i))
+               for i in range(c)]),
         out_specs=(pl.BlockSpec((1, 1, 1, g_pad, dh), part_map),
                    pl.BlockSpec((1, 1, 1, g_pad, LANES), part_map),
                    pl.BlockSpec((1, 1, 1, g_pad, LANES), part_map)),
@@ -505,12 +526,12 @@ def block_sparse_decode_paged_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
             pltpu.VMEM((g_pad, dh), jnp.float32),      # acc
         ],
     )
-    prefetch = [idx.astype(jnp.int32), page_table.astype(jnp.int32),
-                kv_len.astype(jnp.int32)]
+    prefetch = [_layer_operand(layer), idx.astype(jnp.int32),
+                page_table.astype(jnp.int32), kv_len.astype(jnp.int32)]
     if quant:
         phys = _physical_ids(idx, page_table)
-        prefetch += [_selected_scales(k_scales, phys),
-                     _selected_scales(v_scales, phys)]
+        prefetch += [_selected_scales(k_scales, layer, phys),
+                     _selected_scales(v_scales, layer, phys)]
     acc, m, l = pl.pallas_call(
         functools.partial(
             _kernel_paged_splitk_quant if quant else _kernel_paged_splitk,

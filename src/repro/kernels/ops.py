@@ -87,26 +87,28 @@ def gate_select_paged(qg: jnp.ndarray, kg_pages: jnp.ndarray,
 
 
 def paged_sparse_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
-                        v_pages: jnp.ndarray, block_indices: jnp.ndarray,
+                        v_pages: jnp.ndarray, layer: jnp.ndarray,
+                        block_indices: jnp.ndarray,
                         page_table: jnp.ndarray, kv_len: jnp.ndarray, *,
                         block_size: int, impl: str = "ref",
                         k_scales: Optional[jnp.ndarray] = None,
                         v_scales: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Paged-KV twin of ``sparse_decode``: block_indices are LOGICAL block
-    ids, translated through ``page_table`` [B, npt]. Pools are HEAD-MAJOR
-    [P, Hkv, page_size, Dh] with page_size == block_size.
-    ``k_scales``/``v_scales`` [P, Hkv, 1] pool scale rows: fused dequant
-    for int8 pools (None = fp path verbatim)."""
+    ids, translated through ``page_table`` [B, npt]. Pools are the
+    layer-stacked HEAD-MAJOR [L, P, Hkv, page_size, Dh] (page_size ==
+    block_size), read at the int32 ``layer``. ``k_scales``/``v_scales``
+    [L, P, Hkv, 1] pool scale rows: fused dequant for int8 pools (None =
+    fp path verbatim)."""
     if impl == "ref":
         return _ref.paged_sparse_decode_ref(
-            q, k_pages, v_pages, block_indices, page_table, kv_len,
+            q, k_pages, v_pages, layer, block_indices, page_table, kv_len,
             block_size=block_size, k_scales=k_scales, v_scales=v_scales)
     if impl == "pallas":
-        return _bsd_paged_pallas(q, k_pages, v_pages, block_indices,
+        return _bsd_paged_pallas(q, k_pages, v_pages, layer, block_indices,
                                  page_table, kv_len, block_size=block_size,
                                  k_scales=k_scales, v_scales=v_scales)
     if impl == "pallas_interpret":
-        return _bsd_paged_pallas(q, k_pages, v_pages, block_indices,
+        return _bsd_paged_pallas(q, k_pages, v_pages, layer, block_indices,
                                  page_table, kv_len, block_size=block_size,
                                  interpret=True,
                                  k_scales=k_scales, v_scales=v_scales)
@@ -114,7 +116,7 @@ def paged_sparse_decode(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def paged_sparse_decode_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
-                               v_pages: jnp.ndarray,
+                               v_pages: jnp.ndarray, layer: jnp.ndarray,
                                block_indices: jnp.ndarray,
                                page_table: jnp.ndarray,
                                kv_len: jnp.ndarray, *, block_size: int,
@@ -131,16 +133,16 @@ def paged_sparse_decode_splitk(q: jnp.ndarray, k_pages: jnp.ndarray,
     ``k_scales``/``v_scales``: fused int8 dequant, as ``paged_sparse_decode``."""
     if impl == "ref":
         return _ref.paged_sparse_decode_splitk_ref(
-            q, k_pages, v_pages, block_indices, page_table, kv_len,
+            q, k_pages, v_pages, layer, block_indices, page_table, kv_len,
             block_size=block_size, num_splits=num_splits,
             k_scales=k_scales, v_scales=v_scales)
     if impl == "pallas":
-        return _bsd_splitk_pallas(q, k_pages, v_pages, block_indices,
+        return _bsd_splitk_pallas(q, k_pages, v_pages, layer, block_indices,
                                   page_table, kv_len, block_size=block_size,
                                   num_splits=num_splits,
                                   k_scales=k_scales, v_scales=v_scales)
     if impl == "pallas_interpret":
-        return _bsd_splitk_pallas(q, k_pages, v_pages, block_indices,
+        return _bsd_splitk_pallas(q, k_pages, v_pages, layer, block_indices,
                                   page_table, kv_len, block_size=block_size,
                                   num_splits=num_splits, interpret=True,
                                   k_scales=k_scales, v_scales=v_scales)

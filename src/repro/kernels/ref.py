@@ -22,8 +22,8 @@ gate_gt_attention_ref:
 
 Fused dequant (ISSUE 9): every decode ref takes optional
 ``k_scales``/``v_scales`` — per-block symmetric dequant factors (value =
-stored * scale), [B, Hkv, nb] for the contiguous cache, [P, Hkv, 1] pool
-rows for the paged twins. The scale multiply happens on the GATHERED
+stored * scale), [B, Hkv, nb] for the contiguous cache, [L, P, Hkv, 1]
+pool rows for the paged twins. The scale multiply happens on the GATHERED
 selected blocks only, inside the same fp32 upcast attention already does
 — no cache-sized fp copy materializes, and ``None`` leaves the original
 math verbatim (bitwise contract).
@@ -103,7 +103,8 @@ def sparse_decode_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 
 def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
-                            v_pages: jnp.ndarray, block_indices: jnp.ndarray,
+                            v_pages: jnp.ndarray, layer: jnp.ndarray,
+                            block_indices: jnp.ndarray,
                             page_table: jnp.ndarray, kv_len: jnp.ndarray, *,
                             block_size: int,
                             k_scales: Optional[jnp.ndarray] = None,
@@ -111,19 +112,20 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                             ) -> jnp.ndarray:
     """Paged twin of ``sparse_decode_ref``.
 
-    k_pages/v_pages: [P, Hkv, ps, Dh] head-major global pools
-    (ps == block_size); page_table: [B, npt] int32 logical block ->
+    k_pages/v_pages: [L, P, Hkv, ps, Dh] layer-stacked head-major global
+    pools (ps == block_size), read at the int32 ``layer`` index in the
+    same gather as the pages; page_table: [B, npt] int32 logical block ->
     physical page; block_indices carry LOGICAL block ids (the gate's view)
     — the logical->physical indirection happens here, mirroring the
     kernel's scalar-prefetch index_map. The selected pages are gathered
     directly off the native pool layout (no pool-sized transpose); after
     the gather the math is kept identical to the contiguous reference so
     paged == contiguous holds to rounding. ``k_scales``/``v_scales``
-    [P, Hkv, 1] dequantize int8 pools on the gathered pages only (the
+    [L, P, Hkv, 1] dequantize int8 pools on the gathered pages only (the
     scale row rides the same physical-page gather as its page).
     """
     b, hkv, g, dh = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     assert ps == block_size, (ps, block_size)
     nsel = block_indices.shape[-1]
     scale = 1.0 / math.sqrt(dh)
@@ -133,12 +135,12 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                           (b, hkv, page_table.shape[1]))
     phys = jnp.take_along_axis(pt, idx, axis=2)                  # [B,Hkv,nsel]
     har = jnp.arange(hkv)[None, :, None]
-    kg = k_pages[phys, har]                                # [B,Hkv,nsel,ps,Dh]
-    vg = v_pages[phys, har]
+    kg = k_pages[layer, phys, har]                         # [B,Hkv,nsel,ps,Dh]
+    vg = v_pages[layer, phys, har]
     if k_scales is not None:
-        kg = kg.astype(jnp.float32) * k_scales[phys, har][..., None]
+        kg = kg.astype(jnp.float32) * k_scales[layer, phys, har][..., None]
     if v_scales is not None:
-        vg = vg.astype(jnp.float32) * v_scales[phys, har][..., None]
+        vg = vg.astype(jnp.float32) * v_scales[layer, phys, har][..., None]
     kg = kg.reshape(b, hkv, nsel * ps, dh)                 # [B,Hkv,n*ps,Dh]
     vg = vg.reshape(b, hkv, nsel * ps, dh)
 
@@ -159,7 +161,7 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 def paged_sparse_decode_splitk_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
-                                   v_pages: jnp.ndarray,
+                                   v_pages: jnp.ndarray, layer: jnp.ndarray,
                                    block_indices: jnp.ndarray,
                                    page_table: jnp.ndarray,
                                    kv_len: jnp.ndarray, *, block_size: int,
@@ -182,12 +184,12 @@ def paged_sparse_decode_splitk_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     paper's num_split kernel does on-chip.
     """
     if num_splits <= 1:
-        return paged_sparse_decode_ref(q, k_pages, v_pages, block_indices,
-                                       page_table, kv_len,
+        return paged_sparse_decode_ref(q, k_pages, v_pages, layer,
+                                       block_indices, page_table, kv_len,
                                        block_size=block_size,
                                        k_scales=k_scales, v_scales=v_scales)
     b, hkv, g, dh = q.shape
-    ps = k_pages.shape[2]
+    ps = k_pages.shape[3]
     assert ps == block_size, (ps, block_size)
     nsel = block_indices.shape[-1]
     scale = 1.0 / math.sqrt(dh)
@@ -205,12 +207,12 @@ def paged_sparse_decode_splitk_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                           (b, hkv, num_splits, npt))
     phys = jnp.take_along_axis(pt, idx, axis=3)          # [B,Hkv,NS,per]
     har = jnp.arange(hkv)[None, :, None, None]
-    kg = k_pages[phys, har]                        # [B,Hkv,NS,per,ps,Dh]
-    vg = v_pages[phys, har]
+    kg = k_pages[layer, phys, har]                 # [B,Hkv,NS,per,ps,Dh]
+    vg = v_pages[layer, phys, har]
     if k_scales is not None:
-        kg = kg.astype(jnp.float32) * k_scales[phys, har][..., None]
+        kg = kg.astype(jnp.float32) * k_scales[layer, phys, har][..., None]
     if v_scales is not None:
-        vg = vg.astype(jnp.float32) * v_scales[phys, har][..., None]
+        vg = vg.astype(jnp.float32) * v_scales[layer, phys, har][..., None]
     kg = kg.reshape(b, hkv, num_splits, per * ps, dh)
     vg = vg.reshape(b, hkv, num_splits, per * ps, dh)
 

@@ -10,8 +10,15 @@ per-unit page-pool layers — the SeerAttention-R gate is a plug-in over
 existing attention, so the serving substrate must not care which family
 the attention block lives in.
 
-Everything here is a VERBATIM extraction from ``models.transformer``
-(the jaxprs are unchanged, so the transformer goldens stay bitwise);
+Both families carry the layer-stacked pools through the layer scan whole
+and hand the body a layer index: every pool read and write is at
+``[layer, ...]`` of the carried buffer, so the decode step makes no
+layer-sized slice, re-layout or restack of a K/V pool (a scan cannot
+alias its xs with its ys, so pools passed that way are sliced and
+restacked into fresh buffers every step).
+
+Everything here was extracted from ``models.transformer``, and the
+decode outputs stay bitwise those the transformer goldens pin;
 ``transformer`` re-exports these names for backward compatibility.
 """
 from __future__ import annotations
@@ -133,13 +140,14 @@ def zero_decode_aux(batch: int) -> Dict[str, jnp.ndarray]:
 
 
 def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
-                           k_pages, v_pages, kg_pages, page_table, cur_len,
-                           active, options: DecodeOptions,
-                           budget_blocks=None, kmin_pages=None,
-                           kmax_pages=None, k_scale=None, v_scale=None,
+                           pages, layer, page_table, cur_len, active,
+                           options: DecodeOptions, budget_blocks=None,
                            shard=None, stage=None, plan=None):
-    """One token over paged KV. x1 [S,1,d]; pools for ONE layer HEAD-MAJOR
-    [P, Hkv, ps, Dh]; page_table [S, npt]; cur_len/active [S] per-slot.
+    """One token over paged KV. x1 [S,1,d]; ``pages`` the layer-STACKED
+    ``serve.paging.PagedPages`` (HEAD-MAJOR [L, P, Hkv, ps, Dh] K/V) and
+    ``layer`` the int32 index of this layer in them; page_table [S, npt];
+    cur_len/active [S] per-slot. Returns (out, new pages, aux[, plan]):
+    the pools come back whole, written in place at ``[layer, ...]``.
 
     ``stage``/``plan``: per-layer staging of a step-level SelectionSchedule
     and the carried [S, Hkv, k] plan — same contract as the contiguous
@@ -164,13 +172,17 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
     collectives — bitwise equal to the unsharded paged step. Requires the
     gate policy; ungated/dense slots fall through to the local paths.
 
-    ``k_scale``/``v_scale`` [P, Hkv, 1] f32 (int8 pools, ISSUE 9): when
-    present the K/V pools are int8, the trailing page is requantized per
-    append (``paging.append_token_paged_quant``) and every consumer —
+    ``pages.k_scale_pages``/``v_scale_pages`` [L, P, Hkv, 1] f32 (int8
+    pools): when present the K/V pools are int8, the trailing
+    page is requantized per append (``paging.append_token_paged_quant``)
+    and every consumer —
     block-sparse kernels, dense gather fallback, Kg/min-max finalize,
     trailing-block Quest recompute — dequantizes with the scale rows
     (fused in-kernel on the sparse path; no cache-sized fp copy). None
     keeps the fp code path verbatim."""
+    from repro.serve import paging as pg
+    (k_pages, v_pages, kg_pages, kmin_pages, kmax_pages,
+     k_scale, v_scale) = pages
     b = x1.shape[0]
     dh, hkv, g = cfg.resolved_head_dim, cfg.n_kv_heads, cfg.gqa_group
     ps = cfg.gate.block_size
@@ -196,7 +208,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
     # out-of-bounds for the K/V pools. Attention consumers read through a
     # clamped twin; a selected-evicted block is caught by the
     # touched-pages aux and the step replayed after restore.
-    pt_kv = (jnp.minimum(page_table, k_pages.shape[0] - 1)
+    pt_kv = (jnp.minimum(page_table, k_pages.shape[1] - 1)
              if options.track_evictions else page_table)
 
     if sparse_on and options.kernel_impl == "sharded" and policy.needs_gate \
@@ -215,8 +227,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         o, k_pages, v_pages, kg_pages, k_scale, v_scale, idx = \
             sharded_paged_decode(
                 qg, qgrp, kr[:, 0], v[:, 0], k_pages, v_pages, kg_pages,
-                page_table, cur_len, active, p["gate"]["wk"], mesh=mesh,
-                cfg=cfg.gate, rope_theta=cfg.rope_theta,
+                layer, page_table, cur_len, active, p["gate"]["wk"],
+                mesh=mesh, cfg=cfg.gate, rope_theta=cfg.rope_theta,
                 max_selected=options.max_selected(cfg),
                 budget_blocks=budget_blocks, split_k=options.split_k,
                 inner_impl=platform_kernel_impl(),
@@ -228,11 +240,10 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         if options.track_evictions:
             aux = aux + (_touched_pages(idx, npt),)
         out = linear(p["wo"], o.reshape(b, 1, hkv * g * dh))
-        ret = (out, (k_pages, v_pages, kg_pages, kmin_pages, kmax_pages,
-                     k_scale, v_scale), aux)
+        ret = (out, pg.PagedPages(k_pages, v_pages, kg_pages, kmin_pages,
+                                  kmax_pages, k_scale, v_scale), aux)
         return ret + (idx,) if stage is not None else ret
 
-    from repro.serve import paging as pg
     staged = stage is not None and sparse_on
     # mirror the contiguous path: the Kg page rows only advance for the
     # policy that reads them (append skips the gate projection on None);
@@ -244,19 +255,19 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         if k_scale is not None:
             k_pages, v_pages, kg_pages, k_scale, v_scale = \
                 pg.append_token_paged_quant(
-                    k_pages, v_pages, kg_pages, k_scale, v_scale, kr[:, 0],
-                    v[:, 0], page_table, cur_len, active, gate_for_append,
-                    cfg.gate, rope_theta=cfg.rope_theta)
+                    k_pages, v_pages, kg_pages, k_scale, v_scale, layer,
+                    kr[:, 0], v[:, 0], page_table, cur_len, active,
+                    gate_for_append, cfg.gate, rope_theta=cfg.rope_theta)
         else:
             k_pages, v_pages, kg_pages = pg.append_token_paged(
-                k_pages, v_pages, kg_pages, kr[:, 0], v[:, 0], page_table,
-                cur_len, active, gate_for_append, cfg.gate,
+                k_pages, v_pages, kg_pages, layer, kr[:, 0], v[:, 0],
+                page_table, cur_len, active, gate_for_append, cfg.gate,
                 rope_theta=cfg.rope_theta)
         # ... and the min/max metadata page rows only for the policy that
         # reads THEM (QuestPolicy): finalize a page's row when it fills
         if policy.needs_meta and kmin_pages is not None and not staged:
             kmin_pages, kmax_pages = pg.append_meta_paged(
-                kmin_pages, kmax_pages, k_pages, page_table, cur_len,
+                kmin_pages, kmax_pages, k_pages, layer, page_table, cur_len,
                 active, ps, k_scale=k_scale)
     new_len = cur_len + active.astype(jnp.int32)
 
@@ -270,15 +281,15 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 kg_pages = jax.lax.cond(
                     do_select,
                     lambda kgp: pg.finalize_kg_paged(
-                        k_pages, kgp, page_table, cur_len, active,
+                        k_pages, kgp, layer, page_table, cur_len, active,
                         p["gate"], cfg.gate, rope_theta=cfg.rope_theta,
                         k_scale=k_scale),
                     lambda kgp: kgp, kg_pages)
             if policy.needs_meta and kmin_pages is not None:
                 def _adv_meta(mn, mx):
-                    return pg.append_meta_paged(mn, mx, k_pages, page_table,
-                                                cur_len, active, ps,
-                                                k_scale=k_scale)
+                    return pg.append_meta_paged(mn, mx, k_pages, layer,
+                                                page_table, cur_len, active,
+                                                ps, k_scale=k_scale)
                 kmin_pages, kmax_pages = jax.lax.cond(
                     do_select, _adv_meta, lambda mn, mx: (mn, mx),
                     kmin_pages, kmax_pages)
@@ -286,8 +297,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         inp = SelectionInputs(q_nope=q_nope, qr=qr, pos=pos, new_len=new_len,
                               gate_params=p.get("gate"), kg_pages=kg_pages,
                               k_pages=k_pages, page_table=page_table,
-                              kmin_pages=kmin_pages, kmax_pages=kmax_pages,
-                              k_scale_pages=k_scale)
+                              layer=layer, kmin_pages=kmin_pages,
+                              kmax_pages=kmax_pages, k_scale_pages=k_scale)
 
         def _fresh(cur):
             del cur
@@ -307,15 +318,15 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
 
         def _run_sparse(_):
-            o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx,
+            o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, layer, idx,
                                         pt_kv, new_len, block_size=ps,
                                         impl=options.impl,
                                         k_scales=k_scale, v_scales=v_scale)
             return o.reshape(b, 1, hkv * g, dh)
 
         def _run_dense(_):
-            k_ct = pg.gather_kv(k_pages, pt_kv, k_scale)
-            v_ct = pg.gather_kv(v_pages, pt_kv, v_scale)
+            k_ct = pg.gather_kv(k_pages, layer, pt_kv, k_scale)
+            v_ct = pg.gather_kv(v_pages, layer, pt_kv, v_scale)
             return decode_attention(
                 qr, k_ct, v_ct, new_len,
                 logit_softcap=cfg.attn_logit_softcap).reshape(
@@ -335,15 +346,15 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                             _touched_pages(idx, npt))
             aux = aux + (tch,)
         out = linear(p["wo"], o.reshape(b, 1, hkv * g * dh))
-        return (out, (k_pages, v_pages, kg_pages, kmin_pages, kmax_pages,
-                      k_scale, v_scale), aux, idx)
+        return (out, pg.PagedPages(k_pages, v_pages, kg_pages, kmin_pages,
+                                   kmax_pages, k_scale, v_scale), aux, idx)
 
     if sparse_on:
         inp = SelectionInputs(q_nope=q_nope, qr=qr, pos=pos, new_len=new_len,
                               gate_params=p.get("gate"), kg_pages=kg_pages,
                               k_pages=k_pages, page_table=page_table,
-                              kmin_pages=kmin_pages, kmax_pages=kmax_pages,
-                              k_scale_pages=k_scale)
+                              layer=layer, kmin_pages=kmin_pages,
+                              kmax_pages=kmax_pages, k_scale_pages=k_scale)
         with jax.named_scope("gate_select"):
             idx = policy.select(inp, cfg, impl=select_impl(options.impl),
                                 max_selected=options.max_selected(cfg),
@@ -354,8 +365,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 idx = jnp.where(slot_cap, idx, -1)
         qgrp = qr[:, 0].reshape(b, hkv, g, dh)
         with jax.named_scope("sparse_attn"):
-            o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, idx, pt_kv,
-                                        new_len, block_size=ps,
+            o = ops.paged_sparse_decode(qgrp, k_pages, v_pages, layer, idx,
+                                        pt_kv, new_len, block_size=ps,
                                         impl=options.impl,
                                         k_scales=k_scale, v_scales=v_scale)
         o = o.reshape(b, 1, hkv * g, dh)
@@ -365,8 +376,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         if options.track_evictions:
             aux = aux + (_touched_pages(idx, npt),)
     else:
-        k_ct = pg.gather_kv(k_pages, pt_kv, k_scale)       # [S,Hkv,npt*ps,Dh]
-        v_ct = pg.gather_kv(v_pages, pt_kv, v_scale)
+        k_ct = pg.gather_kv(k_pages, layer, pt_kv, k_scale)  # [S,Hkv,npt*ps,Dh]
+        v_ct = pg.gather_kv(v_pages, layer, pt_kv, v_scale)
         o = decode_attention(qr, k_ct, v_ct, new_len,
                              logit_softcap=cfg.attn_logit_softcap)
         aux = (_dense_aux(new_len, ps) if options.measure_sparsity
@@ -374,26 +385,24 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         if options.track_evictions:
             aux = aux + (_dense_touched(new_len, ps, npt),)
     out = linear(p["wo"], o.reshape(b, 1, hkv * g * dh))
-    ret = (out, (k_pages, v_pages, kg_pages, kmin_pages, kmax_pages,
-                 k_scale, v_scale), aux)
+    ret = (out, pg.PagedPages(k_pages, v_pages, kg_pages, kmin_pages,
+                              kmax_pages, k_scale, v_scale), aux)
     # an ungated layer under a plan-carrying schedule: dense fallback, the
     # plan passes through untouched (same contract as attention_decode)
     return ret + (plan,) if stage is not None else ret
 
 
-def block_decode_paged(p: Params, x1, cfg: ModelConfig, layer_pages,
+def block_decode_paged(p: Params, x1, cfg: ModelConfig, pages, layer,
                        page_table, cur_len, active, *,
                        options: DecodeOptions, budget_blocks=None,
                        shard=None, stage=None, plan=None):
-    (k_pages, v_pages, kg_pages, kmin_pages, kmax_pages,
-     k_scale, v_scale) = layer_pages
+    """One decoder block over the layer-stacked ``pages`` at ``layer``
+    (see ``attention_decode_paged``); returns (x1, new pages, aux[, plan])."""
     h = rms_norm(p["ln1"], x1, cfg.norm_eps)
     ret = attention_decode_paged(
-        p["attn"], h, cfg, k_pages=k_pages, v_pages=v_pages,
-        kg_pages=kg_pages, page_table=page_table, cur_len=cur_len,
-        active=active, options=options, budget_blocks=budget_blocks,
-        kmin_pages=kmin_pages, kmax_pages=kmax_pages, k_scale=k_scale,
-        v_scale=v_scale, shard=shard, stage=stage, plan=plan)
+        p["attn"], h, cfg, pages=pages, layer=layer, page_table=page_table,
+        cur_len=cur_len, active=active, options=options,
+        budget_blocks=budget_blocks, shard=shard, stage=stage, plan=plan)
     attn_out, new_pages, aux = ret[:3]
     x1 = x1 + attn_out
     h2 = rms_norm(p["ln2"], x1, cfg.norm_eps)

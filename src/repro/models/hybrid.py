@@ -280,8 +280,9 @@ def lm_decode_step_paged(params: Params, pages, slot_state, token,
     """Continuous-batching decode step (PR 10 unified signature).
 
     The attention layer-core (``attn_core.block_decode_paged``) runs once
-    per unit with the SHARED attention weights over that unit's layer
-    slice of the page pools (``[n_units, P, Hkv, ps, Dh]``); the mamba2
+    per unit with the SHARED attention weights at that unit's layer index
+    of the page pools (``[n_units, P, Hkv, ps, Dh]``, carried through the
+    unit scan whole and written in place); the mamba2
     backbone steps update the per-slot recurrent ``slot_state`` rows.
     Inactive slots' recurrent updates are garbage but harmless — the
     engine rewrites their rows at admission/restore, exactly as it
@@ -290,7 +291,6 @@ def lm_decode_step_paged(params: Params, pages, slot_state, token,
     from repro.core.policy import default_options
     from repro.models.attn_core import (aggregate_decode_aux,
                                         block_decode_paged)
-    from repro.serve.paging import PagedPages
     options = options if options is not None else default_options(cfg)
     if options.schedule.needs_plan:
         raise NotImplementedError(
@@ -312,19 +312,21 @@ def lm_decode_step_paged(params: Params, pages, slot_state, token,
     h_u = slot_state.h[:lm].reshape(
         (n_units, period) + slot_state.h.shape[1:])
 
-    def unit(x1, inp):
-        ublocks, uconv, uh, layer_pages = inp
+    def unit(carry, inp):
+        x1, pages = carry
+        ublocks, uconv, uh, layer = inp
         x1, (c2, h2) = layer_scan(mamba_step_scan, x1,
                                   (ublocks, uconv, uh),
                                   unroll=not cfg.scan_layers)
-        x1, new_pages, aux = block_decode_paged(
-            params["shared_attn"], x1, cfg, layer_pages, page_table,
+        x1, pages, aux = block_decode_paged(
+            params["shared_attn"], x1, cfg, pages, layer, page_table,
             cur_len, active, options=options, budget_blocks=budget_blocks,
             shard=shard)
-        return x1, (c2, h2, new_pages, aux)
+        return (x1, pages), (c2, h2, aux)
 
-    x1, (conv2, h2, new_pages, auxs) = layer_scan(
-        unit, x1, (params["units"], conv_u, h_u, tuple(pages)),
+    (x1, pages), (conv2, h2, auxs) = layer_scan(
+        unit, (x1, pages),
+        (params["units"], conv_u, h_u, jnp.arange(n_units, dtype=jnp.int32)),
         unroll=not cfg.scan_layers)
     conv2 = conv2.reshape((-1,) + conv2.shape[2:])
     h2 = h2.reshape((-1,) + h2.shape[2:])
@@ -339,7 +341,7 @@ def lm_decode_step_paged(params: Params, pages, slot_state, token,
     x1 = rms_norm(params["final_norm"], x1, cfg.norm_eps)
     logits = (x1 @ params["embed"]["w"].T if cfg.tie_embeddings
               else linear(params["lm_head"], x1))
-    return (logits[:, 0], PagedPages(*new_pages),
+    return (logits[:, 0], pages,
             slot_state._replace(conv=conv2.astype(slot_state.conv.dtype),
                                 h=h2),
             aggregate_decode_aux(auxs))

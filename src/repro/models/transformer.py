@@ -720,7 +720,9 @@ def lm_decode_step_paged(params: Params, pages, slot_state,
                          options: Optional[DecodeOptions] = None,
                          budget_blocks=None, shard=None):
     """Continuous-batching decode step. token/cur_len/active [n_slots];
-    pages is a ``serve.paging.PagedPages`` (layer-stacked pools);
+    pages is a ``serve.paging.PagedPages`` (layer-stacked pools, carried
+    through the layer scan whole and written in place at each layer's
+    index);
     page_table [n_slots, npt]; ``budget_blocks`` [n_slots] (optional,
     runtime) per-slot selected-block caps for per-request budget
     overrides. Returns (logits [n_slots, V], new pages, slot_state, aux
@@ -742,8 +744,11 @@ def lm_decode_step_paged(params: Params, pages, slot_state,
     if cfg.cross_attn_period:
         raise NotImplementedError("paged decode: cross-attn families TBD")
     options = options if options is not None else default_options(cfg)
-    from repro.serve.paging import PagedPages
     x1 = jnp.take(params["embed"]["w"], token[:, None], axis=0)
+    # the pools ride the scan CARRY and each layer writes them in place at
+    # its index; as xs/ys they would be sliced per layer and restacked
+    # into a fresh pool every step (a scan cannot alias xs with ys)
+    layers = jnp.arange(pages.k_pages.shape[0], dtype=jnp.int32)
 
     if options.schedule.needs_plan:
         # step-level selection plan: same staging as lm_decode_step, the
@@ -757,37 +762,36 @@ def lm_decode_step_paged(params: Params, pages, slot_state,
                          jnp.int32)
 
         def plan_scan(carry, inp):
-            x1, plan = carry
-            layer_p, layer_pages, stage = inp
-            y, new_pages, aux, plan = block_decode_paged(
-                layer_p, x1, cfg, layer_pages, page_table, cur_len, active,
+            x1, plan, pages = carry
+            layer_p, layer, stage = inp
+            y, pages, aux, plan = block_decode_paged(
+                layer_p, x1, cfg, pages, layer, page_table, cur_len, active,
                 options=options, budget_blocks=budget_blocks, shard=shard,
                 stage=stage, plan=plan)
-            return (y, plan), (new_pages, aux)
+            return (y, plan, pages), aux
 
-        (x1, _), (new_pages, auxs) = layer_scan(
-            plan_scan, (x1, plan0),
-            (params["blocks"], tuple(pages), stages),
+        (x1, _, pages), auxs = layer_scan(
+            plan_scan, (x1, plan0, pages), (params["blocks"], layers, stages),
             unroll=not cfg.scan_layers)
     else:
-        def self_scan(x1, inp):
-            layer_p, layer_pages = inp
-            y, new_pages, aux = block_decode_paged(
-                layer_p, x1, cfg, layer_pages, page_table, cur_len, active,
+        def self_scan(carry, inp):
+            x1, pages = carry
+            layer_p, layer = inp
+            y, pages, aux = block_decode_paged(
+                layer_p, x1, cfg, pages, layer, page_table, cur_len, active,
                 options=options, budget_blocks=budget_blocks, shard=shard)
-            return y, (new_pages, aux)
+            return (y, pages), aux
 
-        x1, (new_pages, auxs) = layer_scan(self_scan, x1,
-                                           (params["blocks"], tuple(pages)),
-                                           unroll=not cfg.scan_layers)
+        (x1, pages), auxs = layer_scan(self_scan, (x1, pages),
+                                       (params["blocks"], layers),
+                                       unroll=not cfg.scan_layers)
     with jax.named_scope("lm_head"):
         x1 = rms_norm(params["final_norm"], x1, cfg.norm_eps)
         if cfg.tie_embeddings:
             logits = x1 @ params["embed"]["w"].T
         else:
             logits = linear(params["lm_head"], x1)
-    return (logits[:, 0], PagedPages(*new_pages), slot_state,
-            aggregate_decode_aux(auxs))
+    return logits[:, 0], pages, slot_state, aggregate_decode_aux(auxs)
 
 
 def lm_prefill(params: Params, batch: Dict[str, jnp.ndarray],

@@ -239,7 +239,7 @@ def scatter_prefill(pages: PagedPages, k_cache: jnp.ndarray,
 
 
 def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-                       kg_pages: Optional[jnp.ndarray],
+                       kg_pages: Optional[jnp.ndarray], layer: jnp.ndarray,
                        kr_new: jnp.ndarray, v_new: jnp.ndarray,
                        page_table: jnp.ndarray, cur_len: jnp.ndarray,
                        active: jnp.ndarray, gate_params: Optional[Dict],
@@ -248,11 +248,15 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                                   Optional[jnp.ndarray]]:
     """ONE layer's paged twin of the contiguous write + ``update_kcache``.
 
-    kr_new/v_new: [S, Hkv, Dh] the new token's post-rope K / V per slot.
-    Writes land at (page_table[slot, cur_len // ps], :, cur_len % ps); rows
-    with ``active == False`` are routed to the null page. When a slot's
-    page completes ((cur_len+1) % ps == 0) the page's keys are rotated
-    back to the pre-rope frame (same trick as kcache.update_kcache) and
+    The pools are the layer-STACKED ones ([L, P, ...]) and ``layer`` the
+    int32 layer index: the write lands in place in the stacked buffer, so
+    the decode layer loop can carry the pools whole (no per-layer slice or
+    restack of a pool-sized array). kr_new/v_new: [S, Hkv, Dh] the new
+    token's post-rope K / V per slot. Writes land at (layer,
+    page_table[slot, cur_len // ps], :, cur_len % ps); rows with
+    ``active == False`` are routed to the null page. When a slot's page
+    completes ((cur_len+1) % ps == 0) the page's keys are rotated back to
+    the pre-rope frame (same trick as kcache.update_kcache) and
     pooled+projected into that page's ``kg_pages`` row.
     """
     ps = cfg.block_size
@@ -262,14 +266,19 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     off = cur_len % ps
     phys = page_table[sidx, logical]                       # [S]
     phys = jnp.where(active, phys, NULL_PAGE)
-    k_pages = k_pages.at[phys, :, off].set(kr_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, :, off].set(v_new.astype(v_pages.dtype))
+    # every index spelled out over the leading four axes: a slice between
+    # the indexed axes would make XLA scatter into a transposed copy of
+    # the whole stacked pool
+    at = (layer, phys[:, None], jnp.arange(k_pages.shape[2])[None, :],
+          off[:, None])                                    # -> [S, Hkv]
+    k_pages = k_pages.at[at].set(kr_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[at].set(v_new.astype(v_pages.dtype))
 
     if kg_pages is None or gate_params is None:
         return k_pages, v_pages, kg_pages
 
-    kg_pages = finalize_kg_paged(k_pages, kg_pages, page_table, cur_len,
-                                 active, gate_params, cfg,
+    kg_pages = finalize_kg_paged(k_pages, kg_pages, layer, page_table,
+                                 cur_len, active, gate_params, cfg,
                                  rope_theta=rope_theta)
     return k_pages, v_pages, kg_pages
 
@@ -277,6 +286,7 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                              kg_pages: Optional[jnp.ndarray],
                              k_scale: jnp.ndarray, v_scale: jnp.ndarray,
+                             layer: jnp.ndarray,
                              kr_new: jnp.ndarray, v_new: jnp.ndarray,
                              page_table: jnp.ndarray, cur_len: jnp.ndarray,
                              active: jnp.ndarray,
@@ -285,7 +295,8 @@ def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                              ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                         Optional[jnp.ndarray],
                                         jnp.ndarray, jnp.ndarray]:
-    """Int8 twin of ``append_token_paged`` (ISSUE 9).
+    """Int8 twin of ``append_token_paged``, on the same stacked
+    pools and ``layer`` index.
 
     The trailing partial page is REQUANTIZED per append: dequant it with
     its stored scale row, insert the new fp token row, recompute the
@@ -310,11 +321,13 @@ def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
              )[:, None, :, None]                           # [S,1,ps,1]
 
     def requant(pages_q, scale_pool, new_row):
-        page = dequantize_block(pages_q[phys], scale_pool[phys])
+        page = dequantize_block(pages_q[layer, phys],
+                                scale_pool[layer, phys])
         page = jnp.where(onehot[:, None, :, None],
                          new_row.astype(jnp.float32)[:, :, None, :], page)
         q, sc = quantize_block(page, valid)
-        return pages_q.at[phys].set(q), scale_pool.at[phys].set(sc)
+        return (pages_q.at[layer, phys].set(q),
+                scale_pool.at[layer, phys].set(sc))
 
     k_pages, k_scale = requant(k_pages, k_scale, kr_new)
     v_pages, v_scale = requant(v_pages, v_scale, v_new)
@@ -322,19 +335,21 @@ def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     if kg_pages is None or gate_params is None:
         return k_pages, v_pages, kg_pages, k_scale, v_scale
 
-    kg_pages = finalize_kg_paged(k_pages, kg_pages, page_table, cur_len,
-                                 active, gate_params, cfg,
+    kg_pages = finalize_kg_paged(k_pages, kg_pages, layer, page_table,
+                                 cur_len, active, gate_params, cfg,
                                  rope_theta=rope_theta, k_scale=k_scale)
     return k_pages, v_pages, kg_pages, k_scale, v_scale
 
 
 def finalize_kg_paged(k_pages: jnp.ndarray, kg_pages: jnp.ndarray,
+                      layer: jnp.ndarray,
                       page_table: jnp.ndarray, cur_len: jnp.ndarray,
                       active: jnp.ndarray, gate_params: Dict,
                       cfg: GateConfig, *, rope_theta: float = 10000.0,
                       k_scale: Optional[jnp.ndarray] = None
                       ) -> jnp.ndarray:
-    """Finalize the Kg row of each slot's just-completed page.
+    """Finalize the Kg row of each slot's just-completed page, at
+    ``[layer, phys]`` of the stacked pools.
 
     Called AFTER the new token's key is written: when a slot's page
     completes ((cur_len+1) % ps == 0) the page's keys are rotated back to
@@ -360,24 +375,26 @@ def finalize_kg_paged(k_pages: jnp.ndarray, kg_pages: jnp.ndarray,
                                  lg * ps, lg, cfg,
                                  is_roped=True, rope_theta=rope_theta)
 
-    blk = k_pages[phys]                                    # [S, Hkv, ps, Dh]
+    blk = k_pages[layer, phys]                             # [S, Hkv, ps, Dh]
     if k_scale is not None:
-        blk = dequantize_block(blk, k_scale[phys])
+        blk = dequantize_block(blk, k_scale[layer, phys])
     kg_new = jax.vmap(one_slot)(blk, logical)              # [S, Hkv, Dg]
     phys_kg = jnp.where(completed, phys, NULL_PAGE)
-    kg_cur = kg_pages[phys_kg]
+    kg_cur = kg_pages[layer, phys_kg]
     kg_write = jnp.where(completed[:, None, None],
                          kg_new.astype(kg_pages.dtype), kg_cur)
-    return kg_pages.at[phys_kg].set(kg_write)
+    return kg_pages.at[layer, phys_kg].set(kg_write)
 
 
 def append_meta_paged(kmin_pages: jnp.ndarray, kmax_pages: jnp.ndarray,
-                      k_pages: jnp.ndarray, page_table: jnp.ndarray,
+                      k_pages: jnp.ndarray, layer: jnp.ndarray,
+                      page_table: jnp.ndarray,
                       cur_len: jnp.ndarray, active: jnp.ndarray,
                       page_size: int,
                       k_scale: Optional[jnp.ndarray] = None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """ONE layer's paged twin of ``metacache.update_metacache``.
+    """ONE layer's paged twin of ``metacache.update_metacache``, written
+    at ``[layer, phys]`` of the stacked pools.
 
     Called AFTER ``append_token_paged`` wrote the new token's key: when a
     slot's page completes ((cur_len+1) % ps == 0) that page's key min/max
@@ -395,16 +412,16 @@ def append_meta_paged(kmin_pages: jnp.ndarray, kmax_pages: jnp.ndarray,
     completed = active & (((cur_len + 1) % ps) == 0)       # [S]
 
     from repro.core.metacache import _block_minmax
-    blk = k_pages[phys]                                    # [S, Hkv, ps, Dh]
+    blk = k_pages[layer, phys]                             # [S, Hkv, ps, Dh]
     if k_scale is not None:
-        blk = dequantize_block(blk, k_scale[phys])
+        blk = dequantize_block(blk, k_scale[layer, phys])
     mn_new, mx_new = _block_minmax(blk, jnp.ones((1, 1, ps, 1), bool))
     phys_w = jnp.where(completed, phys, NULL_PAGE)
     wm = completed[:, None, None]
-    kmin_pages = kmin_pages.at[phys_w].set(
-        jnp.where(wm, mn_new, kmin_pages[phys_w]))
-    kmax_pages = kmax_pages.at[phys_w].set(
-        jnp.where(wm, mx_new, kmax_pages[phys_w]))
+    kmin_pages = kmin_pages.at[layer, phys_w].set(
+        jnp.where(wm, mn_new, kmin_pages[layer, phys_w]))
+    kmax_pages = kmax_pages.at[layer, phys_w].set(
+        jnp.where(wm, mx_new, kmax_pages[layer, phys_w]))
     return kmin_pages, kmax_pages
 
 
@@ -414,23 +431,24 @@ def gather_kg(kg_pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     return jnp.swapaxes(kg_pages[page_table], 1, 2)
 
 
-def gather_kv(pages_1l: jnp.ndarray, page_table: jnp.ndarray,
-              scale_1l: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """[P, Hkv, ps, Dh] x [S, npt] -> head-major contiguous view
-    [S, Hkv, npt*ps, Dh].
+def gather_kv(pages: jnp.ndarray, layer: jnp.ndarray,
+              page_table: jnp.ndarray,
+              scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Stacked [L, P, Hkv, ps, Dh] at ``layer`` x [S, npt] -> head-major
+    contiguous view [S, Hkv, npt*ps, Dh].
 
     Dense-attention fallback path (and debugging) ONLY — this materialises
     a cache-sized copy by construction (dense reads the whole cache); the
     sparse hot path never calls it, it gathers selected pages only.
-    ``scale_1l`` [P, Hkv, 1] dequantizes int8 pools during the gather.
+    ``scale`` [L, P, Hkv, 1] dequantizes int8 pools during the gather.
     """
     s, npt = page_table.shape
-    g = pages_1l[page_table]                 # [S, npt, Hkv, ps, Dh]
-    if scale_1l is not None:
-        g = dequantize_block(g, scale_1l[page_table])
+    g = pages[layer, page_table]             # [S, npt, Hkv, ps, Dh]
+    if scale is not None:
+        g = dequantize_block(g, scale[layer, page_table])
     g = jnp.swapaxes(g, 1, 2)                # [S, Hkv, npt, ps, Dh]
-    return g.reshape(s, pages_1l.shape[1], npt * pages_1l.shape[2],
-                     pages_1l.shape[3])
+    return g.reshape(s, pages.shape[2], npt * pages.shape[3],
+                     pages.shape[4])
 
 
 class PageAllocator:

@@ -236,9 +236,10 @@ def sharded_paged_decode(
         qgrp: jnp.ndarray,        # [S, Hkv, G, Dh]  attention query grouped
         kr_new: jnp.ndarray,      # [S, Hkv, Dh]     new key (post-rope)
         v_new: jnp.ndarray,       # [S, Hkv, Dh]
-        k_pages: jnp.ndarray,     # [P, Hkv, ps, Dh] ONE layer's pool
+        k_pages: jnp.ndarray,     # [L, P, Hkv, ps, Dh] stacked pool
         v_pages: jnp.ndarray,
-        kg_pages: jnp.ndarray,    # [P, Hkv, Dg]
+        kg_pages: jnp.ndarray,    # [L, P, Hkv, Dg]
+        layer: jnp.ndarray,       # [] int32 layer index (replicated)
         page_table: jnp.ndarray,  # [S, npt] int32   (replicated)
         cur_len: jnp.ndarray,     # [S] length BEFORE this token
         active: jnp.ndarray,      # [S] bool
@@ -254,10 +255,16 @@ def sharded_paged_decode(
         reuse_idx: Optional[jnp.ndarray] = None,   # [S, Hkv, k] carried plan
         do_select: Optional[jnp.ndarray] = None,   # [] bool: fresh vs reuse
         pt_kv: Optional[jnp.ndarray] = None,       # [S, npt] clamped table
-        k_scale: Optional[jnp.ndarray] = None,     # [P, Hkv, 1] int8 scales
+        k_scale: Optional[jnp.ndarray] = None,     # [L, P, Hkv, 1] scales
         v_scale: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, ...]:
     """One PAGED decode step for ONE layer on a sharded mesh.
+
+    The pools are the layer-STACKED, head-sharded ones
+    (``distributed.sharding.paged_pool_pspecs``) and ``layer`` picks the
+    layer: the shard body appends and reads at ``[layer, ...]`` of its
+    local head slice, so the layer loop carries the pools whole and
+    writes them in place.
 
     Composition rule (the paged x sharded design): the page POOLS (and the
     Kg pool, and the gate weights, and the per-head queries) are sharded
@@ -282,9 +289,9 @@ def sharded_paged_decode(
     is the gathered selection for telemetry; the scale slots pass through
     as None on fp pools.
 
-    ``k_scale``/``v_scale`` [P, Hkv, 1] f32 (int8 pools, ISSUE 9): the
-    dequant scale rows, rank-3 per layer, sharded over KV heads exactly
-    like the Kg pool (``spec_h3``) — the per-head quantization axis is
+    ``k_scale``/``v_scale`` [L, P, Hkv, 1] f32 (int8 pools): the
+    dequant scale rows, sharded over KV heads exactly like the Kg pool
+    (``spec_pool4``) — the per-head quantization axis is
     what makes int8 pools compose with head sharding for free. The shard
     body swaps the append for ``paging.append_token_paged_quant`` and
     threads the scales into the block-sparse kernels (fused dequant);
@@ -343,29 +350,33 @@ def sharded_paged_decode(
 
     spec_h3 = P(None, MODEL, None)
     spec_h4 = P(None, MODEL, None, None)
+    spec_pool4 = P(None, None, MODEL, None)          # [L, P, Hkv, Dg|1]
+    spec_pool5 = P(None, None, MODEL, None, None)    # [L, P, Hkv, ps, Dh]
     rep1, rep2 = P(None), P(None, None)
 
     if pt_kv is None:
         pt_kv = page_table
     quant = k_scale is not None
 
-    def local(qg, qgrp, kr_new, v_new, kp, vp, kgp, pt, ptk, cl, act, bb,
-              wk, *extra):
+    def local(qg, qgrp, kr_new, v_new, kp, vp, kgp, ly, pt, ptk, cl, act,
+              bb, wk, *extra):
         extra = list(extra)
         if quant:
             ksc, vsc = extra[0], extra[1]
             extra = extra[2:]
             kp, vp, kgp, ksc, vsc = pg.append_token_paged_quant(
-                kp, vp, kgp, ksc, vsc, kr_new, v_new, pt, cl, act,
+                kp, vp, kgp, ksc, vsc, ly, kr_new, v_new, pt, cl, act,
                 {"wk": wk}, cfg, rope_theta=rope_theta)
         else:
             ksc = vsc = None
             kp, vp, kgp = pg.append_token_paged(
-                kp, vp, kgp, kr_new, v_new, pt, cl, act, {"wk": wk}, cfg,
-                rope_theta=rope_theta)
+                kp, vp, kgp, ly, kr_new, v_new, pt, cl, act, {"wk": wk},
+                cfg, rope_theta=rope_theta)
         new_len = cl + act.astype(jnp.int32)
         n_valid = kc.visible_blocks(jnp.maximum(new_len, 1), cfg.block_size)
-        idx = ops.gate_select_paged(qg, kgp, pt, n_valid, cfg, max_selected,
+        # this layer's Kg rows as a slice, as GatePolicy reads them
+        idx = ops.gate_select_paged(qg, kgp[ly], pt, n_valid, cfg,
+                                    max_selected,
                                     impl=select_impl(inner_impl))
         if extra:
             reuse, do_sel = extra
@@ -374,29 +385,31 @@ def sharded_paged_decode(
         idx = jnp.where(cap, idx, -1)
         if split_k > 1:
             o = ops.paged_sparse_decode_splitk(
-                qgrp, kp, vp, idx, ptk, new_len, block_size=cfg.block_size,
-                num_splits=split_k, impl=inner_impl,
-                k_scales=ksc, v_scales=vsc)
+                qgrp, kp, vp, ly, idx, ptk, new_len,
+                block_size=cfg.block_size, num_splits=split_k,
+                impl=inner_impl, k_scales=ksc, v_scales=vsc)
         else:
-            o = ops.paged_sparse_decode(qgrp, kp, vp, idx, ptk, new_len,
+            o = ops.paged_sparse_decode(qgrp, kp, vp, ly, idx, ptk, new_len,
                                         block_size=cfg.block_size,
                                         impl=inner_impl,
                                         k_scales=ksc, v_scales=vsc)
         out = (o, kp, vp, kgp) + ((ksc, vsc) if quant else ()) + (idx,)
         return out
 
-    in_specs = (spec_h3, spec_h4, spec_h3, spec_h3, spec_h4, spec_h4,
-                spec_h3, rep2, rep2, rep1, rep1, rep1, P(MODEL, None, None))
+    in_specs = (spec_h3, spec_h4, spec_h3, spec_h3, spec_pool5, spec_pool5,
+                spec_pool4, P(), rep2, rep2, rep1, rep1, rep1,
+                P(MODEL, None, None))
     args = (qg, qgrp, kr_new, v_new, k_pages, v_pages, kg_pages,
-            page_table, pt_kv, cur_len, active, budget_blocks, gate_wk)
+            jnp.asarray(layer, jnp.int32), page_table, pt_kv, cur_len,
+            active, budget_blocks, gate_wk)
     if quant:
-        in_specs = in_specs + (spec_h3, spec_h3)
+        in_specs = in_specs + (spec_pool4, spec_pool4)
         args = args + (k_scale, v_scale)
     if reuse_idx is not None:
         in_specs = in_specs + (spec_h3, P())
         args = args + (reuse_idx, jnp.asarray(do_select, bool))
-    out_specs = (spec_h4, spec_h4, spec_h4, spec_h3) \
-        + ((spec_h3, spec_h3) if quant else ()) + (spec_h3,)
+    out_specs = (spec_h4, spec_pool5, spec_pool5, spec_pool4) \
+        + ((spec_pool4, spec_pool4) if quant else ()) + (spec_h3,)
     # the replication check stays on: the per-shard Pallas kernels declare
     # the mesh axes their outputs vary over (kernels' ``out_vma``)
     fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,

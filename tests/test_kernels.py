@@ -210,3 +210,57 @@ def test_property_budget_selection_monotone(seed):
     _, m_small = budget_select(scores, n_valid, small)
     _, m_big = budget_select(scores, n_valid, big)
     assert bool(jnp.all(~m_small | m_big))
+
+
+# ---------------------------------------------------------------------------
+# paged kernels read the layer-stacked pool at a scalar-prefetched layer
+# ---------------------------------------------------------------------------
+
+def _stacked_pools(quant, n_layers=3, npool=9, hkv=2, bs=8, dh=32):
+    """[L, P, Hkv, bs, Dh] K/V pools (fp, or int8 plus [L, P, Hkv, 1]
+    scales), every layer different."""
+    from repro.serve import paging as pg
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    k = jax.random.normal(ks[0], (n_layers, npool, hkv, bs, dh), jnp.float32)
+    v = jax.random.normal(ks[1], (n_layers, npool, hkv, bs, dh), jnp.float32)
+    if not quant:
+        return k, v, None, None
+    kq, ksc = pg.quantize_block(k, jnp.ones_like(k, bool))
+    vq, vsc = pg.quantize_block(v, jnp.ones_like(v, bool))
+    return kq, vq, ksc, vsc
+
+
+def _paged_call(kernel, pools, layer):
+    """One interpret-mode call of a paged kernel on ``pools`` at ``layer``
+    (2 slots, scrambled page tables, a forced trailing block)."""
+    from repro.kernels import block_sparse_decode as bsd
+    k, v, ksc, vsc = pools
+    hkv, bs, dh = k.shape[2], k.shape[3], k.shape[4]
+    b, g, nb = 2, 4, 6
+    table = jnp.asarray(np.stack([1 + np.roll(np.arange(nb), r + 2)
+                                  for r in range(b)]), jnp.int32)
+    kv_len = jnp.asarray([nb * bs, nb * bs - 5], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(13), (b, hkv, g, dh))
+    idx = jnp.asarray([[[5, 0, 2, -1], [5, 3, -1, -1]],
+                       [[5, 1, 4, 2], [5, -1, -1, -1]]], jnp.int32)
+    kw = dict(block_size=bs, interpret=True, k_scales=ksc, v_scales=vsc)
+    if kernel == "splitk":
+        return bsd.block_sparse_decode_paged_splitk(
+            q, k, v, layer, idx, table, kv_len, num_splits=2, **kw)
+    return bsd.block_sparse_decode_paged(q, k, v, layer, idx, table, kv_len,
+                                         **kw)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kernel,quant", [
+    ("plain", False), ("plain", True), ("splitk", False), ("splitk", True)])
+def test_paged_kernel_layer_index_bitwise(kernel, quant, layer):
+    """A block-sparse paged kernel at ``layer`` of a 3-layer stacked pool
+    equals, bitwise, the same call on that layer's pool alone (a one-layer
+    stack at layer 0): the layer index only picks which pool rows are
+    streamed."""
+    pools = _stacked_pools(quant)
+    one = tuple(None if x is None else x[layer:layer + 1] for x in pools)
+    got = _paged_call(kernel, pools, jnp.int32(layer))
+    want = _paged_call(kernel, one, jnp.int32(0))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

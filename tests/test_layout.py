@@ -16,6 +16,7 @@ import dataclasses
 import functools
 import inspect
 import os
+import re
 import subprocess
 import sys
 
@@ -214,6 +215,92 @@ def test_no_cache_sized_transpose_on_decode_path():
         src = inspect.getsource(fn)
         for tok in ("moveaxis", "swapaxes", ".transpose("):
             assert tok not in src, f"{fn.__name__} contains {tok}"
+
+
+# shapes an op of the compiled paged step may not produce unless it is
+# the in-place write: one layer's pool, its one-layer slice, the stack
+_POOL_COPY_OPS = ("copy", "dynamic-slice", "dynamic-update-slice", "fusion")
+
+
+def _hlo_computations(text):
+    """Compiled HLO text -> {computation name: [instruction lines]}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith(" "):
+            comps[name].append(line.strip())
+    return comps
+
+
+def _pool_copies(text, pool_shapes):
+    """Instructions of ``_POOL_COPY_OPS`` whose result has one of
+    ``pool_shapes``; a fusion whose root is a scatter is the in-place
+    token append into the carried pool and does not count."""
+    comps = _hlo_computations(text)
+    roots = {n: next((ln for ln in body if ln.startswith("ROOT ")), "")
+             for n, body in comps.items()}
+    found = []
+    for body in comps.values():
+        for ln in body:
+            m = re.match(r"(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(", ln)
+            if not m or m.group(2) not in _POOL_COPY_OPS:
+                continue
+            shape = tuple(int(d) for d in m.group(1).split(",") if d)
+            if shape not in pool_shapes:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", ln)
+            if m.group(2) == "fusion" and called and " scatter(" in \
+                    roots.get(called.group(1), ""):
+                continue
+            found.append(ln[:160])
+    return found
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"], ids=["fp", "int8"])
+def test_paged_step_writes_pools_in_place(quantize):
+    """The compiled, donated paged decode step of the tiny bench config
+    (2 slots) holds no pool-sized copy, slice, restack or fusion: the
+    layer scan carries the stacked pools and every layer writes its one
+    token in place. Its temp memory stays under a quarter of the pools'
+    bytes. The pool has 1024 pages, so that the step's pool-independent
+    temp (the per-layer weight slices, ~0.16 MB) is small beside a
+    quarter of the int8 pools too; a scan over the pools as xs/ys needs
+    about one more pool of temp."""
+    import json
+    bench = os.path.join(os.path.dirname(__file__), "..", "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness.model import decode_options, param_shapes, program_config
+    from repro.serve import paging as pg
+    with open(os.path.join(bench, "tests", "data", "tiny.json")) as f:
+        conf = json.load(f)
+    cfg = program_config(conf)
+    opts = decode_options(cfg, conf).replace(quantize=quantize)
+    api = get_api(cfg)
+    n_pages, slots, npt = 1024, 2, 16
+    n_layers = api.paged_attn_layers(cfg)
+    pages = jax.eval_shape(lambda: pg.init_pages(
+        cfg, n_pages, n_layers, quantize=quantize))
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    step = jax.jit(functools.partial(api.decode_step_paged, cfg=cfg,
+                                     options=opts, shard=None),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        param_shapes(cfg), pages, None, i32((slots,)), i32((slots, npt)),
+        i32((slots,)), jax.ShapeDtypeStruct((slots,), jnp.bool_),
+        budget_blocks=i32((slots,))).compile()
+    layer_pool = (n_pages, cfg.n_kv_heads, cfg.gate.block_size,
+                  cfg.resolved_head_dim)
+    shapes = {layer_pool, (1,) + layer_pool, (n_layers,) + layer_pool}
+    assert _pool_copies(compiled.as_text(), shapes) == []
+    pool_bytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(pages))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 4, (temp, pool_bytes)
 
 
 def test_select_blocks_zero_cap_is_error():

@@ -182,9 +182,10 @@ def test_scheduler_growth_preempts_fewest_generated():
 # ---------------------------------------------------------------------------
 
 def _paged_from_contiguous(k_cache, v_cache, nb, bs, perm):
-    """Scatter a contiguous head-major [B,Hkv,S,Dh] cache into pools
-    [P,Hkv,ps,Dh] via a permuted page table. Returns pooled arrays + table
-    for batch-shared pools (pages of all rows share one pool)."""
+    """Scatter a contiguous head-major [B,Hkv,S,Dh] cache into one-layer
+    stacked pools [1,P,Hkv,ps,Dh] (read at layer 0) via a permuted page
+    table. Returns pooled arrays + table for batch-shared pools (pages of
+    all rows share one pool)."""
     b, hkv, s, dh = k_cache.shape
     npool = b * nb + 1                                  # + null page
     k_pages = np.zeros((npool, hkv, bs, dh), k_cache.dtype)
@@ -196,7 +197,8 @@ def _paged_from_contiguous(k_cache, v_cache, nb, bs, perm):
             table[bi, j] = phys
             k_pages[phys] = k_cache[bi, :, j * bs:(j + 1) * bs]
             v_pages[phys] = v_cache[bi, :, j * bs:(j + 1) * bs]
-    return (jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table))
+    return (jnp.asarray(k_pages)[None], jnp.asarray(v_pages)[None],
+            jnp.asarray(table))
 
 
 @pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
@@ -220,8 +222,8 @@ def test_paged_sparse_decode_matches_contiguous(impl):
     perm = rng.permutation(b * nb)                       # scrambled pages
     k_pages, v_pages, table = _paged_from_contiguous(
         np.asarray(kc_), np.asarray(vc_), nb, bs, perm)
-    o_pg = ops.paged_sparse_decode(q, k_pages, v_pages, idx, table, kv_len,
-                                   block_size=bs, impl=impl)
+    o_pg = ops.paged_sparse_decode(q, k_pages, v_pages, 0, idx, table,
+                                   kv_len, block_size=bs, impl=impl)
     tol = 1e-6 if impl == "ref" else 1e-5
     np.testing.assert_allclose(np.asarray(o_pg), np.asarray(o_ct),
                                atol=tol, rtol=tol)
@@ -250,16 +252,16 @@ def test_paged_splitk_matches_plain(impl):
     perm = rng.permutation(b * nb)
     k_pages, v_pages, table = _paged_from_contiguous(
         np.asarray(kc_), np.asarray(vc_), nb, bs, perm)
-    o_plain = ops.paged_sparse_decode(q, k_pages, v_pages, idx, table,
+    o_plain = ops.paged_sparse_decode(q, k_pages, v_pages, 0, idx, table,
                                       kv_len, block_size=bs, impl="ref")
     if impl == "ref":
         o1 = ops.paged_sparse_decode_splitk(
-            q, k_pages, v_pages, idx, table, kv_len, block_size=bs,
+            q, k_pages, v_pages, 0, idx, table, kv_len, block_size=bs,
             num_splits=1, impl="ref")
         np.testing.assert_array_equal(np.asarray(o1), np.asarray(o_plain))
     for ns in (2, 3, nsel):
         o_s = ops.paged_sparse_decode_splitk(
-            q, k_pages, v_pages, idx, table, kv_len, block_size=bs,
+            q, k_pages, v_pages, 0, idx, table, kv_len, block_size=bs,
             num_splits=ns, impl=impl)
         tol = 1e-6 if impl == "ref" else 1e-5
         np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_plain),
@@ -498,12 +500,13 @@ def _kg_fixture(seed, n_pages_seq=3):
 
 def _run_paged_appends(gcfg, gate, k_nope, ps, hkv, dh, dg, t_total):
     """Token-by-token append into paged storage (single slot, scrambled
-    physical pages); returns (kg_pages, page_table)."""
+    physical pages, layer 1 of a two-layer stack); returns (layer 1's
+    kg_pages, page_table)."""
     n_pages = t_total // ps
     npool = n_pages + 2
-    k_pages = jnp.zeros((npool, hkv, ps, dh), jnp.float32)
-    v_pages = jnp.zeros((npool, hkv, ps, dh), jnp.float32)
-    kg_pages = jnp.zeros((npool, hkv, dg), jnp.float32)
+    k_pages = jnp.zeros((2, npool, hkv, ps, dh), jnp.float32)
+    v_pages = jnp.zeros((2, npool, hkv, ps, dh), jnp.float32)
+    kg_pages = jnp.zeros((2, npool, hkv, dg), jnp.float32)
     # physical ids deliberately not in logical order
     table = np.zeros((1, n_pages), np.int32)
     table[0] = 1 + np.roll(np.arange(n_pages), 1)
@@ -514,10 +517,11 @@ def _run_paged_appends(gcfg, gate, k_nope, ps, hkv, dh, dg, t_total):
         pos = jnp.full((1, 1), t, jnp.int32)
         kr = apply_rope(k_nope[:, t:t + 1], pos, rope_theta)[:, 0]
         k_pages, v_pages, kg_pages = pg.append_token_paged(
-            k_pages, v_pages, kg_pages, kr, kr, table_j,
+            k_pages, v_pages, kg_pages, 1, kr, kr, table_j,
             jnp.full((1,), t, jnp.int32), active, gate, gcfg,
             rope_theta=rope_theta)
-    return kg_pages, table
+    assert not np.asarray(kg_pages[0]).any()     # layer 0 untouched
+    return kg_pages[1], table
 
 
 def test_paged_kg_matches_prefill_recompute():

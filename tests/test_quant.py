@@ -75,12 +75,13 @@ def test_quantize_block_scale_semantics():
 # ---------------------------------------------------------------------------
 
 def _quant_pool_fixture(seed=0, b=2, hkv=2, g=4, dh=32, nb=6, bs=8, nsel=4):
-    """fp pools + their per-page int8 twins + a forced-last selection."""
+    """One-layer stacked fp pools [1, P, Hkv, bs, Dh] (read at layer 0) +
+    their per-page int8 twins + a forced-last selection."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (b, hkv, g, dh), jnp.float32)
     npool = nb + 1
-    kp = jax.random.normal(ks[1], (npool, hkv, bs, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (npool, hkv, bs, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (1, npool, hkv, bs, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (1, npool, hkv, bs, dh), jnp.float32)
     kv_len = jnp.array([nb * bs, nb * bs - 5][:b])
     rng = np.random.default_rng(seed + 3)
     idx = np.full((b, hkv, nsel), -1, np.int32)
@@ -103,11 +104,11 @@ def _quant_pool_fixture(seed=0, b=2, hkv=2, g=4, dh=32, nb=6, bs=8, nsel=4):
 def test_paged_fused_dequant_matches_dequant_first(impl):
     (q, kq, vq, ksc, vsc, kdq, vdq, idx, table,
      kv_len) = _quant_pool_fixture()
-    bs = kq.shape[2]
-    o_fused = ops.paged_sparse_decode(q, kq, vq, idx, table, kv_len,
+    bs = kq.shape[3]
+    o_fused = ops.paged_sparse_decode(q, kq, vq, 0, idx, table, kv_len,
                                       block_size=bs, impl=impl,
                                       k_scales=ksc, v_scales=vsc)
-    o_first = ops.paged_sparse_decode(q, kdq, vdq, idx, table, kv_len,
+    o_first = ops.paged_sparse_decode(q, kdq, vdq, 0, idx, table, kv_len,
                                       block_size=bs, impl="ref")
     if impl == "ref":
         np.testing.assert_array_equal(np.asarray(o_fused),
@@ -161,12 +162,12 @@ def test_contiguous_fused_dequant_matches_dequant_first(impl):
 def test_splitk_fused_dequant_matches_plain(impl):
     (q, kq, vq, ksc, vsc, kdq, vdq, idx, table,
      kv_len) = _quant_pool_fixture(seed=5, nsel=5)
-    bs = kq.shape[2]
-    o_plain = ops.paged_sparse_decode(q, kdq, vdq, idx, table, kv_len,
+    bs = kq.shape[3]
+    o_plain = ops.paged_sparse_decode(q, kdq, vdq, 0, idx, table, kv_len,
                                       block_size=bs, impl="ref")
     for ns in (1, 2, 3):
         o_s = ops.paged_sparse_decode_splitk(
-            q, kq, vq, idx, table, kv_len, block_size=bs, num_splits=ns,
+            q, kq, vq, 0, idx, table, kv_len, block_size=bs, num_splits=ns,
             impl=impl, k_scales=ksc, v_scales=vsc)
         np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_plain),
                                    atol=1e-5, rtol=1e-5)
@@ -177,11 +178,11 @@ def test_fp_path_bitwise_unchanged_with_none_scales():
     guard that int8 support cannot perturb golden-pinned fp decode."""
     (q, kq, vq, ksc, vsc, kdq, vdq, idx, table,
      kv_len) = _quant_pool_fixture(seed=2)
-    bs = kq.shape[2]
+    bs = kq.shape[3]
     for impl in ("ref", "pallas_interpret"):
-        a = ops.paged_sparse_decode(q, kdq, vdq, idx, table, kv_len,
+        a = ops.paged_sparse_decode(q, kdq, vdq, 0, idx, table, kv_len,
                                     block_size=bs, impl=impl)
-        b = ops.paged_sparse_decode(q, kdq, vdq, idx, table, kv_len,
+        b = ops.paged_sparse_decode(q, kdq, vdq, 0, idx, table, kv_len,
                                     block_size=bs, impl=impl,
                                     k_scales=None, v_scales=None)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -263,16 +264,16 @@ def test_trailing_meta_int8_within_half_step():
     quantization error."""
     rng = np.random.default_rng(4)
     npool, hkv, ps, dh = 5, 2, 8, 16
-    kp = jnp.asarray(rng.normal(size=(npool, hkv, ps, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1, npool, hkv, ps, dh)), jnp.float32)
     kq, ksc = pg.quantize_block(kp, jnp.ones_like(kp, bool))
     table = jnp.asarray([[1, 3, 0], [2, 4, 0]], jnp.int32)
     cur_len = jnp.asarray([13, 5], jnp.int32)   # partial pages 3 and 2
-    fmin, fmax, fidx = mc.trailing_meta_paged(kp, table, cur_len, ps)
-    qmin, qmax, qidx = mc.trailing_meta_paged(kq, table, cur_len, ps,
+    fmin, fmax, fidx = mc.trailing_meta_paged(kp, 0, table, cur_len, ps)
+    qmin, qmax, qidx = mc.trailing_meta_paged(kq, 0, table, cur_len, ps,
                                               k_scale=ksc)
     np.testing.assert_array_equal(np.asarray(fidx), np.asarray(qidx))
     phys = np.asarray(table)[np.arange(2), np.asarray(fidx)]
-    half = np.asarray(ksc)[phys] / 2 + 1e-6                  # [S, Hkv, 1]
+    half = np.asarray(ksc)[0, phys] / 2 + 1e-6               # [S, Hkv, 1]
     assert np.all(np.abs(np.asarray(qmin) - np.asarray(fmin)) <= half)
     assert np.all(np.abs(np.asarray(qmax) - np.asarray(fmax)) <= half)
 

@@ -30,11 +30,12 @@ from repro.launch.mesh import make_mesh
 from repro.serve.sharded import sharded_paged_decode
 
 # qwen3_0_6b decode widths: 8 slots, 8 KV heads x GQA group 2, head_dim
-# 128, page == gate block 64, d_gate 128; a 2048-page pool, 512-entry
-# page tables (32k-token contexts) and the config's 64-block budget
+# 128, page == gate block 64, d_gate 128; a 2048-page pool stacked over 4
+# layers (read at a layer index), 512-entry page tables (32k-token
+# contexts) and the config's 64-block budget
 GATE = configs.get("qwen3_0_6b").gate
 B, HKV, G, DH, PS, DG = 8, 8, 2, 128, GATE.block_size, GATE.d_gate
-P, NPT, K = 2048, 512, GATE.token_budget // GATE.block_size
+L, P, NPT, K = 4, 2048, 512, GATE.token_budget // GATE.block_size
 
 
 @pytest.fixture(scope="module")
@@ -70,8 +71,9 @@ def _compile(fn, *args):
 
 def _paged_operands(one_chip, kv_dtype):
     return (_sds(one_chip, (B, HKV, G, DH), jnp.bfloat16),
-            _sds(one_chip, (P, HKV, PS, DH), kv_dtype),
-            _sds(one_chip, (P, HKV, PS, DH), kv_dtype),
+            _sds(one_chip, (L, P, HKV, PS, DH), kv_dtype),
+            _sds(one_chip, (L, P, HKV, PS, DH), kv_dtype),
+            _sds(one_chip, (), jnp.int32),
             _sds(one_chip, (B, HKV, K), jnp.int32),
             _sds(one_chip, (B, NPT), jnp.int32),
             _sds(one_chip, (B,), jnp.int32))
@@ -79,21 +81,23 @@ def _paged_operands(one_chip, kv_dtype):
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_block_sparse_decode_paged_compiles(one_chip, quant):
-    def fn(q, kp, vp, idx, pt, kv_len, *scales):
+    def fn(q, kp, vp, layer, idx, pt, kv_len, *scales):
         ks, vs = scales or (None, None)
-        return block_sparse_decode_paged(q, kp, vp, idx, pt, kv_len,
+        return block_sparse_decode_paged(q, kp, vp, layer, idx, pt, kv_len,
                                          block_size=PS, k_scales=ks,
                                          v_scales=vs)
-    scales = (_sds(one_chip, (P, HKV, 1), jnp.float32),) * 2 if quant else ()
+    scales = ((_sds(one_chip, (L, P, HKV, 1), jnp.float32),) * 2 if quant
+              else ())
     _compile(fn, *_paged_operands(one_chip,
                                   jnp.int8 if quant else jnp.bfloat16),
              *scales)
 
 
 def test_block_sparse_decode_paged_splitk_compiles(one_chip):
-    def fn(q, kp, vp, idx, pt, kv_len):
-        return block_sparse_decode_paged_splitk(q, kp, vp, idx, pt, kv_len,
-                                                block_size=PS, num_splits=2)
+    def fn(q, kp, vp, layer, idx, pt, kv_len):
+        return block_sparse_decode_paged_splitk(q, kp, vp, layer, idx, pt,
+                                                kv_len, block_size=PS,
+                                                num_splits=2)
     _compile(fn, *_paged_operands(one_chip, jnp.bfloat16))
 
 
@@ -136,7 +140,8 @@ def test_fused_gate_select_paged_compiles(one_chip, method, dtype):
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_sharded_paged_decode_compiles(four_chips, quant):
     """One layer's paged x sharded step (append, gate-select, block-sparse
-    attention) with the Pallas kernels inside its ``shard_map``, whose
+    attention) on the stacked pools at a layer index, with the Pallas
+    kernels inside its ``shard_map``, whose
     replication check stays on: each kernel output declares the mesh axes
     it varies over."""
     def sds(shape, dtype):
@@ -145,15 +150,15 @@ def test_sharded_paged_decode_compiles(four_chips, quant):
     kv = jnp.int8 if quant else jnp.bfloat16
     args = (sds((B, HKV, DG), jnp.bfloat16), sds((B, HKV, G, DH), jnp.bfloat16),
             sds((B, HKV, DH), jnp.bfloat16), sds((B, HKV, DH), jnp.bfloat16),
-            sds((P, HKV, PS, DH), kv), sds((P, HKV, PS, DH), kv),
-            sds((P, HKV, DG), jnp.bfloat16), sds((B, NPT), jnp.int32),
-            sds((B,), jnp.int32), sds((B,), jnp.bool_),
-            sds((HKV, 3 * DH, DG), jnp.bfloat16))
-    scales = (sds((P, HKV, 1), jnp.float32),) * 2 if quant else ()
+            sds((L, P, HKV, PS, DH), kv), sds((L, P, HKV, PS, DH), kv),
+            sds((L, P, HKV, DG), jnp.bfloat16), sds((), jnp.int32),
+            sds((B, NPT), jnp.int32), sds((B,), jnp.int32),
+            sds((B,), jnp.bool_), sds((HKV, 3 * DH, DG), jnp.bfloat16))
+    scales = (sds((L, P, HKV, 1), jnp.float32),) * 2 if quant else ()
 
     def fn(*a):
-        ks, vs = a[11:] or (None, None)
-        return sharded_paged_decode(*a[:11], mesh=four_chips, cfg=GATE,
+        ks, vs = a[12:] or (None, None)
+        return sharded_paged_decode(*a[:12], mesh=four_chips, cfg=GATE,
                                     rope_theta=1e6, inner_impl="pallas",
                                     k_scale=ks, v_scale=vs)
     _compile(fn, *args, *scales)
