@@ -8,7 +8,34 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Any, Mapping, Tuple
+from typing import Any, Mapping, Optional, Tuple
+
+
+@dataclass(frozen=True)
+class Rope:
+    """One rotary position embedding: base ``theta`` and the linear scaling
+    ``factor`` (every rotation angle divided by it, i.e. position / factor).
+    A factor of 1 adds no op. The forward RoPE and its inverse (the Kg
+    un-rope, negated positions) take the same ``Rope``, so they cannot
+    drift apart."""
+    theta: float = 10000.0
+    factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """Published RoPE scaling (Hugging Face ``rope_scaling``). Only
+    ``"linear"`` is run: positions are divided by ``factor``."""
+    type: str
+    factor: float
+
+    def __post_init__(self):
+        if self.type != "linear":
+            raise ValueError(f"rope_scaling type {self.type!r}: only "
+                             f"'linear' is supported")
+        if not self.factor > 0:
+            raise ValueError(f"rope_scaling factor {self.factor!r} must be "
+                             f"positive")
 
 
 @dataclass(frozen=True)
@@ -34,6 +61,11 @@ class GateConfig:
     # ceil(k/nshards * local_cap_factor) selected blocks (static shape);
     # score-ordered overflow is dropped. 2.0 covers 2x hot-shard imbalance.
     local_cap_factor: float = 2.0
+
+    @property
+    def rope(self) -> Rope:
+        """The gate's own RoPE: its base, never scaled."""
+        return Rope(self.rope_theta)
 
 
 @dataclass(frozen=True)
@@ -74,6 +106,10 @@ class ModelConfig:
     qk_norm: bool = False
     causal: bool = True           # False for encoder-only (hubert)
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
+    # published context length (0: not stated); DecodeEngine refuses a
+    # longer max_len
+    max_position_embeddings: int = 0
     attn_logit_softcap: float = 0.0
     # activation: "swiglu" | "geglu" | "gelu"
     activation: str = "swiglu"
@@ -106,6 +142,12 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def rope(self) -> Rope:
+        """The model's RoPE, with the published scaling."""
+        return Rope(self.rope_theta, self.rope_scaling.factor
+                    if self.rope_scaling is not None else 1.0)
 
     @property
     def gqa_group(self) -> int:

@@ -53,7 +53,7 @@ def gate_q(params: Params, q_nope: jnp.ndarray, positions: jnp.ndarray,
     qr = q_nope.reshape(b, l, hkv, g * dh)
     qg = jnp.einsum("blhe,hed->blhd", qr, params["wq"])
     if cfg.use_rope:
-        qg = apply_rope(qg, positions, cfg.rope_theta)
+        qg = apply_rope(qg, positions, cfg.rope)
     return qg
 
 
@@ -81,7 +81,7 @@ def gate_k(params: Params, k_nope: jnp.ndarray, cfg: GateConfig,
     if cfg.use_rope:
         nb = kg.shape[1]
         pos = (first_block_index + jnp.arange(nb)) * cfg.block_size
-        kg = apply_rope(kg, pos, cfg.rope_theta)
+        kg = apply_rope(kg, pos, cfg.rope)
     return kg
 
 

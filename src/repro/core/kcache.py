@@ -10,12 +10,12 @@ d_gate * 2) — <1% at b=64, d_gate=128 (paper's number).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.config import GateConfig
+from repro.config import GateConfig, Rope
 from repro.core.attngate import gate_k
 
 
@@ -49,7 +49,7 @@ def prefill_kcache(cache: KCompressionCache, gate_params: Dict[str, Any],
 
 def finalize_block_kg(gate_params: Dict[str, Any], blk: jnp.ndarray,
                       start_pos, block_index, cfg: GateConfig, *,
-                      is_roped: bool, rope_theta: float = 10000.0
+                      is_roped: bool, rope: Optional[Rope]
                       ) -> jnp.ndarray:
     """One COMPLETE block of keys [block_size, Hkv, Dh] -> Kg row [Hkv, Dg].
 
@@ -58,12 +58,13 @@ def finalize_block_kg(gate_params: Dict[str, Any], blk: jnp.ndarray,
     (serve.paging.append_token_paged) so the two can never drift. When
     ``is_roped`` the stored keys are rotated back to the pre-rope frame
     first (RoPE is an orthogonal rotation: inversion = apply with negated
-    positions), avoiding a second pre-rope K cache just for the gate.
+    positions), avoiding a second pre-rope K cache just for the gate;
+    ``rope`` is the model's RoPE that rotated them, scaling included.
     """
     from repro.models.common import apply_rope
     if is_roped:
         pos = -(start_pos + jnp.arange(blk.shape[0]))
-        blk = apply_rope(blk[None], pos[None], rope_theta)[0]
+        blk = apply_rope(blk[None], pos[None], rope)[0]
     return gate_k(gate_params, blk[None], cfg,
                   first_block_index=block_index)[0, 0]
 
@@ -71,12 +72,13 @@ def finalize_block_kg(gate_params: Dict[str, Any], blk: jnp.ndarray,
 def update_kcache(cache: KCompressionCache, gate_params: Dict[str, Any],
                   k_cache_raw: jnp.ndarray, cur_len: jnp.ndarray,
                   cfg: GateConfig, *, cache_is_roped: bool = False,
-                  rope_theta: float = 10000.0) -> KCompressionCache:
+                  rope: Optional[Rope] = None) -> KCompressionCache:
     """Decode-time incremental update.
 
     k_cache_raw: [B, Hkv, S_max, Dh] HEAD-MAJOR key cache. If
     ``cache_is_roped`` the stored keys are post-RoPE (the standard layout)
-    and are rotated *back* to the pre-rope frame before pooling (RoPE is an
+    and are rotated *back* to the pre-rope frame with ``rope`` (the model's
+    RoPE, scaling included) before pooling (RoPE is an
     orthogonal rotation, so inversion = apply with negated positions) —
     this avoids keeping a second pre-rope K cache (2x memory) just for the
     gate. Only ONE block-size slice of the cache is ever touched per step.
@@ -101,7 +103,7 @@ def update_kcache(cache: KCompressionCache, gate_params: Dict[str, Any],
         blk = jax.lax.dynamic_slice_in_dim(k_raw, st, bs, axis=1)
         return finalize_block_kg(gate_params, jnp.swapaxes(blk, 0, 1), st,
                                  bi, cfg, is_roped=cache_is_roped,
-                                 rope_theta=rope_theta)    # [Hkv, Dg]
+                                 rope=rope)    # [Hkv, Dg]
 
     kg_new = jax.vmap(one_row)(k_cache_raw, start, blk_idx)   # [B,Hkv,Dg]
     cur = jax.vmap(lambda c, i: c[:, i])(cache.kg, blk_idx)   # current content

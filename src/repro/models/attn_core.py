@@ -191,8 +191,8 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
     q, k, v = _qkv(p, x1, cfg)
     q_nope = q
     pos = cur_len[:, None]                                 # [S,1]
-    qr = apply_rope(q, pos, cfg.rope_theta)
-    kr = apply_rope(k, pos, cfg.rope_theta)
+    qr = apply_rope(q, pos, cfg.rope)
+    kr = apply_rope(k, pos, cfg.rope)
 
     mesh = getattr(shard, "mesh", None)
     if sparse_on and options.kernel_impl == "sharded" and mesh is None:
@@ -228,7 +228,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
             sharded_paged_decode(
                 qg, qgrp, kr[:, 0], v[:, 0], k_pages, v_pages, kg_pages,
                 layer, page_table, cur_len, active, p["gate"]["wk"],
-                mesh=mesh, cfg=cfg.gate, rope_theta=cfg.rope_theta,
+                mesh=mesh, cfg=cfg.gate, rope=cfg.rope,
                 max_selected=options.max_selected(cfg),
                 budget_blocks=budget_blocks, split_k=options.split_k,
                 inner_impl=platform_kernel_impl(),
@@ -257,12 +257,12 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 pg.append_token_paged_quant(
                     k_pages, v_pages, kg_pages, k_scale, v_scale, layer,
                     kr[:, 0], v[:, 0], page_table, cur_len, active,
-                    gate_for_append, cfg.gate, rope_theta=cfg.rope_theta)
+                    gate_for_append, cfg.gate, rope=cfg.rope)
         else:
             k_pages, v_pages, kg_pages = pg.append_token_paged(
                 k_pages, v_pages, kg_pages, layer, kr[:, 0], v[:, 0],
                 page_table, cur_len, active, gate_for_append, cfg.gate,
-                rope_theta=cfg.rope_theta)
+                rope=cfg.rope)
         # ... and the min/max metadata page rows only for the policy that
         # reads THEM (QuestPolicy): finalize a page's row when it fills
         if policy.needs_meta and kmin_pages is not None and not staged:
@@ -282,7 +282,7 @@ def attention_decode_paged(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                     do_select,
                     lambda kgp: pg.finalize_kg_paged(
                         k_pages, kgp, layer, page_table, cur_len, active,
-                        p["gate"], cfg.gate, rope_theta=cfg.rope_theta,
+                        p["gate"], cfg.gate, rope=cfg.rope,
                         k_scale=k_scale),
                     lambda kgp: kgp, kg_pages)
             if policy.needs_meta and kmin_pages is not None:

@@ -12,6 +12,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.config import Rope
+
 Params = Dict[str, Any]
 
 
@@ -70,15 +72,22 @@ def layer_scan(body, carry, xs, *, unroll: bool = False):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, rope: Rope) -> jnp.ndarray:
+    """Rotation rate per dimension pair; linear scaling divides it by the
+    factor (the same angles as position / factor)."""
+    freqs = 1.0 / (rope.theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                  / head_dim))
+    if rope.factor != 1.0:
+        freqs = freqs / rope.factor
+    return freqs
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, rope: Rope
                ) -> jnp.ndarray:
-    """x: [..., seq, heads, head_dim]; positions: broadcastable to [..., seq]."""
+    """x: [..., seq, heads, head_dim]; positions: broadcastable to [..., seq].
+    Negated positions undo the rotation of the same ``rope``."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)                       # [hd/2]
+    freqs = rope_freqs(head_dim, rope)                        # [hd/2]
     ang = positions[..., None].astype(jnp.float32) * freqs    # [..., seq, hd/2]
     cos = jnp.cos(ang)[..., None, :]                          # [..., seq, 1, hd/2]
     sin = jnp.sin(ang)[..., None, :]

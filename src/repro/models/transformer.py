@@ -143,8 +143,8 @@ def attention_full(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     b, l, _ = x.shape
     q, k, v = _qkv(p, x, cfg)
     q_nope, k_nope = q, k
-    qr = apply_rope(q, rope_positions, cfg.rope_theta)
-    kr = apply_rope(k, rope_positions, cfg.rope_theta)
+    qr = apply_rope(q, rope_positions, cfg.rope)
+    kr = apply_rope(k, rope_positions, cfg.rope)
 
     gate_on = distill and "gate" in p
     gt_bs = cfg.gate.block_size if gate_on else 0
@@ -412,8 +412,8 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
     q, k, v = _qkv(p, x1, cfg)
     q_nope = q
     pos = cur_len[:, None]                                 # [B,1]
-    qr = apply_rope(q, pos, cfg.rope_theta)
-    kr = apply_rope(k, pos, cfg.rope_theta)
+    qr = apply_rope(q, pos, cfg.rope)
+    kr = apply_rope(k, pos, cfg.rope)
 
     mesh = getattr(shard, "mesh", None)
     if sparse_on and options.kernel_impl == "sharded" and policy.needs_gate \
@@ -426,7 +426,7 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
         o, k_cache, v_cache, kg_cache, n_sel = sharded_sparse_decode(
             qg, qgrp, kr[:, 0], v[:, 0], k_cache, v_cache, kg_cache,
             cur_len, p["gate"]["wk"], mesh=mesh, seq_axes=seq_axes,
-            batch_spec=bspec, cfg=cfg.gate, rope_theta=cfg.rope_theta,
+            batch_spec=bspec, cfg=cfg.gate, rope=cfg.rope,
             max_selected=options.max_selected(cfg))
         new_len = cur_len + 1
         completed = (new_len % bs) == 0
@@ -472,7 +472,7 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
                 cache = kc.update_kcache(
                     kc.KCompressionCache(kg, n), p["gate"], k_cache,
                     new_len, cfg.gate, cache_is_roped=True,
-                    rope_theta=cfg.rope_theta)
+                    rope=cfg.rope)
                 return cache.kg, cache.n_complete
             kg_cache, kg_n = jax.lax.cond(
                 do_select, _adv_kg, lambda kg, n: (kg, n), kg_cache, kg_n)
@@ -531,7 +531,7 @@ def attention_decode(p: Params, x1: jnp.ndarray, cfg: ModelConfig, *,
             cache = kc.update_kcache(
                 kc.KCompressionCache(kg_cache, kg_n), p["gate"], k_cache,
                 new_len, cfg.gate, cache_is_roped=True,
-                rope_theta=cfg.rope_theta)
+                rope=cfg.rope)
             kg_cache, kg_n = cache.kg, cache.n_complete
         # same advance-only-for-the-reader rule for the selection-metadata
         # cache (QuestPolicy): O(block_size) finalize on block boundaries

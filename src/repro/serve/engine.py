@@ -97,6 +97,11 @@ class DecodeEngine:
                 f"covers the dense/moe/ssm/hybrid families; for a family "
                 f"without it, run the contiguous api.prefill/decode_step "
                 f"loop directly instead of DecodeEngine")
+        if cfg.max_position_embeddings and \
+                max_len > cfg.max_position_embeddings:
+            raise ValueError(
+                f"max_len {max_len} > {cfg.arch_id}'s published "
+                f"max_position_embeddings {cfg.max_position_embeddings}")
         self.max_len = max_len
         self.options = options if options is not None else default_options(cfg)
         self.options.check_platform()

@@ -51,7 +51,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config import GateConfig, ModelConfig
+from repro.config import GateConfig, ModelConfig, Rope
 from repro.core.kcache import finalize_block_kg
 
 NULL_PAGE = 0
@@ -243,7 +243,7 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                        kr_new: jnp.ndarray, v_new: jnp.ndarray,
                        page_table: jnp.ndarray, cur_len: jnp.ndarray,
                        active: jnp.ndarray, gate_params: Optional[Dict],
-                       cfg: GateConfig, *, rope_theta: float = 10000.0
+                       cfg: GateConfig, *, rope: Rope
                        ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                   Optional[jnp.ndarray]]:
     """ONE layer's paged twin of the contiguous write + ``update_kcache``.
@@ -256,8 +256,9 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     page_table[slot, cur_len // ps], :, cur_len % ps); rows with
     ``active == False`` are routed to the null page. When a slot's page
     completes ((cur_len+1) % ps == 0) the page's keys are rotated back to
-    the pre-rope frame (same trick as kcache.update_kcache) and
-    pooled+projected into that page's ``kg_pages`` row.
+    the pre-rope frame with ``rope``, the model's RoPE (same trick as
+    kcache.update_kcache), and pooled+projected into that page's
+    ``kg_pages`` row.
     """
     ps = cfg.block_size
     n_slots = cur_len.shape[0]
@@ -279,7 +280,7 @@ def append_token_paged(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 
     kg_pages = finalize_kg_paged(k_pages, kg_pages, layer, page_table,
                                  cur_len, active, gate_params, cfg,
-                                 rope_theta=rope_theta)
+                                 rope=rope)
     return k_pages, v_pages, kg_pages
 
 
@@ -291,7 +292,7 @@ def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                              page_table: jnp.ndarray, cur_len: jnp.ndarray,
                              active: jnp.ndarray,
                              gate_params: Optional[Dict],
-                             cfg: GateConfig, *, rope_theta: float = 10000.0
+                             cfg: GateConfig, *, rope: Rope
                              ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                         Optional[jnp.ndarray],
                                         jnp.ndarray, jnp.ndarray]:
@@ -337,7 +338,7 @@ def append_token_paged_quant(k_pages: jnp.ndarray, v_pages: jnp.ndarray,
 
     kg_pages = finalize_kg_paged(k_pages, kg_pages, layer, page_table,
                                  cur_len, active, gate_params, cfg,
-                                 rope_theta=rope_theta, k_scale=k_scale)
+                                 rope=rope, k_scale=k_scale)
     return k_pages, v_pages, kg_pages, k_scale, v_scale
 
 
@@ -345,7 +346,7 @@ def finalize_kg_paged(k_pages: jnp.ndarray, kg_pages: jnp.ndarray,
                       layer: jnp.ndarray,
                       page_table: jnp.ndarray, cur_len: jnp.ndarray,
                       active: jnp.ndarray, gate_params: Dict,
-                      cfg: GateConfig, *, rope_theta: float = 10000.0,
+                      cfg: GateConfig, *, rope: Rope,
                       k_scale: Optional[jnp.ndarray] = None
                       ) -> jnp.ndarray:
     """Finalize the Kg row of each slot's just-completed page, at
@@ -353,8 +354,9 @@ def finalize_kg_paged(k_pages: jnp.ndarray, kg_pages: jnp.ndarray,
 
     Called AFTER the new token's key is written: when a slot's page
     completes ((cur_len+1) % ps == 0) the page's keys are rotated back to
-    the pre-rope frame (same trick as kcache.update_kcache) and
-    pooled+projected into that page's ``kg_pages`` row. Inactive /
+    the pre-rope frame with ``rope``, the model's RoPE (same trick as
+    kcache.update_kcache), and pooled+projected into that page's
+    ``kg_pages`` row. Inactive /
     incomplete slots route the write to the null page. Split out from
     ``append_token_paged`` so a SelectionSchedule can gate the Kg advance
     (selecting layers only) independently of the K/V append, which always
@@ -373,7 +375,7 @@ def finalize_kg_paged(k_pages: jnp.ndarray, kg_pages: jnp.ndarray,
         # flip the tiny page corner to the seq-major frame finalize expects
         return finalize_block_kg(gate_params, jnp.swapaxes(page_k, 0, 1),
                                  lg * ps, lg, cfg,
-                                 is_roped=True, rope_theta=rope_theta)
+                                 is_roped=True, rope=rope)
 
     blk = k_pages[layer, phys]                             # [S, Hkv, ps, Dh]
     if k_scale is not None:

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.config import GateConfig
+from repro.config import GateConfig, Rope
 from repro.core import sparsity as sp
 from repro.core.policy import select_impl
 from repro.distributed.sharding import MODEL
@@ -63,7 +63,7 @@ def sharded_sparse_decode(
         seq_axes: Tuple[str, ...],
         batch_spec,
         cfg: GateConfig,
-        rope_theta: float,
+        rope: Rope,               # the model's RoPE, for the Kg un-rope
         max_selected: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step for ONE layer. ``max_selected`` overrides the
@@ -123,14 +123,14 @@ def sharded_sparse_decode(
             blk = jax.lax.dynamic_slice_in_dim(k_row, st, bs, axis=1)
             blk = jnp.swapaxes(blk, 0, 1)                  # [bs, Hkv, Dh]
             pos = -(tok0 + st + jnp.arange(bs))            # un-rope
-            blk = apply_rope(blk[None], pos[None], rope_theta)[0]
+            blk = apply_rope(blk[None], pos[None], rope)[0]
             pooled = jnp.concatenate(
                 [jnp.max(blk, 0), jnp.min(blk, 0),
                  jnp.mean(blk.astype(jnp.float32), 0).astype(blk.dtype)], -1)
             kg = jnp.einsum("he,hed->hd", pooled, wk)      # [Hkv, Dg]
             if cfg.use_rope:
                 kg = apply_rope(kg[None, None], (gb * bs)[None, None],
-                                cfg.rope_theta)[0, 0]
+                                cfg.rope)[0, 0]
             return kg
 
         kg_new = jax.vmap(kg_row)(k_loc, lstart, gblk)     # [B,Hkv,Dg]
@@ -247,7 +247,7 @@ def sharded_paged_decode(
         *,
         mesh: Mesh,
         cfg: GateConfig,
-        rope_theta: float,
+        rope: Rope,               # the model's RoPE, for the Kg un-rope
         max_selected: Optional[int] = None,
         budget_blocks: Optional[jnp.ndarray] = None,
         split_k: int = 1,
@@ -366,12 +366,12 @@ def sharded_paged_decode(
             extra = extra[2:]
             kp, vp, kgp, ksc, vsc = pg.append_token_paged_quant(
                 kp, vp, kgp, ksc, vsc, ly, kr_new, v_new, pt, cl, act,
-                {"wk": wk}, cfg, rope_theta=rope_theta)
+                {"wk": wk}, cfg, rope=rope)
         else:
             ksc = vsc = None
             kp, vp, kgp = pg.append_token_paged(
                 kp, vp, kgp, ly, kr_new, v_new, pt, cl, act, {"wk": wk},
-                cfg, rope_theta=rope_theta)
+                cfg, rope=rope)
         new_len = cl + act.astype(jnp.int32)
         n_valid = kc.visible_blocks(jnp.maximum(new_len, 1), cfg.block_size)
         # this layer's Kg rows as a slice, as GatePolicy reads them

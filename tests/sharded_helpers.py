@@ -484,5 +484,77 @@ def moe_sharded_grads():
     print("moe_sharded_grads OK")
 
 
+def paged_sharded_rope_scaled():
+    """Linear RoPE scaling on the paged x sharded path: the shard body's
+    Kg finalize equals gate_k of the pre-RoPE keys under a x4 factor, and
+    serving a 7:1-group scaled config on the mesh gives the unsharded
+    engine's tokens and logits bitwise."""
+    import functools
+    import jax, jax.numpy as jnp
+    import numpy as np
+    import repro.configs as configs
+    from repro.config import reduced
+    from repro.core import attngate as ag
+    from repro.core.policy import DecodeOptions
+    from repro.distributed import sharding as shd
+    from repro.models.common import apply_rope
+    from repro.models.registry import get_api
+    from repro.serve.engine import DecodeEngine
+    from repro.serve.sharded import sharded_paged_decode
+
+    mesh = make_mesh((4, 2), ("data", "model"))   # Hkv=2 over model=2
+    cfg = reduced(configs.get("deepseek_coder_33b"), n_heads=14,
+                  n_kv_heads=2).replace(dtype="float32")
+    assert cfg.rope.factor == 4.0 and cfg.gqa_group == 7
+    gcfg, rope = cfg.gate, cfg.rope
+    ps, hkv, g = gcfg.block_size, cfg.n_kv_heads, cfg.gqa_group
+    dh, dg, nb = cfg.resolved_head_dim, gcfg.d_gate, 5
+
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    gate = ag.init_attngate(k1, n_kv_heads=hkv, group=g, head_dim=dh,
+                            cfg=gcfg, dtype="float32")
+    k_nope = jax.random.normal(k2, (1, nb * ps, hkv, dh), jnp.float32)
+    want = np.asarray(ag.gate_k(gate, k_nope, gcfg))[0]
+    k_pages = jnp.zeros((2, nb + 2, hkv, ps, dh), jnp.float32)
+    v_pages = jnp.zeros_like(k_pages)
+    kg_pages = jnp.zeros((2, nb + 2, hkv, dg), jnp.float32)
+    table = jnp.asarray(1 + np.roll(np.arange(nb), 2)[None], jnp.int32)
+    step = jax.jit(functools.partial(sharded_paged_decode, mesh=mesh,
+                                     cfg=gcfg, rope=rope))
+    with mesh:
+        for t in range(nb * ps):
+            kr = apply_rope(k_nope[:, t:t + 1], jnp.full((1, 1), t),
+                            rope)[:, 0]
+            _, k_pages, v_pages, kg_pages, _, _, _ = step(
+                jnp.zeros((1, hkv, dg)), jnp.zeros((1, hkv, g, dh)), kr, kr,
+                k_pages, v_pages, kg_pages, jnp.int32(1), table,
+                jnp.full((1,), t, jnp.int32), jnp.ones((1,), bool),
+                gate["wk"])
+    got = np.asarray(kg_pages[1])[np.asarray(table[0])]
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    api = get_api(cfg)
+    params = api.init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(11)
+    reqs = [{"rid": i, "max_new_tokens": mn,
+             "tokens": rng.integers(0, cfg.vocab_size,
+                                    size=(pl,)).astype(np.int32)}
+            for i, (pl, mn) in enumerate([(41, 24), (57, 16), (30, 20)])]
+    res_ref = DecodeEngine(cfg, params, max_len=96).serve(
+        [dict(r) for r in reqs], n_slots=2, collect_logits=True)
+    with mesh:
+        eng_sh = DecodeEngine(cfg, params, max_len=96,
+                              shard=shd.make_shard_fn(mesh),
+                              options=DecodeOptions(kernel_impl="sharded"))
+        res_sh = eng_sh.serve([dict(r) for r in reqs], n_slots=2,
+                              collect_logits=True)
+    for r in reqs:
+        rid = r["rid"]
+        assert res_sh[rid] == res_ref[rid], f"rid {rid} token mismatch"
+        np.testing.assert_array_equal(res_sh["logits"][rid],
+                                      res_ref["logits"][rid])
+    print("paged_sharded_rope_scaled OK")
+
+
 if __name__ == "__main__":
     globals()[sys.argv[1]]()

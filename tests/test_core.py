@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import GateConfig
+from repro.config import GateConfig, Rope
 from repro.core import attngate as ag
 from repro.core import kcache as kc
 from repro.core import oracle, quest
@@ -60,7 +60,7 @@ def test_gate_rope_uses_block_start_positions():
     kg_rope = ag.gate_k(p, k, GCFG)
     cfg_no = GateConfig(block_size=8, d_gate=16, use_rope=False)
     kg_plain = ag.gate_k(p, k, cfg_no)
-    manual = apply_rope(kg_plain, jnp.arange(3) * 8, GCFG.rope_theta)
+    manual = apply_rope(kg_plain, jnp.arange(3) * 8, GCFG.rope)
     np.testing.assert_allclose(np.asarray(kg_rope), np.asarray(manual),
                                atol=1e-5)
 
@@ -139,12 +139,12 @@ def test_kcache_derope_matches_pre_rope():
     bs = GCFG.block_size
     k_nope = jax.random.normal(key, (1, 2 * bs, 1, 16))
     pos = jnp.arange(2 * bs)[None]
-    k_rope = apply_rope(k_nope, pos, 10000.0)
+    k_rope = apply_rope(k_nope, pos, Rope(10000.0))
     cache = kc.init_kcache(1, 2, 1, GCFG.d_gate, jnp.float32)
     cur = jnp.array([2 * bs])
     c_a = kc.update_kcache(cache, p, jnp.swapaxes(k_nope, 1, 2), cur, GCFG)
     c_b = kc.update_kcache(cache, p, jnp.swapaxes(k_rope, 1, 2), cur, GCFG,
-                           cache_is_roped=True, rope_theta=10000.0)
+                           cache_is_roped=True, rope=Rope(10000.0))
     np.testing.assert_allclose(np.asarray(c_a.kg[:, :, 1]),
                                np.asarray(c_b.kg[:, :, 1]), atol=1e-4)
 

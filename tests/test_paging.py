@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 import repro.configs as configs
-from repro.config import GateConfig, reduced
+from repro.config import GateConfig, Rope, reduced
 from repro.core import attngate as ag
 from repro.core.policy import DecodeOptions, DensePolicy
 from repro.core import kcache as kc
@@ -512,14 +512,14 @@ def _run_paged_appends(gcfg, gate, k_nope, ps, hkv, dh, dg, t_total):
     table[0] = 1 + np.roll(np.arange(n_pages), 1)
     table_j = jnp.asarray(table)
     active = jnp.ones((1,), bool)
-    rope_theta = 10000.0
+    rope = Rope(10000.0)
     for t in range(t_total):
         pos = jnp.full((1, 1), t, jnp.int32)
-        kr = apply_rope(k_nope[:, t:t + 1], pos, rope_theta)[:, 0]
+        kr = apply_rope(k_nope[:, t:t + 1], pos, rope)[:, 0]
         k_pages, v_pages, kg_pages = pg.append_token_paged(
             k_pages, v_pages, kg_pages, 1, kr, kr, table_j,
             jnp.full((1,), t, jnp.int32), active, gate, gcfg,
-            rope_theta=rope_theta)
+            rope=rope)
     assert not np.asarray(kg_pages[0]).any()     # layer 0 untouched
     return kg_pages[1], table
 

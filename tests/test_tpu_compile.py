@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as PS_
 from jax.sharding import SingleDeviceSharding
 
 import repro.configs as configs
+from repro.config import Rope
 from repro.kernels.block_sparse_decode import (
     block_sparse_decode, block_sparse_decode_paged,
     block_sparse_decode_paged_splitk)
@@ -93,6 +94,23 @@ def test_block_sparse_decode_paged_compiles(one_chip, quant):
              *scales)
 
 
+def test_block_sparse_decode_paged_compiles_at_a_7_to_1_group(one_chip):
+    """deepseek_coder_33b_pp16.long8_16k's call: 56/8 heads (a 7:1 group,
+    padded to the sublanes in the kernel), 4 layers of a 2049-page pool,
+    256-entry tables (16384 positions) and a 64-block budget."""
+    g, p, npt, k = 7, 2049, 256, 64
+
+    def fn(q, kp, vp, layer, idx, pt, kv_len):
+        return block_sparse_decode_paged(q, kp, vp, layer, idx, pt, kv_len,
+                                         block_size=PS)
+    pool = _sds(one_chip, (L, p, HKV, PS, DH), jnp.bfloat16)
+    _compile(fn, _sds(one_chip, (B, HKV, g, DH), jnp.bfloat16), pool, pool,
+             _sds(one_chip, (), jnp.int32), _sds(one_chip, (B, HKV, k),
+                                                 jnp.int32),
+             _sds(one_chip, (B, npt), jnp.int32),
+             _sds(one_chip, (B,), jnp.int32))
+
+
 def test_block_sparse_decode_paged_splitk_compiles(one_chip):
     def fn(q, kp, vp, layer, idx, pt, kv_len):
         return block_sparse_decode_paged_splitk(q, kp, vp, layer, idx, pt,
@@ -159,6 +177,6 @@ def test_sharded_paged_decode_compiles(four_chips, quant):
     def fn(*a):
         ks, vs = a[12:] or (None, None)
         return sharded_paged_decode(*a[:12], mesh=four_chips, cfg=GATE,
-                                    rope_theta=1e6, inner_impl="pallas",
+                                    rope=Rope(1e6), inner_impl="pallas",
                                     k_scale=ks, v_scale=vs)
     _compile(fn, *args, *scales)
