@@ -66,6 +66,17 @@ def _pull(a, what: str):
         return np.array(a)
 
 
+# serve()'s decode step takes one packed int32 input per step, one row per
+# slot: these leading columns (the budget column only when some request
+# sets a budget), then the slot's page-table row
+_IN_TOKEN, _IN_CUR_LEN, _IN_ACTIVE, _IN_BUDGET = range(4)
+# and returns one packed int32 [n_slots, 2 or 4] array that the host pulls
+# once: the greedy token, whether the logits row is finite and, under
+# measure_sparsity, the float32 bits of the row's sparsity and selected
+# block count
+_OUT_NEXT, _OUT_FINITE, _OUT_RHO, _OUT_SEL = range(4)
+
+
 def _bucket_pages(prompt_len: int, ps: int) -> int:
     """Prefill bucket of a prompt: its page count rounded up to a power of
     two."""
@@ -110,9 +121,8 @@ class DecodeEngine:
         self._step = jax.jit(functools.partial(
             self._decode_step, options=self.options), donate_argnums=(1,))
         # paged decode steps, built lazily on first serve(): one program
-        # per track_evictions flavor (plain, and the eviction-telemetry
-        # variant serve(eviction=...) compiles)
-        self._paged_steps: Dict[bool, Any] = {}
+        # per (track_evictions, per-slot budget column) flavor
+        self._paged_steps: Dict[tuple, Any] = {}
         # serve()-path prefill, jitted per POWER-OF-TWO page bucket (ISSUE
         # 5: prompts are right-padded to the bucket, so the cache holds
         # O(log max_len) programs instead of one per distinct length)
@@ -421,17 +431,31 @@ class DecodeEngine:
                 lambda s: NamedSharding(mesh, s),
                 paged_pool_pspecs(pages, mesh)))
         track = eviction is not None
-        step = self._paged_steps.get(track)
+        # leading columns of the packed step input, before the page table
+        lead = _IN_BUDGET + 1 if use_budget else _IN_BUDGET
+        step = self._paged_steps.get((track, use_budget))
         if step is None:   # one jit per flavor per engine: repeat serve()
-            def paged_decode_step(params, pages, slot_state, token,
-                                  page_table, cur_len, active,
-                                  budget_blocks=None):
-                return self.api.decode_step_paged(
-                    params, pages, slot_state, token, page_table, cur_len,
-                    active, cfg=cfg, options=eviction_options,
-                    budget_blocks=budget_blocks, shard=self.shard)
+            measure = self.options.measure_sparsity
+
+            def paged_decode_step(params, pages, slot_state, step_in):
+                logits, pages, slot_state, aux = self.api.decode_step_paged(
+                    params, pages, slot_state, step_in[:, _IN_TOKEN],
+                    step_in[:, lead:], step_in[:, _IN_CUR_LEN],
+                    step_in[:, _IN_ACTIVE].astype(bool), cfg=cfg,
+                    options=eviction_options,
+                    budget_blocks=(step_in[:, _IN_BUDGET] if use_budget
+                                   else None),
+                    shard=self.shard)
+                # what the host reads of every step, in one small array
+                cols = [jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                        jnp.isfinite(logits).all(axis=-1).astype(jnp.int32)]
+                if measure:
+                    cols += [jax.lax.bitcast_convert_type(
+                        aux[k].astype(jnp.float32), jnp.int32)
+                        for k in ("sparsity_rows", "sel_blocks")]
+                return logits, pages, slot_state, aux, jnp.stack(cols, -1)
             # named, so the device trace shows jit_paged_decode_step
-            step = self._paged_steps[track] = jax.jit(
+            step = self._paged_steps[track, use_budget] = jax.jit(
                 paged_decode_step, donate_argnums=(1,))
         evmgr = None
         if eviction is not None:
@@ -718,15 +742,20 @@ class DecodeEngine:
                     # recurrent state (updates are not idempotent), which keeps
                     # the replay bitwise-equal to a never-faulted step
                     with TraceAnnotation("serve.dispatch", active=active_now):
-                        logits, pages, slot_state_out, aux = step(
+                        # one upload: a fresh host array every attempt (the
+                        # device may alias it while the step is in flight)
+                        step_in = np.empty(
+                            (n_slots, lead + sched.page_table.shape[1]),
+                            np.int32)
+                        step_in[:, _IN_TOKEN] = token_buf
+                        step_in[:, _IN_CUR_LEN] = sched.cur_len
+                        step_in[:, _IN_ACTIVE] = sched.active
+                        if budget_blocks is not None:
+                            step_in[:, _IN_BUDGET] = budget_blocks
+                        step_in[:, lead:] = sched.page_table
+                        logits, pages, slot_state_out, aux, step_out = step(
                             self.params, pages, slot_state,
-                            jnp.asarray(token_buf),
-                            jnp.asarray(sched.page_table),
-                            jnp.asarray(sched.cur_len),
-                            jnp.asarray(sched.active),
-                            budget_blocks=(jnp.asarray(budget_blocks)
-                                           if budget_blocks is not None
-                                           else None))
+                            jnp.asarray(step_in))
                     if evmgr is None:
                         break
                     with _sync("touched_pages"):
@@ -804,11 +833,14 @@ class DecodeEngine:
                 # was live so sparsity_stats() averages ACTIVE rows only
                 self._last_active = sched.active.copy()
                 slot_reqs = list(sched.slots)   # before retirement mutates it
+                # the step's one blocking pull: greedy tokens, finite
+                # flags and the sparsity rows, packed on the device
+                with _sync("step_out"):
+                    out_np = np.asarray(step_out)
                 # per-request failure isolation: a non-finite logits row (a
                 # poisoned request, or an injected "logits" fault) is retired
                 # with an error instead of sampling garbage into the batch
-                with _sync("isfinite"):
-                    finite = np.array(jnp.isfinite(logits).all(axis=-1))
+                finite = out_np[:, _OUT_FINITE] != 0
                 if faults is not None and faults.fire("logits"):
                     act = np.nonzero(sched.active)[0]
                     if act.size:
@@ -828,15 +860,10 @@ class DecodeEngine:
                             nxt[slot] = sample_slot(slot_reqs[slot],
                                                     lg_np[slot])
                 else:
-                    with _sync("argmax"):
-                        nxt = np.asarray(jnp.argmax(logits, axis=-1),
-                                         np.int32)
+                    nxt = out_np[:, _OUT_NEXT]
                 if self.options.measure_sparsity:
-                    with _sync("sparsity_rows"):
-                        rho_rows = np.asarray(aux["sparsity_rows"],
-                                              np.float32)
-                    with _sync("sel_blocks"):
-                        sel_rows = np.asarray(aux["sel_blocks"], np.float32)
+                    rho_rows = out_np[:, _OUT_RHO].view(np.float32)
+                    sel_rows = out_np[:, _OUT_SEL].view(np.float32)
                     for slot in np.nonzero(sched.active)[0]:
                         rid = slot_reqs[slot].rid
                         rho_sum[rid] += float(rho_rows[slot])

@@ -87,10 +87,12 @@ def test_serve_spans_nest_in_one_step_span_per_decode_step(tmp_path):
         assert res[rid] == plain[rid]
 
 
-@pytest.mark.parametrize("measure_sparsity,syncs", [(True, 4), (False, 2)])
-def test_serve_syncs_per_greedy_step(tmp_path, measure_sparsity, syncs):
-    """A greedy step with no admission pulls isfinite and argmax, plus the
-    two sparsity rows when telemetry is on: one serve.sync span each."""
+@pytest.mark.parametrize("measure_sparsity", [True, False])
+def test_serve_syncs_per_greedy_step(tmp_path, measure_sparsity):
+    """A greedy step with no admission makes one blocking pull, the step
+    program's packed output (``what="step_out"``: greedy tokens, finite
+    flags and, with telemetry on, the sparsity rows), in one serve.sync
+    span, whether or not telemetry is on."""
     eng = _engine(measure_sparsity=measure_sparsity)
     _, spans = _serve_traced(eng, tmp_path)
     steps = [s for s in spans if s[0] == "serve.step"]
@@ -101,10 +103,39 @@ def test_serve_syncs_per_greedy_step(tmp_path, measure_sparsity, syncs):
             i = _step_of(steps, s)
             if s[0] == "serve.sync":
                 count[i] += 1
+                assert s[3]["what"] in ("step_out", "prefill_logits")
             else:
                 admitted[i] = True
     plain = [c for c, a in zip(count, admitted) if not a]
     assert len(plain) == len(steps) - 1       # both admitted at step 0
-    assert plain == [syncs] * len(plain)
+    assert plain == [1] * len(plain)
     # the admission step adds one pull of each prefill's logits
-    assert count[0] == syncs + len(SPECS)
+    assert count[0] == 1 + len(SPECS)
+
+
+@pytest.mark.parametrize("measure_sparsity", [True, False])
+def test_one_pull_matches_host_argmax_and_telemetry(measure_sparsity):
+    """The tokens and sparsity read from the step's packed output equal
+    what the host makes of the pulled logits, and an injected "logits"
+    fault still retires exactly one row."""
+    from repro.serve.faults import FaultInjector
+    eng = _engine(measure_sparsity=measure_sparsity)
+    plain = eng.serve(_requests(eng), n_slots=2)
+    pulled = eng.serve(_requests(eng), n_slots=2, collect_logits=True)
+    for rid in range(len(SPECS)):
+        assert plain[rid] == pulled[rid]
+        assert len(plain[rid]) == SPECS[rid][1]
+        np.testing.assert_array_equal(
+            np.argmax(pulled["logits"][rid], axis=-1), plain[rid])
+    for key in ("sel_blocks_by_rid", "sparsity_by_rid"):
+        assert plain["stats"][key] == pulled["stats"][key]
+        assert len(plain["stats"][key]) == (len(SPECS) if measure_sparsity
+                                            else 0)
+    # the second decode step's first active row (rid 0) reads non-finite
+    res = eng.serve(_requests(eng), n_slots=2,
+                    faults=FaultInjector({"logits": [1]}))
+    assert res["stats"]["errors"] == {0: "non_finite_logits"}
+    assert res["stats"]["failed"] == 1
+    # retired after its prefill token and the first decode step's
+    assert res[0] == plain[0][:2]
+    assert res[1] == plain[1]
