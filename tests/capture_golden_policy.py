@@ -30,6 +30,17 @@ the config ``token_budget`` (which keeps the paper's floor semantics via
 ``resolve_max_selected``), never a runtime ``budget_override`` — both
 capture modes re-verified bitwise after the change.
 
+jax 0.9 turned ``jax_threefry_partitionable`` on by default, which draws
+different parameters from the same PRNG key, so every token array
+drifted. This module pins the flag on (jax's own default, so importing it
+changes nothing for other tests in the same process) and the npz was
+recaptured under it; with the flag off the old token arrays still matched
+and the logits differed by <= 5.7e-7 (3.9e-3 on the bf16 sharded run).
+The npz also records the jax/jaxlib versions it was captured with:
+``check_versions`` fails a golden test run under other versions, naming
+both and the recapture command, since the numerics are only pinned for
+one toolchain.
+
 ``--verify`` (the CI golden-drift guard, ISSUE 4): recompute the mode's
 arrays and BITWISE-compare them against the committed npz instead of
 writing — exits non-zero on drift, so a stale golden is caught as its own
@@ -40,7 +51,11 @@ import dataclasses
 import os
 import sys
 
+import jax
 import numpy as np
+
+# the PRNG stream the npz was captured with (jax 0.9's default)
+jax.config.update("jax_threefry_partitionable", True)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, "golden_policy.npz")
@@ -83,11 +98,36 @@ def paged_requests(cfg):
 
 
 VERIFY = False
+VERSION_KEYS = ("jax_version", "jaxlib_version")
+RECAPTURE = ("PYTHONPATH=src:tests python tests/capture_golden_policy.py "
+             "contiguous_paged && XLA_FLAGS=--xla_force_host_platform_"
+             "device_count=8 PYTHONPATH=src:tests python "
+             "tests/capture_golden_policy.py sharded")
+
+
+def versions():
+    import jaxlib
+    return {"jax_version": jax.__version__,
+            "jaxlib_version": jaxlib.__version__}
+
+
+def check_versions(gold):
+    """Fail unless the running jax/jaxlib are the ones ``gold`` was
+    captured with: bitwise numerics are pinned for one toolchain only."""
+    want = {k: str(gold[k]) if k in gold else "unrecorded"
+            for k in VERSION_KEYS}
+    have = versions()
+    if want != have:
+        raise AssertionError(
+            f"golden_policy.npz was captured with {want}, this run has "
+            f"{have}; if the arrays drift only by toolchain, recapture "
+            f"both modes: {RECAPTURE}")
 
 
 def _merge_save(arrays):
     if VERIFY:
         return _verify(arrays)
+    arrays = {**arrays, **{k: np.array(v) for k, v in versions().items()}}
     if os.path.exists(OUT):
         prev = dict(np.load(OUT))
         prev.update(arrays)
@@ -99,6 +139,7 @@ def _merge_save(arrays):
 def _verify(arrays):
     """Bitwise-compare freshly captured arrays against the committed npz."""
     gold = dict(np.load(OUT))
+    check_versions(gold)
     bad = []
     for k, v in sorted(arrays.items()):
         if k not in gold:
@@ -120,8 +161,6 @@ def _verify(arrays):
 
 
 def capture_contiguous_paged():
-    import jax
-    import jax.numpy as jnp
     jax.config.update("jax_platform_name", "cpu")
     from repro.models.registry import get_api
     from repro.serve.engine import DecodeEngine
@@ -156,7 +195,6 @@ def capture_contiguous_paged():
 
 def capture_sharded():
     import functools
-    import jax
     import jax.numpy as jnp
     from repro.data.pipeline import DataState, make_batch
     from repro.models import transformer as tf

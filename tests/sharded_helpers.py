@@ -108,6 +108,7 @@ def sharded_policy_golden():
 
     gold = np.load(os.path.join(os.path.dirname(__file__),
                                 "golden_policy.npz"))
+    G.check_versions(gold)
     mesh = make_mesh((2, 4), ("data", "model"))
     cfg = G.sharded_cfg()
     params = tf.init_lm(jax.random.PRNGKey(G.PARAM_SEED), cfg)

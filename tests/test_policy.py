@@ -57,6 +57,7 @@ def _params_and_prompt(cfg):
 
 @pytest.mark.parametrize("method", ["budget", "threshold"])
 def test_gate_policy_contiguous_bitwise_golden(method):
+    G.check_versions(GOLD)
     cfg = G.tiny_cfg(method)
     api, params, toks = _params_and_prompt(cfg)
     eng = DecodeEngine(cfg, params, max_len=G.MAX_LEN)
@@ -72,6 +73,7 @@ def test_gate_policy_contiguous_bitwise_golden(method):
 
 
 def test_gate_policy_paged_bitwise_golden():
+    G.check_versions(GOLD)
     cfg = G.tiny_cfg("budget")
     api, params, _ = _params_and_prompt(cfg)
     eng = DecodeEngine(cfg, params, max_len=128)
@@ -84,6 +86,7 @@ def test_gate_policy_paged_bitwise_golden():
 
 
 def test_gate_policy_sharded_bitwise_golden():
+    G.check_versions(GOLD)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(HERE, "..", "src") + os.pathsep + \

@@ -343,6 +343,7 @@ def test_quantize_none_keeps_paged_goldens_bitwise():
     equality with tests/golden_policy.npz."""
     import capture_golden_policy as G
     gold = np.load(os.path.join(HERE, "golden_policy.npz"))
+    G.check_versions(gold)
     cfg = G.tiny_cfg("budget")
     api = get_api(cfg)
     params = api.init_params(jax.random.PRNGKey(G.PARAM_SEED), cfg)

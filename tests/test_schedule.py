@@ -140,6 +140,7 @@ def test_all_select_schedule_contiguous_bitwise_golden():
     """The plan-carrying machinery (lax.cond staging, carried plan, gated
     Kg advance) with an every-layer-selects schedule reproduces the
     committed golden trajectory BITWISE on the contiguous path."""
+    G.check_versions(GOLD)
     cfg = G.tiny_cfg("budget")
     _, params, toks = _params_and_prompt(cfg)
     sched = SelectionSchedule(
@@ -151,6 +152,7 @@ def test_all_select_schedule_contiguous_bitwise_golden():
 
 
 def test_all_select_schedule_paged_bitwise_golden():
+    G.check_versions(GOLD)
     cfg = G.tiny_cfg("budget")
     _, params, _ = _params_and_prompt(cfg)
     sched = SelectionSchedule(
