@@ -42,7 +42,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metacache import BlockHeat
@@ -105,9 +104,6 @@ class EvictionManager:
         self.ghost_free: List[int] = list(range(num_phys,
                                                 num_phys + ghost_rows))
         self.evicted: Dict[int, Dict[int, int]] = {}   # rid -> lb -> ghost
-        # engine-installed: un-dirty restored pages so the kg sweep does
-        # not zero rows that were just rewritten by restore_pages
-        self.mark_clean = lambda ids: None
         self.n_evicted = 0
         self.n_page_restores = 0
         self.n_replays = 0
@@ -182,22 +178,16 @@ class EvictionManager:
         A victim whose swap put fails (capacity/IO fault) is skipped —
         eviction degrades to freeing fewer pages, and the caller falls
         back to preemption. Freed physical ids go through the scheduler's
-        released list so their stale gate rows are zeroed before reuse.
+        recycled set so their stale gate rows are zeroed before reuse.
         """
         freed = 0
         for req, lb in self.pick_victims(n, pinned, only):
             if not self.ghost_free:
                 break
             phys = req.pages[lb]
-            k, v, kg, kmin, kmax, k_sc, v_sc = pg.extract_pages(
-                pages, pg.pad_page_ids([phys]))
-            entry = PageEntry(
-                k=np.asarray(k[:, :1]), v=np.asarray(v[:, :1]),
-                kg=None if kg is None else np.asarray(kg[:, :1]),
-                kmin=None if kmin is None else np.asarray(kmin[:, :1]),
-                kmax=None if kmax is None else np.asarray(kmax[:, :1]),
-                k_scale=None if k_sc is None else np.asarray(k_sc[:, :1]),
-                v_scale=None if v_sc is None else np.asarray(v_sc[:, :1]))
+            entry = PageEntry(*(
+                None if a is None else np.asarray(a[:, :1])
+                for a in pg.extract_pages(pages, pg.pad_page_ids([phys]))))
             try:
                 self.swap.put(("page", req.rid, lb), entry)
             except SwapError:
@@ -208,8 +198,7 @@ class EvictionManager:
             req.pages[lb] = ghost
             self.sched.page_table[req.slot, lb] = ghost
             self.evicted.setdefault(req.rid, {})[lb] = ghost
-            self.sched.allocator.free([phys])
-            self.sched.released.append(phys)
+            self.sched.free_pages([phys])
             self.n_evicted += 1
             freed += 1
         return pages, freed
@@ -233,19 +222,13 @@ class EvictionManager:
             try:
                 pe = self.swap.pop(("page", req.rid, lb))
             except SwapError:
-                self.sched.allocator.free([phys])
-                self.sched.released.append(phys)
+                self.sched.free_pages([phys])
                 return pages, False
             pages = pg.restore_pages(
-                pages, jnp.asarray(pe.k), jnp.asarray(pe.v),
-                None if pe.kg is None else jnp.asarray(pe.kg),
-                pg.pad_page_ids([phys]),
-                None if pe.kmin is None else jnp.asarray(pe.kmin),
-                None if pe.kmax is None else jnp.asarray(pe.kmax),
-                k_scale=None if pe.k_scale is None
-                else jnp.asarray(pe.k_scale),
-                v_scale=None if pe.v_scale is None
-                else jnp.asarray(pe.v_scale))
+                pages, pg.to_dev(pe.k), pg.to_dev(pe.v), pg.to_dev(pe.kg),
+                pg.pad_page_ids([phys]), pg.to_dev(pe.kmin),
+                pg.to_dev(pe.kmax), k_scale=pg.to_dev(pe.k_scale),
+                v_scale=pg.to_dev(pe.v_scale))
             req.pages[lb] = phys
             self.sched.page_table[req.slot, lb] = phys
             del self.evicted[req.rid][lb]
@@ -253,8 +236,8 @@ class EvictionManager:
                 del self.evicted[req.rid]
             self.ghost_free.append(ghost)
             # restore_pages just rewrote this page's gate rows — pull it
-            # out of the dirty/released sweep or they would be zeroed
-            self.mark_clean([phys])
+            # out of the recycled set or a sweep would zero them
+            self.sched.mark_live([phys])
             self.n_page_restores += 1
         return pages, True
 
@@ -291,22 +274,10 @@ class EvictionManager:
 
     # -- lifecycle ----------------------------------------------------------
 
-    def forget(self, req: Request) -> List[int]:
+    def forget(self, req: Request) -> None:
         """Drop every evicted-page record of ``req`` (retire / fail /
-        preempt-merge); returns the ghost ids handed back to the free
-        list. Idempotent."""
-        ghosts: List[int] = []
-        blocks = self.evicted.pop(req.rid, None)
-        if blocks:
-            for lb, ghost in blocks.items():
-                self.swap.discard(("page", req.rid, lb))
-                ghosts.append(ghost)
-            self.ghost_free.extend(ghosts)
-        return ghosts
-
-    def stats(self) -> Dict[str, int]:
-        return {"evictions": self.n_evicted,
-                "page_restores": self.n_page_restores,
-                "replay_steps": self.n_replays,
-                "pages_evicted_now": sum(len(v)
-                                         for v in self.evicted.values())}
+        preempt-merge) and hand its ghost ids back to the free list.
+        Idempotent."""
+        for lb, ghost in self.evicted.pop(req.rid, {}).items():
+            self.swap.discard(("page", req.rid, lb))
+            self.ghost_free.append(ghost)

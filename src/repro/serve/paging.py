@@ -507,6 +507,12 @@ def pad_page_ids(ids: Sequence[int], *, min_len: int = 1) -> jnp.ndarray:
                        jnp.int32)
 
 
+def to_dev(a):
+    """A host page row (swap/page entry field) on the device; None passes
+    through."""
+    return None if a is None else jnp.asarray(a)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def reset_kg_rows(pages: PagedPages, page_ids: jnp.ndarray) -> PagedPages:
     """Zero the Kg AND selection-metadata rows of freshly (lazily)

@@ -40,7 +40,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, Iterable, List, Optional, Set
 
 import numpy as np
 
@@ -130,7 +130,8 @@ class Scheduler:
     def __init__(self, n_slots: int, num_pages: int, page_size: int,
                  max_pages_per_seq: int, *, admission: str = "lazy",
                  watermark: int = 0, eviction_enabled: bool = False,
-                 faults=None):
+                 faults=None, evict_cb=None, release_filter=None,
+                 on_token=None):
         if admission not in ADMISSION_MODES:
             raise ValueError(f"admission {admission!r} not in "
                              f"{ADMISSION_MODES}")
@@ -141,7 +142,7 @@ class Scheduler:
         self.max_pages_per_seq = max_pages_per_seq
         self.admission = admission
         self.watermark = watermark
-        # ISSUE 7 seams, wired by the engine when eviction is on:
+        # ISSUE 7 seams, passed by the engine when eviction is on:
         #   eviction_enabled — relaxes the full-lifetime admission bound
         #     (growth past the pool is absorbed by page eviction) and makes
         #     _pick_victim skip victims whose resume need can't fit
@@ -150,10 +151,9 @@ class Scheduler:
         #   release_filter(req) -> physical page ids to free — ghost ids of
         #     evicted pages must never reach PageAllocator.free
         self.eviction_enabled = eviction_enabled
-        self.evict_cb: Optional[Callable[[int], int]] = None
-        self.release_filter: Optional[Callable[[Request], List[int]]] = None
+        self.evict_cb, self.release_filter = evict_cb, release_filter
         self.faults = faults
-        # ISSUE 8 seams, wired by the engine:
+        # ISSUE 8 seams:
         #   now — the decode-loop step counter (virtual clock); lifecycle
         #     ``*_step`` stamps read it, so they are deterministic for a
         #     fixed trace. The engine sets it each iteration.
@@ -163,8 +163,7 @@ class Scheduler:
         #     by ``note_token`` exactly once per appended token, in order.
         self.now = 0
         self.wall: Callable[[], float] = time.perf_counter
-        self.on_token: Optional[Callable[[Request, int, int, int],
-                                         None]] = None
+        self.on_token = on_token
         self.allocator = PageAllocator(num_pages)
         self.page_table = np.full((n_slots, max_pages_per_seq), NULL_PAGE,
                                   np.int32)
@@ -173,10 +172,13 @@ class Scheduler:
         self.slots: List[Optional[Request]] = [None] * n_slots
         self.pending: Deque[Request] = deque()
         self.finished: Dict[int, Request] = {}
-        # pages freed since the engine last drained (retire/preempt) —
-        # the engine zeroes their Kg rows before the free list re-issues
-        # them (one batched device call per release, not per growth)
-        self.released: List[int] = []
+        # the recycled-page set: pages freed (retire, preempt, fail,
+        # eviction) whose gate/meta/scale rows may still hold a previous
+        # tenant's entries. The scheduler is its only writer; the engine
+        # zeroes on device what ``drain_released`` hands it (one batched
+        # call per step, not per growth) and ``mark_live`` drops pages
+        # whose content was rewritten (prefill, restore)
+        self.released: Set[int] = set()
         # telemetry
         self.n_admitted = 0                # fresh admissions (prefills)
         self.n_resumed = 0                 # swap-in re-admissions
@@ -394,12 +396,16 @@ class Scheduler:
         """Free a request's pages, routing through the engine's
         release_filter so ghost ids of evicted pages (which are table
         aliases, not allocator pages) never hit PageAllocator.free."""
-        pages = (self.release_filter(req) if self.release_filter is not None
-                 else req.pages)
-        if pages:
-            self.allocator.free(pages)
-            self.released.extend(pages)
+        self.free_pages(self.release_filter(req)
+                        if self.release_filter is not None else req.pages)
         req.pages = []
+
+    def free_pages(self, ids: List[int]) -> None:
+        """Return physical pages to the allocator; they join the recycled
+        set until the engine zeroes or rewrites them."""
+        if ids:
+            self.allocator.free(ids)
+            self.released.update(ids)
 
     def _preempt(self, req: Request,
                  swap_out: Optional[Callable[[Request], None]]) -> None:
@@ -407,14 +413,10 @@ class Scheduler:
         req.swap_len = int(self.cur_len[slot])
         if swap_out is not None:
             swap_out(req)                  # capture BEFORE pages are freed
-        self._release(req)
+        self._vacate(slot)
         req.swapped = True
         req.n_preemptions += 1
         self.n_preemptions += 1
-        self.slots[slot] = None
-        self.active[slot] = False
-        self.cur_len[slot] = 0
-        self.page_table[slot] = NULL_PAGE
         req.slot = -1
         self.pending.appendleft(req)       # resume ahead of fresh arrivals
 
@@ -463,17 +465,34 @@ class Scheduler:
             return True
         return False
 
-    def drain_released(self) -> List[int]:
-        out, self.released = self.released, []
-        return out
+    def mark_live(self, ids: Iterable[int]) -> None:
+        """Pages just (re)written with live content leave the recycled set,
+        so a later sweep cannot clobber their fresh gate rows (a page can be
+        freed and reused within one iteration — retire-at-admission,
+        eviction, replay restore)."""
+        self.released.difference_update(ids)
 
-    def _retire(self, slot: int) -> Request:
-        req = self.slots[slot]
-        self._release(req)
+    def drain_released(self, within: Optional[Iterable[int]] = None
+                       ) -> List[int]:
+        """Take the recycled pages the engine must zero now, sorted: all of
+        them, or only those among ``within`` (the pages growth just
+        handed out)."""
+        out = (set(self.released) if within is None
+               else self.released.intersection(within))
+        self.released -= out
+        return sorted(out)
+
+    def _vacate(self, slot: int) -> None:
+        """Free the slot's request's pages and empty the slot."""
+        self._release(self.slots[slot])
         self.slots[slot] = None
         self.active[slot] = False
         self.cur_len[slot] = 0
         self.page_table[slot] = NULL_PAGE
+
+    def _retire(self, slot: int) -> Request:
+        req = self.slots[slot]
+        self._vacate(slot)
         req.retire_step = self.now
         req.t_retire = self.wall()
         self.finished[req.rid] = req
@@ -495,11 +514,7 @@ class Scheduler:
         req.error = reason
         slot = req.slot
         if slot >= 0 and self.slots[slot] is req:
-            self._release(req)
-            self.slots[slot] = None
-            self.active[slot] = False
-            self.cur_len[slot] = 0
-            self.page_table[slot] = NULL_PAGE
+            self._vacate(slot)
             req.slot = -1
         else:
             try:

@@ -303,6 +303,42 @@ def test_paged_step_writes_pools_in_place(quantize):
     assert temp < pool_bytes / 4, (temp, pool_bytes)
 
 
+@pytest.mark.parametrize("program", ["step", "step_budget", "step_evict",
+                                     "prefill"])
+def test_serve_programs_keep_their_trace_names(program):
+    """The benchmark finds serve()'s programs in the device trace by name
+    (``decode_step_ms`` and ``decode_hbm_share`` read
+    ``jit_paged_decode_step``, ``prefill_ms`` reads ``jit_paged_prefill``):
+    the engine's packed step, in each flavor serve() dispatches, and its
+    bucketed prefill lower, from shapes alone, to modules of those
+    names."""
+    from repro.serve import paging as pg
+    cfg = reduced(configs.get("qwen3_0_6b")).replace(dtype="float32")
+    cfg = cfg.replace(gate=dataclasses.replace(
+        cfg.gate, block_size=8, d_gate=16, token_budget=16))
+    api = get_api(cfg)
+    params = jax.eval_shape(lambda: api.init_params(jax.random.PRNGKey(0),
+                                                    cfg))
+    eng = DecodeEngine(cfg, params, max_len=64)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    slots, npt, n_pages = 2, 4, 9
+    if program == "prefill":
+        lowered = eng._prefill_fn(2).lower(
+            params, {"tokens": i32((1, 2 * cfg.gate.block_size)),
+                     "lengths": i32((1,))})
+        want = "jit_paged_prefill"
+    else:
+        track, use_budget = program == "step_evict", program == "step_budget"
+        pages = jax.eval_shape(lambda: pg.init_pages(
+            cfg, n_pages, api.paged_attn_layers(cfg),
+            ghost_rows=slots * npt if track else 0))
+        lead = 4 if use_budget else 3   # token, cur_len, active[, budget]
+        lowered = eng._paged_step(track, use_budget).lower(
+            params, pages, None, i32((slots, lead + npt)))
+        want = "jit_paged_decode_step"
+    assert re.match(r"module @(\S+)", lowered.as_text()).group(1) == want
+
+
 def test_select_blocks_zero_cap_is_error():
     """max_selected=0 must raise, not silently fall back to the config
     budget (ISSUE 2 satellite)."""

@@ -147,6 +147,34 @@ def test_watermark_exempts_swap_in_resumes():
     assert r2 is req and r2.swapped and len(r2.pages) == 6
 
 
+def test_scheduler_owns_the_recycled_page_set():
+    """Every page the scheduler frees (retire, eviction's free_pages)
+    joins the recycled set; mark_live drops rewritten pages; a drain
+    within the growth pages takes only those and keeps the rest for the
+    end-of-step drain, which takes everything, sorted, once."""
+    sched = Scheduler(n_slots=2, num_pages=12, page_size=4,
+                      max_pages_per_seq=4, admission="lazy")
+    r0 = Request(rid=0, prompt=np.zeros(8, np.int32), max_new_tokens=1)
+    r1 = Request(rid=1, prompt=np.zeros(12, np.int32), max_new_tokens=1)
+    sched.submit(r0)
+    sched.submit(r1)
+    a0, a1 = sched.admissions()
+    p0, p1 = list(a0.pages), list(a1.pages)
+    assert sched.drain_released() == []
+    for r in (a0, a1):                        # the prefill's token ends it
+        r.out_tokens.append(7)
+        assert sched.retire_if_done(r)
+    assert sched.released == set(p0 + p1)
+    sched.mark_live([p1[0]])                  # rewritten: not stale
+    assert sched.drain_released(within=[p0[1], p1[0], 11]) == [p0[1]]
+    assert sched.drain_released() == sorted([p0[0]] + p1[1:])
+    assert sched.drain_released() == []
+    # eviction frees a physical page through the scheduler too
+    ids = sched.allocator.alloc(1)
+    sched.free_pages(ids)
+    assert sched.drain_released() == ids
+
+
 def test_scheduler_growth_preempts_fewest_generated():
     """Pool exhaustion during lazy growth preempts the request with the
     fewest generated tokens; its pages are freed, the swap callback fires
