@@ -162,7 +162,7 @@ class ServeRun:
                                    with_meta=eng.options.policy.needs_meta,
                                    ghost_rows=ghosts,
                                    quantize=eng.options.quantize)
-        # per-slot recurrent state (PR 10): the page pools' lifecycle twin
+        # per-slot recurrent state: the page pools' lifecycle twin
         # for recurrent families — None (an empty pytree) for pages-only
         # families, so the step jit sees zero extra operands
         self.slot_state = (None if eng.api.init_slot_state is None
@@ -320,7 +320,7 @@ class ServeRun:
         restore path. A permanent swap fault here marks the victim failed
         instead of raising through the scheduler.
 
-        Recurrent families (PR 10): the victim's per-layer recurrent rows
+        Recurrent families: the victim's per-layer recurrent rows
         ride along in the entry (``state_conv``/``state_h``) — captured
         from the PRE-step buffer (the step jit never donates
         ``slot_state``), which together with the pending ``token`` is
@@ -371,8 +371,8 @@ class ServeRun:
     def prefill(self, req: Request):
         """Contiguous prefill of one request, scattered into its pages.
 
-        Prompt lengths are rounded UP to power-of-two page buckets (ISSUE
-        5 satellite): tokens are right-padded to the bucket width and the
+        Prompt lengths are rounded UP to power-of-two page buckets: tokens
+        are right-padded to the bucket width and the
         true length rides along as ``batch["lengths"]`` — causality (and,
         for recurrent families, exact pad-identity masking in the mamba
         scans) keeps real positions unaffected by pad tokens,
@@ -388,7 +388,7 @@ class ServeRun:
         admission growth pages are zeroed at allocation time
         (``pg.reset_kg_rows``).
 
-        Family dispatch happens through ``api.state_view`` (PR 10): the
+        Family dispatch happens through ``api.state_view``: the
         view names which prefill-state fields scatter into the page pools
         (skipped entirely for a pages-free family) and which rows seed the
         request's slot in ``slot_state``. Returns the fp32 logits row — the

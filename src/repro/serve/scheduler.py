@@ -142,7 +142,7 @@ class Scheduler:
         self.max_pages_per_seq = max_pages_per_seq
         self.admission = admission
         self.watermark = watermark
-        # ISSUE 7 seams, passed by the engine when eviction is on:
+        # memory-pressure seams, passed by the engine when eviction is on:
         #   eviction_enabled — relaxes the full-lifetime admission bound
         #     (growth past the pool is absorbed by page eviction) and makes
         #     _pick_victim skip victims whose resume need can't fit
@@ -153,7 +153,7 @@ class Scheduler:
         self.eviction_enabled = eviction_enabled
         self.evict_cb, self.release_filter = evict_cb, release_filter
         self.faults = faults
-        # ISSUE 8 seams:
+        # lifecycle and streaming seams:
         #   now — the decode-loop step counter (virtual clock); lifecycle
         #     ``*_step`` stamps read it, so they are deterministic for a
         #     fixed trace. The engine sets it each iteration.
