@@ -117,12 +117,13 @@ def paged_sparse_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     same gather as the pages; page_table: [B, npt] int32 logical block ->
     physical page; block_indices carry LOGICAL block ids (the gate's view)
     — the logical->physical indirection happens here, mirroring the
-    kernel's scalar-prefetch index_map. The selected pages are gathered
-    directly off the native pool layout (no pool-sized transpose); after
-    the gather the math is kept identical to the contiguous reference so
-    paged == contiguous holds to rounding. ``k_scales``/``v_scales``
-    [L, P, Hkv, 1] dequantize int8 pools on the gathered pages only (the
-    scale row rides the same physical-page gather as its page).
+    kernel's lookup in its scalar-prefetched page table. The selected
+    pages are gathered directly off the native pool layout (no
+    pool-sized transpose); after the gather the math is kept identical
+    to the contiguous reference so paged == contiguous holds to rounding.
+    ``k_scales``/``v_scales`` [L, P, Hkv, 1] dequantize int8 pools on the
+    gathered pages only (the scale row rides the same physical-page
+    gather as its page).
     """
     b, hkv, g, dh = q.shape
     ps = k_pages.shape[3]

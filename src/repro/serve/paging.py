@@ -7,8 +7,8 @@ subsystem: one page == one gate block, so the K-compression cache pages
 alongside the raw KV (``kg_pages`` has exactly one row per physical page)
 and admission/eviction can never desync the two. The gate's top-k still
 emits *logical* block ids; the logical->physical translation happens at
-gather time (pure-JAX path) or inside the kernel's scalar-prefetch
-index_map (repro.kernels.block_sparse_decode).
+gather time (pure-JAX path) or inside the kernel, from the
+scalar-prefetched page table (repro.kernels.block_sparse_decode).
 
 Layout (``L`` = self-attn layers, ``P`` = pool pages, ``ps`` = page size;
 HEAD-MAJOR — ISSUE 2 invariant: decode consumes the pools natively, no

@@ -264,3 +264,63 @@ def test_paged_kernel_layer_index_bitwise(kernel, quant, layer):
     got = _paged_call(kernel, pools, jnp.int32(layer))
     want = _paged_call(kernel, one, jnp.int32(0))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# hkv, g, pool dtype, page size, head dim, nsel; C (list positions a grid
+# step folds per head) follows from the shape: 1 MiB of K and V pages
+PAGED_SWEEP = [
+    (1, 7, "bf16", 16, 128, 5),       # C = nsel
+    (2, 1, "f32", 16, 64, 5),
+    (2, 2, "int8", 16, 64, 5),
+    (8, 2, "bf16", 64, 128, 6),       # C 4: a padded trailing group
+    (8, 1, "f32", 32, 128, 5),        # C 4
+    (8, 7, "int8", 64, 128, 10),      # C 8
+]
+
+
+@pytest.mark.parametrize("hkv,g,kv,ps,dh,nsel", PAGED_SWEEP)
+def test_block_sparse_decode_paged_matches_ref(hkv, g, kv, ps, dh, nsel):
+    """The paged kernel (every KV head of a slot per grid step, pages by
+    explicit DMA) equals the jnp oracle: slot 0 full lists ending in a
+    partial block, slot 1 at kv_len 1, slot 2 with per-head -1 padding
+    and one wholly padded head; page tables scrambled over a 2-layer pool
+    read at layer 1."""
+    from repro.kernels import block_sparse_decode as bsd
+    from repro.serve import paging as pg
+    b, nb = 3, nsel + 2
+    npool = b * nb + 1
+    rng = np.random.default_rng(hkv * 100 + g)
+    ks = jax.random.split(jax.random.PRNGKey(hkv + g), 3)
+    dtype = jnp.bfloat16 if kv == "bf16" else jnp.float32
+    k = jax.random.normal(ks[0], (2, npool, hkv, ps, dh), jnp.float32)
+    v = jax.random.normal(ks[1], (2, npool, hkv, ps, dh), jnp.float32)
+    q = jax.random.normal(ks[2], (b, hkv, g, dh), jnp.float32).astype(dtype)
+    ksc = vsc = None
+    if kv == "int8":
+        (k, ksc), (v, vsc) = (pg.quantize_block(x, jnp.ones_like(x, bool))
+                              for x in (k, v))
+    else:
+        k, v = k.astype(dtype), v.astype(dtype)
+    table = jnp.asarray(1 + rng.permutation(npool - 1)[:b * nb]
+                        .reshape(b, nb), jnp.int32)
+    kv_len = jnp.asarray([nb * ps - ps // 2, 1, (nb - 1) * ps + 3],
+                         jnp.int32)
+    idx = np.full((b, hkv, nsel), -1, np.int32)
+    for h in range(hkv):
+        idx[0, h] = np.concatenate(
+            [[nb - 1], rng.choice(nb - 1, nsel - 1, replace=False)])
+        idx[1, h, 0] = 0
+        n = rng.integers(1, nsel + 1)
+        idx[2, h, :n] = rng.choice(nb, n, replace=False)
+    idx[2, hkv - 1] = -1
+    idx = jnp.asarray(idx)
+    kw = dict(block_size=ps, k_scales=ksc, v_scales=vsc)
+    got = bsd.block_sparse_decode_paged(q, k, v, jnp.int32(1), idx, table,
+                                        kv_len, interpret=True, **kw)
+    want = ref.paged_sparse_decode_ref(q, k, v, jnp.int32(1), idx, table,
+                                       kv_len, **kw)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    assert not np.any(np.asarray(got[2, hkv - 1], np.float32))

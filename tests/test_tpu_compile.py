@@ -68,6 +68,13 @@ def _sds(one_chip, shape, dtype):
 def _compile(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text        # the Mosaic kernel is in there
+    return text
+
+
+def _kernel_names(text):
+    """The instruction names of the compiled text's Mosaic kernels."""
+    return [line.split()[0] for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
 
 
 def _paged_operands(one_chip, kv_dtype):
@@ -109,6 +116,33 @@ def test_block_sparse_decode_paged_compiles_at_a_7_to_1_group(one_chip):
                                                  jnp.int32),
              _sds(one_chip, (B, npt), jnp.int32),
              _sds(one_chip, (B,), jnp.int32))
+
+
+# the benchmark cells' calls: qwen3_0_6b.long4 (4 slots, GQA 2, a
+# 32-block budget, 28 layers of a 641-page pool, 8192-position tables) and
+# deepseek_coder_33b_pp16.long8_16k (8 slots, a 7:1 group, 64 blocks, 4
+# layers of a 2049-page pool, 16384 positions)
+CELLS = {"long4": (4, 2, 32, 28, 641, 128),
+         "long8_16k": (8, 7, 64, 4, 2049, 256)}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_block_sparse_decode_paged_compiles_at_cell_widths(one_chip, cell):
+    """The kernel compiles at each cell's call, and its op carries the
+    name the benchmark's trace readers look for."""
+    b, g, k, n_layers, p, npt = CELLS[cell]
+
+    def fn(q, kp, vp, layer, idx, pt, kv_len):
+        return block_sparse_decode_paged(q, kp, vp, layer, idx, pt, kv_len,
+                                         block_size=PS)
+    pool = _sds(one_chip, (n_layers, p, HKV, PS, DH), jnp.bfloat16)
+    text = _compile(fn, _sds(one_chip, (b, HKV, g, DH), jnp.bfloat16), pool,
+                    pool, _sds(one_chip, (), jnp.int32),
+                    _sds(one_chip, (b, HKV, k), jnp.int32),
+                    _sds(one_chip, (b, npt), jnp.int32),
+                    _sds(one_chip, (b,), jnp.int32))
+    assert any(n.startswith("%block_sparse_decode_paged")
+               for n in _kernel_names(text)), _kernel_names(text)
 
 
 def test_block_sparse_decode_paged_splitk_compiles(one_chip):
